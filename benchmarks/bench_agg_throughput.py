@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.core import get_strategy, list_strategies
 from repro.core.plan import dispatch_counter
+from repro.launch.cache import enable_compile_cache
 from repro.lora import init_adapters, set_ranks
 from repro.obs import bench_payload, time_fn
 
@@ -228,6 +229,7 @@ def run_svd_factored_case(iters, tol):
 
 
 def main(argv=None):
+    enable_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--smoke", action="store_true",
                    help="tiny case + hard parity/dispatch gate (CI)")

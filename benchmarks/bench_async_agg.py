@@ -59,6 +59,7 @@ from repro.core.strategy import ClientUpdate, ServerState, get_strategy
 from repro.fl import AsyncAggregator, DurableAggregator
 from repro.fl.comm import tree_bytes
 from repro.fl.selection import ClientLatencyModel
+from repro.launch.cache import enable_compile_cache
 from repro.lora import init_adapters, set_ranks
 from repro.obs import bench_payload, set_enabled, time_fn
 
@@ -392,6 +393,7 @@ def serving_chaos_check(specs, r_max):
 
 
 def main(argv=None):
+    enable_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--smoke", action="store_true",
                    help="tiny case + hard gates (CI)")

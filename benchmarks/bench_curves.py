@@ -11,11 +11,13 @@ import os
 import time
 
 from repro.fl import FLConfig, run_simulation
+from repro.launch.cache import enable_compile_cache
 
 ART = os.path.join(os.path.dirname(__file__), "artifacts", "curves")
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=20)
     ap.add_argument("--n-per-class", type=int, default=300)

@@ -1,8 +1,11 @@
-"""Distributed RBLA (shard_map masked psum) vs host aggregation.
+"""Distributed RBLA (shard_map masked psum) vs host aggregation: a CPU
+mesh rehearsal.
 
-Runs in a SUBPROCESS with 8 forced host devices (so the parent process /
-other benches keep seeing 1 CPU device), checks numerical equivalence with
-the single-host core implementation, and times both.
+Runs in a SUBPROCESS pinned to the CPU with 8 forced host devices (so the
+parent process / other benches keep seeing their own devices, and the
+child never reaches for an accelerator the parent may hold), checks
+numerical equivalence with the single-host core implementation, and
+times both on XLA:CPU -- not a device measurement.
 """
 from __future__ import annotations
 
@@ -60,6 +63,7 @@ print(f"agg/host_jit/n{n}_r{r}_d{d},{us_host:.0f},reference")
 def main():
     env = dict(os.environ)
     env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", CHILD], env=env,
                           capture_output=True, text=True, timeout=600)
     sys.stdout.write(proc.stdout)

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import lora_matmul, lora_matmul_ref
+from repro.launch.cache import enable_compile_cache
 
 CASES = [
     # (m, k, n, r)
@@ -41,6 +42,7 @@ def traffic_model(m, k, n, r, bytes_per=2):
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     for m, k, n, r in CASES:
         x = jnp.asarray(rng.normal(size=(m, k)), jnp.bfloat16)

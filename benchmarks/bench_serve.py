@@ -45,6 +45,7 @@ from repro.core import ClientUpdate, ServerState
 from repro.fl import AsyncAggregator
 from repro.kernels import lora_matmul_ref
 from repro.kernels.lora_matmul.ops import trace_counts
+from repro.launch.cache import enable_compile_cache
 from repro.lora import init_adapters, set_ranks
 from repro.obs import bench_payload, block
 from repro.serving import AdapterStore, ServingEngine, merged_reference
@@ -253,6 +254,7 @@ def run_case(n_tenants, width, r_max, batch, n_batches, iters, rounds,
 
 
 def main(argv=None):
+    enable_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--smoke", action="store_true",
                    help="reduced case + hard parity/speedup/no-retrace "

@@ -12,6 +12,7 @@ import os
 import time
 
 from repro.fl import FLConfig, run_simulation
+from repro.launch.cache import enable_compile_cache
 
 # lr 0.05: full fine-tune diverges at 0.1 under the staircase non-IID
 # (the paper used 0.01 with more rounds; 0.05 is the stable compromise at
@@ -65,6 +66,7 @@ def run(columns, methods, rounds, n_per_class, participation=1.0,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=25)
     ap.add_argument("--n-per-class", type=int, default=300)

@@ -1,10 +1,12 @@
 """Heterogeneous ranks under the hood: Alg. 2 slicing, delta masks, and the
 difference between zero-padding and RBLA on a single adapter -- then the
-same aggregation as a distributed shard_map psum on 8 simulated devices.
+same aggregation as a distributed shard_map psum on 8 simulated CPU
+devices (a mesh rehearsal: it never touches an accelerator).
 
     PYTHONPATH=src python examples/heterogeneous_ranks.py
 """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import jax

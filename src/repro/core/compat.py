@@ -1,38 +1,31 @@
-"""Version-compat shims for JAX APIs that moved between releases.
+"""The JAX APIs this repo reaches through one import site.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to a top-level
-``jax.shard_map`` (jax >= 0.7), and its replication-check kwarg was renamed
-``check_rep`` -> ``check_vma`` along the way.  Everything in this repo that
-builds shard_map programs goes through :func:`shard_map_no_check` so one
-import site absorbs both changes.
+The repo targets JAX >= 0.7 (``jax.shard_map`` with ``check_vma``;
+installed: 0.9.0).  Where an API is private or has moved before, it is
+wrapped here and nowhere else, so a JAX upgrade is fixed in one file.
 """
 from __future__ import annotations
 
 import jax
+from jax._src import core as _jax_core
 
-try:
-    shard_map = jax.shard_map                     # jax >= 0.7
-except AttributeError:                            # pragma: no cover - old jax
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
-def axis_size(axis_name) -> int:
-    """``lax.axis_size`` (jax >= 0.4.x-late); older jax constant-folds
-    ``psum(1, axis)`` to the same static size inside shard_map bodies."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:                        # pragma: no cover - old jax
-        return jax.lax.psum(1, axis_name)
+def trace_state_clean() -> bool:
+    """True when JAX is *not* tracing (code runs eagerly on the host).
+
+    JAX 0.9 keeps the check only in ``jax._src.core``; a JAX without it
+    fails at import here instead of silently reporting "not tracing"."""
+    return bool(_jax_core.trace_state_clean())
 
 
 def shard_map_no_check(body, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking disabled, any JAX version."""
-    try:
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:                             # jax < 0.7 spells it check_rep
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    """``shard_map`` with replication checking disabled."""
+    return shard_map(body, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
-__all__ = ["shard_map", "shard_map_no_check"]
+__all__ = ["axis_size", "shard_map", "shard_map_no_check",
+           "trace_state_clean"]

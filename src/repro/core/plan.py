@@ -559,17 +559,25 @@ class CompiledRound:
     ``n_fallback_pairs``
         pairs a packed plan still routes through reference pair math
         (e.g. flora's over-cap SVD re-projection).
+    ``n_pallas_launches``
+        compiled Pallas kernels among those launches (0 on the ref and
+        distributed backends and wherever a pallas plan runs the XLA
+        lowering instead -- interpret mode, unaligned stack copies, mixed
+        codec means; None where the plan cannot tell: eager and jit
+        rounds).
     """
 
     def __init__(self, strategy, spec: CohortSpec, kind: str,
                  execute: Callable, *, n_kernel_launches: int | None = None,
-                 n_fallback_pairs: int = 0):
+                 n_fallback_pairs: int = 0,
+                 n_pallas_launches: int | None = None):
         self.strategy = strategy
         self.spec = spec
         self.kind = kind
         self._execute = execute
         self.n_kernel_launches = n_kernel_launches
         self.n_fallback_pairs = n_fallback_pairs
+        self.n_pallas_launches = n_pallas_launches
         self.n_calls = 0
 
     def __call__(self, stacked_tree: PyTree, weights, prev_tree=None,
@@ -582,6 +590,7 @@ class CompiledRound:
     def describe(self) -> str:
         return (f"CompiledRound({self.strategy.name}/{self.spec.kind}, "
                 f"kind={self.kind}, launches={self.n_kernel_launches}, "
+                f"pallas={self.n_pallas_launches}, "
                 f"fallback_pairs={self.n_fallback_pairs})")
 
 
@@ -793,7 +802,8 @@ def _build_mean_round(strategy, spec: CohortSpec,
         return rebuild[0](pairs)
 
     return CompiledRound(strategy, spec, "packed", execute,
-                         n_kernel_launches=len(buckets))
+                         n_kernel_launches=len(buckets),
+                         n_pallas_launches=len(buckets) if use_kernel else 0)
 
 
 # ---------------------------------------------- encoded (quantized) plans --
@@ -1043,8 +1053,12 @@ def _build_encoded_mean_round(strategy, spec: CohortSpec,
                  for i, o in enumerate(outs)]
         return rebuild[0](pairs)
 
+    # a mixed-codec mean combines per-group partial sums in XLA
+    kernel_buckets = (len(buckets) if use_kernel
+                      and (len(groups) == 1 or robust != "none") else 0)
     return CompiledRound(strategy, spec, "packed", execute,
-                         n_kernel_launches=len(buckets))
+                         n_kernel_launches=len(buckets),
+                         n_pallas_launches=kernel_buckets)
 
 
 def _build_mean_distributed(strategy, spec, buckets, masks_const,
@@ -1124,7 +1138,7 @@ def _build_mean_distributed(strategy, spec, buckets, masks_const,
         return rebuild[0](pairs)
 
     return CompiledRound(strategy, spec, "packed", execute,
-                         n_kernel_launches=len(buckets))
+                         n_kernel_launches=len(buckets), n_pallas_launches=0)
 
 
 # ----------------------------------------------------- packed stack plans --
@@ -1242,10 +1256,16 @@ def _build_stack_round(strategy, spec: CohortSpec) -> CompiledRound:
     # interpreted Pallas pays per-op Python overhead on every static copy,
     # so the fused stacking loses to XLA there; the copies are static
     # slices either way, so the ref lowering is just as fused (and is the
-    # only lowering the "ref" backend may use)
+    # only lowering the "ref" backend may use).  The compiled kernel moves
+    # whole row tiles, so a bucket with a copy off the tiling (a rank not
+    # a multiple of 8) stacks in XLA too.
+    from repro.kernels.rbla_agg.kernel import stack_copies_aligned
     from repro.kernels.runtime import auto_interpret
-    use_kernel = (spec.kind == "pallas"
-                  and not auto_interpret(spec.interpret))
+    use_kernel = [spec.kind == "pallas"
+                  and not auto_interpret(spec.interpret)
+                  and stack_copies_aligned(m["copies_x"], m["copies_prev"],
+                                           jnp.float32)
+                  for m in bucket_meta]
 
     def round_fn(ab, wt_raw, prev_ab):
         wt = wt_raw
@@ -1290,7 +1310,7 @@ def _build_stack_round(strategy, spec: CohortSpec) -> CompiledRound:
                         if len(parts) > 1 else parts[0])
             stack = (functools.partial(packed_stack_inline,
                                        interpret=spec.interpret)
-                     if use_kernel else packed_stack_ref)
+                     if use_kernel[bi] else packed_stack_ref)
             outs.append(stack(
                 x, scales, prev,
                 copies_x=bucket_meta[bi]["copies_x"],
@@ -1339,7 +1359,8 @@ def _build_stack_round(strategy, spec: CohortSpec) -> CompiledRound:
 
     return CompiledRound(strategy, spec, "packed", execute,
                          n_kernel_launches=len(buckets) + len(fallback),
-                         n_fallback_pairs=len(fallback))
+                         n_fallback_pairs=len(fallback),
+                         n_pallas_launches=sum(use_kernel))
 
 
 # ------------------------------------------------------ packed svd plans --
@@ -1432,7 +1453,8 @@ def _build_svd_round(strategy, spec: CohortSpec) -> CompiledRound:
         return rebuild[0](pairs)
 
     return CompiledRound(strategy, spec, "packed", execute,
-                         n_kernel_launches=len(svd_buckets))
+                         n_kernel_launches=len(svd_buckets),
+                         n_pallas_launches=0)
 
 
 # ----------------------------------------------------------- legacy plans --

@@ -794,7 +794,8 @@ class AggregationStrategy:
                   client_updates: Sequence[ClientUpdate],
                   weights: Array | None = None, *, backend: str = "auto",
                   mesh=None, client_axis: str = "clients",
-                  donate: bool = False) -> ServerState:
+                  donate: bool = False,
+                  interpret: bool | None = None) -> ServerState:
         """One server round: fold a participant cohort into ``state``.
 
         Non-LoRA trainables are FedAvg'd; adapters go through this
@@ -827,7 +828,7 @@ class AggregationStrategy:
             new_adapters = self.aggregate_adapters(
                 ad_trees, w, r_max=state.r_max, client_ranks=ranks,
                 prev_global=state.adapters, backend=backend, mesh=mesh,
-                client_axis=client_axis, donate=donate)
+                client_axis=client_axis, donate=donate, interpret=interpret)
 
         current_rank = (adapter_live_ranks(new_adapters)
                         if new_adapters is not None else state.current_rank)
@@ -875,7 +876,8 @@ class AggregationStrategy:
         w = float(update.n_examples if weight is None else weight)
         if w <= 0:
             raise ValueError(f"fold needs a positive weight, got {w}")
-        agg = self.aggregate(state, [update], weights=[w], backend=backend)
+        agg = self.aggregate(state, [update], weights=[w], backend=backend,
+                             interpret=interpret)
         alpha = w / (fs.mass + w)
         kind = resolve_backend(backend, self)
         new_adapters = state.adapters

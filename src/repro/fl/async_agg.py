@@ -126,9 +126,11 @@ class AsyncAggregator:
         clock -- ``now - pulled_at`` -- so a schedule's decay ``a`` /
         grace ``b`` are in the event loop's time units and slow *wall
         time*, not fold churn, is what discounts an update.
-    backend
+    backend, interpret
         Execution backend for the underlying strategy paths
-        (``auto | ref | pallas | distributed``).
+        (``auto | ref | pallas | distributed``) and the Pallas kernels'
+        interpret flag (``None`` = compiled on TPU/GPU, interpreted on
+        CPU).
     replay_window
         Fully-async mode only: non-incremental strategies replay the
         updates folded since the last anchor; after this many the service
@@ -196,6 +198,7 @@ class AsyncAggregator:
                  staleness_b: float = 4.0, staleness_clock: str = "version",
                  buffer_size: int = 1,
                  deadline: float | None = None, backend: str = "auto",
+                 interpret: bool | None = None,
                  replay_window: int = 64,
                  on_publish: "Callable | None" = None,
                  publish_every: int = 1,
@@ -246,6 +249,7 @@ class AsyncAggregator:
         self.server_momentum = float(server_momentum)
         self.state = state
         self.backend = backend
+        self.interpret = interpret
         self.staleness_clock = staleness_clock
         self.staleness_fn = make_staleness_fn(
             staleness, a=staleness_a, b=staleness_b)
@@ -479,7 +483,7 @@ class AsyncAggregator:
                     self.state = self.strategy.aggregate(
                         self.state, [b.update for b in batch],
                         weights=[b.weight for b in batch],
-                        backend=self.backend)
+                        backend=self.backend, interpret=self.interpret)
                     self.n_folded += len(batch)
                     self._m_folds.inc(len(batch))
                     self._apply_momentum(prev_state)
@@ -548,7 +552,7 @@ class AsyncAggregator:
             momentum = self._fold_state.momentum
             self.state, self._fold_state = self.strategy.fold(
                 self.state, update, weight, fold_state=self._fold_state,
-                backend=self.backend)
+                backend=self.backend, interpret=self.interpret)
             self._fold_state.momentum = momentum
         else:
             # replay: recompute the joint aggregate of every update since
@@ -560,7 +564,8 @@ class AsyncAggregator:
             self._replay.append((update, weight))
             out = self.strategy.aggregate(
                 self._anchor, [u for u, _ in self._replay],
-                weights=[w for _, w in self._replay], backend=self.backend)
+                weights=[w for _, w in self._replay], backend=self.backend,
+                interpret=self.interpret)
             self.state = dataclasses.replace(out,
                                              round=self.state.round + 1)
         self.n_folded += 1

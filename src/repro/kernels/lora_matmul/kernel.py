@@ -32,6 +32,10 @@ The packed rank axis R_total rides whole through the grid like the
 single-adapter r does; VMEM adds bm*R + 2*R*max(bk, bn) floats, so keep
 R_total <= ~2048 at the default blocks (ops.py shrinks bk/bn as R
 grows).
+
+f32 operands contract at ``Precision.HIGHEST`` (Mosaic's fp32 matmul)
+so a compiled f32 kernel keeps f32 accuracy; bf16 operands use the
+MXU's native bf16 products with f32 accumulation.
 """
 from __future__ import annotations
 
@@ -49,6 +53,13 @@ DEFAULT_BN = 256
 DEFAULT_BK = 512
 
 
+def _dot(a, b, dims):
+    prec = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+            and b.dtype == jnp.float32 else None)
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=prec,
+                               preferred_element_type=jnp.float32)
+
+
 def _kernel(x_ref, w_ref, a_ref, b_ref, scale_ref, o_ref, acc_ref, axr_ref,
             *, n_k: int):
     k = pl.program_id(2)
@@ -59,18 +70,12 @@ def _kernel(x_ref, w_ref, a_ref, b_ref, scale_ref, o_ref, acc_ref, axr_ref,
         axr_ref[...] = jnp.zeros_like(axr_ref)
 
     x = x_ref[...]
-    acc_ref[...] += jax.lax.dot_general(
-        x, w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    axr_ref[...] += jax.lax.dot_general(
-        x, a_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot(x, w_ref[...], ((1,), (0,)))
+    axr_ref[...] += _dot(x, a_ref[...], ((1,), (1,)))
 
     @pl.when(k == n_k - 1)
     def _finish():
-        lora = jax.lax.dot_general(
-            axr_ref[...], b_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        lora = _dot(axr_ref[...], b_ref[...], ((1,), (1,)))
         y = acc_ref[...] + scale_ref[0, 0] * lora
         o_ref[...] = y.astype(o_ref.dtype)
 
@@ -121,12 +126,8 @@ def _batched_kernel(x_ref, w_ref, a_ref, b_ref, off_ref, cnt_ref,
         axr_ref[...] = jnp.zeros_like(axr_ref)
 
     x = x_ref[...]
-    acc_ref[...] += jax.lax.dot_general(
-        x, w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    axr_ref[...] += jax.lax.dot_general(
-        x, a_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot(x, w_ref[...], ((1,), (0,)))
+    axr_ref[...] += _dot(x, a_ref[...], ((1,), (1,)))
 
     @pl.when(k == n_k - 1)
     def _finish():
@@ -139,9 +140,7 @@ def _batched_kernel(x_ref, w_ref, a_ref, b_ref, off_ref, cnt_ref,
         cnt = cnt_ref[...]
         seg = (p >= off) & (p < off + cnt)
         axr = jnp.where(seg, axr_ref[...], 0.0) * scale_ref[...]
-        lora = jax.lax.dot_general(
-            axr, b_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        lora = _dot(axr, b_ref[...], ((1,), (0,)))
         o_ref[...] = (acc_ref[...] + lora).astype(o_ref.dtype)
 
 

@@ -41,6 +41,18 @@ def _pad_to(v: int, mult: int) -> int:
     return (v + mult - 1) // mult * mult
 
 
+def _reduction_block(dim: int, block: int) -> int:
+    """The largest multiple of 128 that is at most ``block`` and divides
+    ``dim`` (itself a multiple of 128).  The contraction axis is a grid
+    reduction: a partial last block would add whatever lies past the
+    array's end into the accumulator (the compiled kernel reads it as
+    is, interpret mode as NaN)."""
+    b = min(block, dim)
+    while dim % b:
+        b -= 128
+    return b
+
+
 def lora_matmul_inline(x, w, a, b, scale, *, interpret=None, bm=256,
                        bn=256, bk=512):
     """Un-jitted :func:`lora_matmul` body (for use inside compiled
@@ -63,7 +75,7 @@ def lora_matmul_inline(x, w, a, b, scale, *, interpret=None, bm=256,
 
     y = lora_matmul_pallas(x2, wp, ap, bp, sc,
                            bm=min(bm, mp), bn=min(bn, np_),
-                           bk=min(bk, kp), interpret=interpret)
+                           bk=_reduction_block(kp, bk), interpret=interpret)
     return y[:m, :n].reshape(lead + (n,))
 
 
@@ -138,7 +150,8 @@ def batched_lora_matmul_inline(x, w, a_rows, b_rows, adapter_ids, seg_off,
     sc = jnp.pad(sc, (0, mp - m)).reshape(-1, 1)
     y = batched_lora_matmul_pallas(x2, wp, ap, bp, off, cnt, sc,
                                    bm=min(bm, mp), bn=min(bn, np_),
-                                   bk=min(bk, kp), interpret=interpret)
+                                   bk=_reduction_block(kp, bk),
+                                   interpret=interpret)
     return y[:m, :n].reshape(lead + (n,))
 
 

@@ -10,9 +10,16 @@ O(1) flops per element).  One pass, fused mask generation from the rank
 vector (delta is never materialized in HBM -- the jnp reference builds an
 (N, R, 1) mask tensor; the kernel derives it from a VMEM iota).
 
-Grid (R/br, D/bd); the client axis is an in-kernel fori_loop over VMEM
+Grid (R/br, D/bd); the client axis is an in-kernel loop over VMEM
 blocks (N is small: the cohort size).  Block (N, br, bd) of x streams
 through VMEM; ranks/weights ride along as (N,) f32 vectors.
+
+The compiled plan's packed kernels below (``packed_agg``,
+``packed_robust``, ``packed_stack``, ``axpy_fold``) keep every block legal
+for the TPU at published widths and cohorts up to a few hundred clients:
+per-row operands ride as (R, N) / (R, 1) columns, per-client scalars in
+SMEM, and one VMEM budget (:func:`_client_blocks`) sizes the client
+block; ``tests/test_tpu_compile.py`` compiles them for a v5e chip.
 """
 from __future__ import annotations
 
@@ -20,6 +27,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -49,67 +57,124 @@ def _kernel(ranks_ref, weights_ref, x_ref, o_ref, *, n_clients: int,
     o_ref[...] = out.astype(o_ref.dtype)
 
 
+#: bytes of one streamed ``(n, br, bd)`` client block, counted at f32
+#: (the kernels upcast each client slice on load, and the order-statistic
+#: modes keep all n upcast slices live).  The pipeline double-buffers the
+#: block, so two of these plus the live values stay well inside v5e's
+#: 16 MiB of scoped VMEM at any cohort size.
+_CLIENT_BLOCK_BYTES = 2 * 1024 * 1024
+
+
+def _sublanes(dtype) -> int:
+    """Row tiling of ``dtype`` on TPU: 8 rows of f32, 16 of bf16, 32 of
+    int8 -- the multiple a row block (and a DMA'd row range) must be."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _client_blocks(n: int, r: int, d: int, dtype, br: int, bd: int):
+    """Row/column block of a ``(n, br, bd)`` client block inside
+    :data:`_CLIENT_BLOCK_BYTES`: halve the (lane-aligned) column block
+    first while even one row tile overflows, then shrink the row block
+    to the budget.  ``r``/``d`` are the padded bucket dims (multiples of
+    8 and 128); a block equal to the whole dim is always legal."""
+    sub = _sublanes(dtype)
+    bd = min(bd, d)
+    while (bd > 128 and bd % 256 == 0
+           and n * sub * bd * 4 > _CLIENT_BLOCK_BYTES):
+        bd //= 2
+    fit = max(sub, _CLIENT_BLOCK_BYTES // (n * bd * 4) // sub * sub)
+    br = min(br, fit)
+    return (r if br >= r else br), bd
+
+
 def _packed_kernel(weights_ref, masks_ref, x_ref, *rest, n_clients: int,
-                   norm_by: str, has_prev: bool, norm_restore: bool = False,
-                   has_scales: bool = False):
+                   norm_by: str, has_prev: bool, has_scales: bool = False):
     """Fused whole-round aggregation over a packed bucket (plan path).
 
     ``x``: (N, R, D) packed rows from *every* pair of the cohort that
-    shares this bucket's (width, dtype); ``masks``: (N, R) per-row owner
+    shares this bucket's (width, dtype); ``masks``: (R, N) per-row owner
     indicators precomputed on the host from the cohort's rank multiset
     (delta_{i,r} in packed-row form -- layer-stacked pairs just occupy
-    more rows); optional ``prev``: (R, D) packed previous global, the
-    fallback for rows no participant owns.  One launch aggregates what
-    the per-pair path spread over 2 x n_pairs launches.
+    more rows), laid out rows-major so a row block's ``(br, N)`` tile is
+    legal at any ``br``; ``weights``: (N,) in SMEM; optional ``prev``:
+    (R, D) packed previous global, the fallback for rows no participant
+    owns.  One launch aggregates what the per-pair path spread over
+    2 x n_pairs launches.
 
-    ``norm_restore`` fuses rbla_norm's per-row norm restoration into the
-    same pass: each output row is rescaled so its L2 norm matches the
-    owners' weighted-mean row norm (the wrapper keeps the whole row in
-    one block -- the reduction runs over the full width).
-
-    ``has_scales`` adds a (N, R) per-row dequantization-scale operand
-    (after ``x``, before ``prev``): each client row is multiplied by its
-    scale on load, fusing int8 upload decoding into the same pass -- the
-    fp32 view of the payload never hits HBM.
+    ``has_scales`` adds an (R, N) per-row scale operand (after ``x``,
+    before ``prev``): each client row is multiplied by its scale on load,
+    fusing int8 upload decoding (and the clipped mode's per-row clip)
+    into the same pass -- the fp32 view of the payload never hits HBM.
     """
     rest = list(rest)
     scales_ref = rest.pop(0) if has_scales else None
-    if has_prev:
-        prev_ref, o_ref = rest
-    else:
-        (o_ref,) = rest
+    prev_ref = rest.pop(0) if has_prev else None
+    (o_ref,) = rest
     br = x_ref.shape[1]
     num = jnp.zeros(o_ref.shape, jnp.float32)
     den = jnp.zeros((br, 1), jnp.float32)
     wtot = jnp.zeros((), jnp.float32)
-    tnum = jnp.zeros((br, 1), jnp.float32)           # w-mass-weighted norms
-    town = jnp.zeros((br, 1), jnp.float32)           # owner weight mass
     for nix in range(n_clients):                     # static unroll
-        m = masks_ref[nix][:, None]                  # (br, 1)
+        m = masks_ref[:, nix:nix + 1]                # (br, 1)
         w = weights_ref[nix]
         xn = x_ref[nix].astype(jnp.float32)
         if has_scales:
-            xn = scales_ref[nix][:, None] * xn       # fused dequant
+            xn = scales_ref[:, nix:nix + 1] * xn     # fused dequant
         num = num + (w * m) * xn
         den = den + w * m
         wtot = wtot + w
-        if norm_restore:
-            xm = m * xn
-            rn = jnp.sqrt(jnp.sum(xm * xm, axis=1, keepdims=True))
-            own = (m > 0).astype(jnp.float32) * w
-            tnum = tnum + own * rn
-            town = town + own
     if norm_by == "mask":
         fb = (prev_ref[...].astype(jnp.float32) if has_prev
               else jnp.zeros_like(num))
         out = jnp.where(den > 0, num / jnp.where(den > 0, den, 1.0), fb)
     else:
         out = num / wtot
-    if norm_restore:
-        target = tnum / (town + 1e-12)
-        agg = jnp.sqrt(jnp.sum(out * out, axis=1, keepdims=True))
-        out = out * jnp.where(agg > 1e-12, target / (agg + 1e-12), 1.0)
     o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _bucket_call(kernel, x, masks, weights, prev, scales, out_dtype, br, bd,
+                 interpret):
+    """Launch a packed-bucket kernel over a ``(R/br, D/bd)`` grid: the
+    client axis rides whole in each block, bounded by
+    :func:`_client_blocks`; masks and scales go in as ``(R, N)`` so their
+    ``(br, N)`` block is legal for every row block."""
+    n, r, d = x.shape
+    br, bd = _client_blocks(n, r, d, x.dtype, br, bd)
+    rows_by_client = pl.BlockSpec((br, n), lambda i, j: (i, 0))
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), rows_by_client,
+                pl.BlockSpec((n, br, bd), lambda i, j: (0, i, j))]
+    args = [weights.astype(jnp.float32), masks.astype(jnp.float32).T, x]
+    if scales is not None:
+        in_specs.append(rows_by_client)
+        args.append(scales.astype(jnp.float32).T)
+    if prev is not None:
+        in_specs.append(pl.BlockSpec((br, bd), lambda i, j: (i, j)))
+        args.append(prev)
+    return pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(r, br), pl.cdiv(d, bd)),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((br, bd), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((r, d), out_dtype),
+        interpret=interpret,
+    )(*args)
+
+
+def _check_bucket(name, x, masks, prev, scales):
+    n, r, d = x.shape
+    if masks.shape != (n, r):
+        raise ValueError(f"{name}: masks {masks.shape} != ({n}, {r})")
+    if scales is not None and scales.shape != (n, r):
+        raise ValueError(f"{name}: scales {scales.shape} != ({n}, {r})")
+    if prev is not None and prev.shape != (r, d):
+        raise ValueError(f"{name}: prev {prev.shape} != ({r}, {d})")
+
+
+def _dequant(x, scales):
+    xf = x.astype(jnp.float32)
+    if scales is None:
+        return xf
+    return scales.astype(jnp.float32)[:, :, None] * xf
 
 
 def packed_agg_pallas(x, masks, weights, prev=None, *,
@@ -120,51 +185,37 @@ def packed_agg_pallas(x, masks, weights, prev=None, *,
     or None -> (R, D).  The plan path's fused bucket reduction: like
     :func:`rbla_agg_pallas` but with an explicit per-row owner-mask
     matrix (packed rows span many pairs, so a single rank vector cannot
-    describe them) and prev-global retention fused in.  ``norm_restore``
-    adds rbla_norm's per-row norm restoration (full-width blocks: the
-    row-norm reduction cannot cross column tiles).  ``scales``: optional
-    (N, R) f32 per-row dequantization scales fused on the load (int8
-    transport); ``out_dtype`` overrides the output dtype when ``x`` is a
-    wire dtype."""
-    n, r, d = x.shape
-    if masks.shape != (n, r):
-        raise ValueError(f"packed_agg: masks {masks.shape} != ({n}, {r})")
-    if scales is not None and scales.shape != (n, r):
-        raise ValueError(f"packed_agg: scales {scales.shape} != ({n}, {r})")
-    if prev is not None and prev.shape != (r, d):
-        raise ValueError(f"packed_agg: prev {prev.shape} != ({r}, {d})")
-    br, bd = min(br, r), (d if norm_restore else min(bd, d))
-    if norm_restore:
-        # full-width blocks (the row-norm reduction cannot cross column
-        # tiles): bound VMEM by shrinking the row block as the bucket
-        # widens -- the (n, br, d) f32 x block must fit on-chip.  A
-        # two-pass scheme is the follow-on if even br=8 overflows.
-        budget = 4 * 1024 * 1024
-        br = min(br, max(8, (budget // max(n * d * 4, 1)) // 8 * 8))
-    grid = (pl.cdiv(r, br), pl.cdiv(d, bd))
-    in_specs = [
-        pl.BlockSpec((n,), lambda i, j: (0,)),
-        pl.BlockSpec((n, br), lambda i, j: (0, i)),
-        pl.BlockSpec((n, br, bd), lambda i, j: (0, i, j)),
-    ]
-    args = [weights.astype(jnp.float32), masks.astype(jnp.float32), x]
-    if scales is not None:
-        in_specs.append(pl.BlockSpec((n, br), lambda i, j: (0, i)))
-        args.append(scales.astype(jnp.float32))
-    if prev is not None:
-        in_specs.append(pl.BlockSpec((br, bd), lambda i, j: (i, j)))
-        args.append(prev)
-    return pl.pallas_call(
-        functools.partial(_packed_kernel, n_clients=n, norm_by=norm_by,
-                          has_prev=prev is not None,
-                          norm_restore=norm_restore,
-                          has_scales=scales is not None),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((br, bd), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((r, d), out_dtype or x.dtype),
-        interpret=interpret,
-    )(*args)
+    describe them) and prev-global retention fused in.  ``scales``:
+    optional (N, R) f32 per-row dequantization scales fused on the load
+    (int8 transport); ``out_dtype`` overrides the output dtype when ``x``
+    is a wire dtype.
+
+    ``norm_restore`` adds rbla_norm's per-row norm restoration: each
+    output row is rescaled so its L2 norm matches the owners'
+    weighted-mean row norm.  A row norm spans the whole width, which no
+    bounded column block holds at published widths, so the client row
+    norms and the output's rescale are XLA reductions around the tiled
+    kernel."""
+    _check_bucket("packed_agg", x, masks, prev, scales)
+    out_dtype = out_dtype or x.dtype
+    n = x.shape[0]
+    kernel = functools.partial(_packed_kernel, n_clients=n, norm_by=norm_by,
+                               has_prev=prev is not None,
+                               has_scales=scales is not None)
+    if not norm_restore:
+        return _bucket_call(kernel, x, masks, weights, prev, scales,
+                            out_dtype, br, bd, interpret)
+    out = _bucket_call(kernel, x, masks, weights, prev, scales, jnp.float32,
+                       br, bd, interpret)
+    m = masks.astype(jnp.float32)
+    xf = _dequant(x, scales)
+    row_norms = m * jnp.sqrt(jnp.sum(xf * xf, axis=-1))          # (N, R)
+    own = (m > 0).astype(jnp.float32) * weights.astype(jnp.float32)[:, None]
+    target = jnp.sum(own * row_norms, axis=0) / (jnp.sum(own, axis=0)
+                                                 + 1e-12)
+    agg = jnp.sqrt(jnp.sum(out * out, axis=1))
+    out = out * jnp.where(agg > 1e-12, target / (agg + 1e-12), 1.0)[:, None]
+    return out.astype(out_dtype)
 
 
 #: sentinel for unowned slots in the order-statistic kernels (matches
@@ -173,58 +224,36 @@ _SENTINEL = 1e30
 
 
 def _packed_robust_kernel(weights_ref, masks_ref, x_ref, *rest,
-                          n_clients: int, mode: str, clip_norm: float,
-                          trim_frac: float, has_prev: bool,
-                          has_scales: bool = False):
-    """Byzantine-robust fused bucket reduction (plan path).
+                          n_clients: int, mode: str, trim_frac: float,
+                          has_prev: bool, has_scales: bool = False):
+    """Per-coordinate order statistics over a packed bucket (plan path).
 
-    Same packed layout as :func:`_packed_kernel`.  ``mode="clipped"``
-    rescales each client row to at most ``clip_norm`` L2 (full-width
-    blocks -- the norm reduction cannot cross column tiles) before the
-    standard masked weighted mean.  ``mode="trimmed"``/``"median"`` run
-    per-coordinate order statistics over the owners: unowned slots get a
-    large sentinel, a static odd-even transposition network sorts the
-    client axis (``jnp.sort`` does not lower in Mosaic; n is the cohort
-    size, so the O(n^2) compare-exchange unroll stays small), and a
-    per-row owner count selects the retained positions.  Rows nobody
-    owns retain ``prev``.
-
-    ``has_scales`` fuses int8 dequantization on the load exactly as in
-    :func:`_packed_kernel` -- *before* any clip or order statistic, so
-    quantized uploads cannot widen the robustness bounds.
+    Same layout as :func:`_packed_kernel`.  ``mode="trimmed"`` /
+    ``"median"`` run order statistics over each row's owners: unowned
+    slots get a large sentinel, a static odd-even transposition network
+    sorts the client axis (``jnp.sort`` does not lower in Mosaic; n is
+    the cohort size, so the O(n^2) compare-exchange unroll stays small),
+    and a per-row owner count selects the retained positions.  Rows
+    nobody owns retain ``prev``.  ``has_scales`` dequantizes *before*
+    the sort, so quantized uploads cannot widen the robustness bounds.
+    ``weights`` is unused: order statistics on values, not on
+    client-reported masses, is what bounds the breakdown point.
     """
+    del weights_ref
     rest = list(rest)
     scales_ref = rest.pop(0) if has_scales else None
-    if has_prev:
-        prev_ref, o_ref = rest
-    else:
-        (o_ref,) = rest
+    prev_ref = rest.pop(0) if has_prev else None
+    (o_ref,) = rest
     br = x_ref.shape[1]
     fb = (prev_ref[...].astype(jnp.float32) if has_prev
           else jnp.zeros(o_ref.shape, jnp.float32))
-    if mode == "clipped":
-        num = jnp.zeros(o_ref.shape, jnp.float32)
-        den = jnp.zeros((br, 1), jnp.float32)
-        for nix in range(n_clients):                 # static unroll
-            m = masks_ref[nix][:, None]              # (br, 1)
-            w = weights_ref[nix]
-            xn = x_ref[nix].astype(jnp.float32)
-            if has_scales:
-                xn = scales_ref[nix][:, None] * xn   # fused dequant
-            rn = jnp.sqrt(jnp.sum(xn * xn, axis=1, keepdims=True))
-            scale = jnp.minimum(1.0, clip_norm / jnp.maximum(rn, 1e-12))
-            num = num + (w * m) * (scale * xn)
-            den = den + w * m
-        out = jnp.where(den > 0, num / jnp.where(den > 0, den, 1.0), fb)
-        o_ref[...] = out.astype(o_ref.dtype)
-        return
     vals = []
     cnt = jnp.zeros((br, 1), jnp.int32)
     for nix in range(n_clients):
-        m = masks_ref[nix][:, None]                  # (br, 1)
+        m = masks_ref[:, nix:nix + 1]                # (br, 1)
         xn = x_ref[nix].astype(jnp.float32)
         if has_scales:
-            xn = scales_ref[nix][:, None] * xn       # fused dequant
+            xn = scales_ref[:, nix:nix + 1] * xn     # fused dequant
         vals.append(jnp.where(m > 0, xn, _SENTINEL))
         cnt = cnt + (m > 0).astype(jnp.int32)
     for rnd in range(n_clients):                     # odd-even sort
@@ -264,81 +293,96 @@ def packed_robust_pallas(x, masks, weights, prev=None, *, mode: str,
     mean (``"trimmed"``), or coordinate-wise median (``"median"``) in
     place of the weighted mean.  Numerics match
     ``ref.packed_robust_ref``.  ``scales``/``out_dtype`` as in
-    :func:`packed_agg_pallas` (dequant applied before clip/sort)."""
-    n, r, d = x.shape
-    if masks.shape != (n, r):
-        raise ValueError(f"packed_robust: masks {masks.shape} != ({n}, {r})")
-    if scales is not None and scales.shape != (n, r):
-        raise ValueError(f"packed_robust: scales {scales.shape} != "
-                         f"({n}, {r})")
-    if prev is not None and prev.shape != (r, d):
-        raise ValueError(f"packed_robust: prev {prev.shape} != ({r}, {d})")
+    :func:`packed_agg_pallas` (dequant applied before clip/sort).
+
+    Clipping is a per-(client, row) scale ``min(1, clip / ||row||)``:
+    the full-width row norms are one XLA reduction, and the scales ride
+    into the masked-mean kernel on the dequantization operand."""
+    _check_bucket("packed_robust", x, masks, prev, scales)
     if mode not in ("clipped", "trimmed", "median"):
         raise ValueError(f"unknown robust mode {mode!r}; options: "
                          f"['clipped', 'median', 'trimmed']")
-    br = min(br, r)
-    # clipped needs the full row in one block (L2 norm over D); the sort
-    # network keeps n f32 blocks live -- either way, bound VMEM by
-    # shrinking the row block as n*width grows
-    bd = d if mode == "clipped" else min(bd, d)
-    budget = 4 * 1024 * 1024
-    br = min(br, max(8, (budget // max(n * bd * 4, 1)) // 8 * 8))
-    grid = (pl.cdiv(r, br), pl.cdiv(d, bd))
-    in_specs = [
-        pl.BlockSpec((n,), lambda i, j: (0,)),
-        pl.BlockSpec((n, br), lambda i, j: (0, i)),
-        pl.BlockSpec((n, br, bd), lambda i, j: (0, i, j)),
-    ]
-    args = [weights.astype(jnp.float32), masks.astype(jnp.float32), x]
-    if scales is not None:
-        in_specs.append(pl.BlockSpec((n, br), lambda i, j: (0, i)))
-        args.append(scales.astype(jnp.float32))
-    if prev is not None:
-        in_specs.append(pl.BlockSpec((br, bd), lambda i, j: (i, j)))
-        args.append(prev)
-    return pl.pallas_call(
-        functools.partial(_packed_robust_kernel, n_clients=n, mode=mode,
-                          clip_norm=float(clip_norm),
-                          trim_frac=float(trim_frac),
-                          has_prev=prev is not None,
-                          has_scales=scales is not None),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((br, bd), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((r, d), out_dtype or x.dtype),
-        interpret=interpret,
-    )(*args)
+    out_dtype = out_dtype or x.dtype
+    n = x.shape[0]
+    if mode == "clipped":
+        xf = _dequant(x, scales)
+        norms = jnp.sqrt(jnp.sum(xf * xf, axis=-1))               # (N, R)
+        clip = jnp.minimum(1.0, clip_norm / jnp.maximum(norms, 1e-12))
+        row_scales = clip if scales is None else scales * clip
+        kernel = functools.partial(_packed_kernel, n_clients=n,
+                                   norm_by="mask",
+                                   has_prev=prev is not None,
+                                   has_scales=True)
+        return _bucket_call(kernel, x, masks, weights, prev, row_scales,
+                            out_dtype, br, bd, interpret)
+    kernel = functools.partial(_packed_robust_kernel, n_clients=n, mode=mode,
+                               trim_frac=float(trim_frac),
+                               has_prev=prev is not None,
+                               has_scales=scales is not None)
+    return _bucket_call(kernel, x, masks, weights, prev, scales, out_dtype,
+                        br, bd, interpret)
 
 
-def _packed_stack_kernel(scales_ref, x_ref, *rest, copies_x, copies_prev,
-                         has_prev: bool):
-    """Fused FLoRA stacking over a packed bucket: every (pair, layer,
-    contributor) placement is one static sliced copy/scale.  ``copies_x``
-    entries are ``(client, src_row, dst_row, rows, scale_idx)``;
-    ``copies_prev`` drop the client index and read the packed previous
-    global.  Rows no copy touches stay zero (the cap padding)."""
-    if has_prev:
-        prev_ref, o_ref = rest
-    else:
-        (o_ref,) = rest
-    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
-    for (src, s0, d0, nr, si) in copies_x:
-        o_ref[d0:d0 + nr, :] = (
-            scales_ref[si] * x_ref[src, s0:s0 + nr, :].astype(jnp.float32)
-        ).astype(o_ref.dtype)
-    for (s0, d0, nr, si) in copies_prev:
-        o_ref[d0:d0 + nr, :] = (
-            scales_ref[si] * prev_ref[s0:s0 + nr, :].astype(jnp.float32)
-        ).astype(o_ref.dtype)
+def _packed_stack_kernel(*refs, groups, has_prev: bool, window: int,
+                         align: int):
+    """Fused FLoRA stacking as row-range DMAs: every (pair, layer,
+    contributor) placement copies ``nr`` rows from the packed cohort (or
+    the packed previous global) into the output, HBM to HBM.  Copies are
+    grouped by (source, ``nr``) -- the DMA shape must be static -- and
+    each group's ``(src_row, dst_row)`` table arrives by scalar prefetch,
+    so the program is O(#groups) however many layers and clients the
+    cohort has.  At most ``window`` copies are in flight.  Rows no copy
+    touches keep the zeros of the aliased output buffer."""
+    tabs, refs = refs[:len(groups)], refs[len(groups):]
+    x_ref = refs[0]
+    prev_ref = refs[1] if has_prev else None
+    o_ref, sem = refs[-2], refs[-1]
+    for tab, (from_prev, nr, count) in zip(tabs, groups):
+        src_ref = prev_ref if from_prev else x_ref
+
+        def copy(c, tab=tab, src_ref=src_ref, nr=nr):
+            s0 = pl.multiple_of(tab[2 * c], align)
+            d0 = pl.multiple_of(tab[2 * c + 1], align)
+            return pltpu.make_async_copy(src_ref.at[pl.ds(s0, nr)],
+                                         o_ref.at[pl.ds(d0, nr)], sem)
+
+        def start(c, carry, copy=copy):
+            copy(c).start()
+
+            @pl.when(c >= window)
+            def _():
+                copy(c - window).wait()
+            return carry
+
+        def drain(c, carry, copy=copy):
+            copy(c).wait()
+            return carry
+
+        jax.lax.fori_loop(0, count, start, 0)
+        jax.lax.fori_loop(max(count - window, 0), count, drain, 0)
+
+
+def stack_copies_aligned(copies_x, copies_prev, dtype) -> bool:
+    """True when every copy's rows start and end on the row tiling of
+    ``dtype`` -- the condition for :func:`packed_stack_pallas` to run
+    compiled (a DMA moves whole row tiles)."""
+    sub = _sublanes(dtype)
+    return all(v % sub == 0 for c in copies_x for v in c[1:4]) and all(
+        v % sub == 0 for c in copies_prev for v in c[0:3])
 
 
 def packed_stack_pallas(x, scales, prev=None, *, copies_x=(),
-                        copies_prev=(), out_rows: int, bd=DEFAULT_BD,
-                        interpret=True):
+                        copies_prev=(), out_rows: int, interpret=True):
     """x: (N, R_in, D); scales: (S,) f32; prev: (R_prev, D) or None ->
     (out_rows, D).  One launch stacks every packable pair of the cohort
     (the plan path's flora bucket); :func:`flora_stack_pallas` remains
-    the single-pair form."""
+    the single-pair form.  ``copies_x`` entries are ``(client, src_row,
+    dst_row, rows, scale_idx)``; ``copies_prev`` drop the client index
+    and read ``prev``.
+
+    The kernel only moves rows (DMA); each output row's scale is one
+    XLA multiply after it.  Compiled, every copy must be aligned to the
+    row tiling (:func:`stack_copies_aligned`)."""
     n, r_in, d = x.shape
     for (src, s0, d0, nr, si) in copies_x:
         if not (0 <= src < n and 0 <= s0 and s0 + nr <= r_in
@@ -350,27 +394,42 @@ def packed_stack_pallas(x, scales, prev=None, *, copies_x=(),
         if not (0 <= s0 and s0 + nr <= prev.shape[0]
                 and 0 <= d0 and d0 + nr <= out_rows):
             raise ValueError(f"packed_stack: bad prev copy {(s0, d0, nr, si)}")
-    bd = min(bd, d)
-    grid = (pl.cdiv(d, bd),)
-    in_specs = [
-        pl.BlockSpec((scales.shape[0],), lambda j: (0,)),
-        pl.BlockSpec((n, r_in, bd), lambda j: (0, 0, j)),
-    ]
-    args = [scales.astype(jnp.float32), x]
-    if prev is not None:
-        in_specs.append(pl.BlockSpec((prev.shape[0], bd), lambda j: (0, j)))
-        args.append(prev)
-    return pl.pallas_call(
-        functools.partial(_packed_stack_kernel,
-                          copies_x=tuple(copies_x),
-                          copies_prev=tuple(copies_prev),
-                          has_prev=prev is not None),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((out_rows, bd), lambda j: (0, j)),
+    if not interpret and not stack_copies_aligned(copies_x, copies_prev,
+                                                  x.dtype):
+        raise ValueError(
+            f"packed_stack: compiled copies must start and end on "
+            f"{_sublanes(x.dtype)}-row tiles")
+    grouped: dict = {}
+    row_scale = np.full(out_rows, -1, np.int32)
+    for (src, s0, d0, nr, si) in copies_x:
+        grouped.setdefault((False, nr), []).extend((src * r_in + s0, d0))
+        row_scale[d0:d0 + nr] = si
+    for (s0, d0, nr, si) in copies_prev:
+        grouped.setdefault((True, nr), []).extend((s0, d0))
+        row_scale[d0:d0 + nr] = si
+    keys = sorted(grouped)
+    tabs = [jnp.asarray(np.asarray(grouped[k], np.int32)) for k in keys]
+    groups = tuple((k[0], k[1], len(grouped[k]) // 2) for k in keys)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    ins = [x.reshape(n * r_in, d)] + ([prev] if prev is not None else [])
+    ins.append(jnp.zeros((out_rows, d), x.dtype))       # aliased output
+    out = pl.pallas_call(
+        functools.partial(_packed_stack_kernel, groups=groups,
+                          has_prev=prev is not None, window=16,
+                          align=_sublanes(x.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tabs), grid=(1,),
+            in_specs=[hbm] * len(ins), out_specs=hbm,
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
         out_shape=jax.ShapeDtypeStruct((out_rows, d), x.dtype),
+        input_output_aliases={len(tabs) + len(ins) - 1: 0},
         interpret=interpret,
-    )(*args)
+    )(*tabs, *ins)
+    # uncovered rows index the leading 0: they stay zero
+    sc = jnp.concatenate([jnp.zeros((1,), jnp.float32),
+                          jnp.asarray(scales, jnp.float32)])
+    return (sc[jnp.asarray(row_scale + 1)][:, None]
+            * out.astype(jnp.float32)).astype(x.dtype)
 
 
 def _stack_kernel(scales_ref, x_ref, o_ref, *, segs, offs):
@@ -428,12 +487,12 @@ def flora_stack_pallas(x, scales, *, segs: tuple[int, ...], out_rows: int,
 def _axpy_kernel(alpha_ref, x_ref, y_ref, o_ref):
     """Staleness-weighted fold: o = y + alpha_row * (x - y).
 
-    ``alpha`` rides along as a per-row (br,) f32 vector so the same kernel
+    ``alpha`` rides along as a per-row (br, 1) f32 column so the same kernel
     serves both the scalar server-mixing fold (uniform alpha) and RBLA's
     per-rank-row running masked mean (row-dependent alpha: rows the client
     does not own get alpha 0 and pass ``y`` through untouched).
     """
-    a = alpha_ref[...][:, None]                              # (br, 1)
+    a = alpha_ref[...]                                       # (br, 1)
     y = y_ref[...].astype(jnp.float32)
     x = x_ref[...].astype(jnp.float32)
     o_ref[...] = (y + a * (x - y)).astype(o_ref.dtype)
@@ -460,14 +519,14 @@ def axpy_fold_pallas(y, x, alpha, *, br=DEFAULT_BR, bd=DEFAULT_BD,
         _axpy_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((br,), lambda i, j: (i,)),
+            pl.BlockSpec((br, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((br, bd), lambda i, j: (i, j)),
             pl.BlockSpec((br, bd), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((br, bd), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, d), y.dtype),
         interpret=interpret,
-    )(alpha.astype(jnp.float32), x, y)
+    )(alpha.astype(jnp.float32).reshape(r, 1), x, y)
 
 
 def rbla_agg_pallas(x, ranks, weights, *, norm_by: str = "mask",

@@ -17,7 +17,7 @@ import jax               # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.configs import ARCHS, INPUT_SHAPES, get_config       # noqa: E402
-from repro.launch.mesh import make_production_mesh              # noqa: E402
+from repro.launch.mesh import make_pod_mesh                     # noqa: E402
 from repro.lora import attach_ranks, strip_ranks                # noqa: E402
 from repro.models.model import make_model                       # noqa: E402
 from repro.optim import adam, apply_updates                     # noqa: E402
@@ -136,7 +136,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         print(f"[skip] {arch} x {shape_name} x {mesh_name}: {reason}")
         return rec
 
-    mesh = make_production_mesh(multi_pod=multi_pod)
+    mesh = make_pod_mesh(multi_pod=multi_pod)
     chips = mesh.devices.size
     model = make_model(cfg, remat=remat, mla_absorbed=mla_absorbed)
     rec["remat"] = str(remat)

@@ -1,7 +1,8 @@
 """Serving launcher: batched prefill + decode with sharded KV caches.
 
-Identical code path to the decode dry-run; --preset reduced runs it live
-on the container (single device), --preset full on a pod.
+Identical code path to the decode dry-run; --preset reduced runs it on
+one CPU device, --preset full at published widths on a mesh over the
+devices present.
 """
 from __future__ import annotations
 
@@ -13,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
-from repro.launch.mesh import make_production_mesh, make_test_mesh
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_device_mesh, make_test_mesh
 from repro.models.model import make_model
 
 
@@ -27,13 +29,14 @@ def main():
     ap.add_argument("--new", type=int, default=16)
     ap.add_argument("--rank", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.preset == "reduced":
         cfg = cfg.reduced()
         mesh = make_test_mesh((1, 1))
     else:
-        mesh = make_production_mesh()
+        mesh = make_device_mesh()
 
     model = make_model(cfg, remat=False)
     with mesh:

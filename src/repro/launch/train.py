@@ -1,8 +1,9 @@
-"""Production training launcher: mesh + sharded LoRA fine-tuning loop.
+"""Training launcher: sharded LoRA fine-tuning loop, then the cohort upload.
 
-On a real TPU pod this runs under `python -m repro.launch.train --arch ...`
-with the production mesh; on the CPU container use --preset reduced
-(single device, reduced config) to exercise the identical code path.
+``--preset full`` trains the architecture at its published widths on a
+mesh over the devices present (``python -m repro.launch.train``; one v5e
+chip holds h2o-danube-3-4b in bf16); ``--preset reduced`` runs the same
+code path on a reduced config on one CPU device.
 
 The loop is the pod-side of FLaaS: one client cohort's local steps.  The
 FL simulator (repro.fl) drives many such loops + RBLA aggregation.
@@ -20,14 +21,18 @@ from repro.checkpoint import save as ckpt_save
 from repro.configs import get_config, INPUT_SHAPES
 from repro.core.strategy import get_strategy, list_strategies
 from repro.data import make_lm_dataset
-from repro.launch.mesh import make_production_mesh, make_test_mesh
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_device_mesh, make_test_mesh
 from repro.lora import attach_ranks, strip_ranks
 from repro.models.model import make_model
 from repro.optim import adam, apply_updates
 from repro.sharding import rules
 
 
-def main():
+def main(argv=None):
+    """Run the launcher on ``argv`` (default: the command line); returns
+    the trained adapters (ranks attached).  Raises on a non-finite loss
+    and when the strategy cannot aggregate the cohort upload."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="h2o-danube-3-4b")
     ap.add_argument("--preset", default="reduced",
@@ -37,22 +42,22 @@ def main():
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--rank", type=int, default=8)
     ap.add_argument("--lr", type=float, default=1e-3)
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--method", default="rbla",
                     help="server aggregation strategy for the cohort "
                          f"upload: one of {list_strategies()}")
     ap.add_argument("--agg-backend", default="auto",
                     choices=["auto", "ref", "pallas", "distributed"])
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     strategy = get_strategy(args.method)   # fail fast on typos
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.preset == "reduced":
         cfg = cfg.reduced()
         mesh = make_test_mesh((1, 1))
     else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+        mesh = make_device_mesh()
 
     model = make_model(cfg, remat=args.preset == "full")
     with mesh:
@@ -70,8 +75,10 @@ def main():
         data = make_lm_dataset(cfg.vocab_size, args.seq + 1,
                                n_seqs=args.batch * 32, seed=42)
 
+        # the base weights are an argument, never a closure: a jitted
+        # closure embeds them as constants of the compiled program
         @jax.jit
-        def step(factors, opt_state, tokens):
+        def step(params, factors, opt_state, tokens):
             def loss_fn(f):
                 return model.loss(params, attach_ranks(f, ranks),
                                   {"tokens": tokens})
@@ -83,8 +90,10 @@ def main():
         t0 = time.time()
         for i in range(args.steps):
             ix = rng.integers(0, len(data), args.batch)
-            factors, opt_state, loss = step(factors, opt_state,
+            factors, opt_state, loss = step(params, factors, opt_state,
                                             jnp.asarray(data[ix]))
+            if not np.isfinite(float(loss)):
+                raise FloatingPointError(f"step {i}: loss {float(loss)}")
             if i % max(1, args.steps // 10) == 0:
                 print(f"step {i:4d} loss {float(loss):.4f} "
                       f"({(time.time() - t0) / (i + 1):.2f}s/step)",
@@ -95,23 +104,16 @@ def main():
         # r_max=args.rank keeps the live rank (and the alpha/rank forward
         # scale) identical to the model that was just trained
         trained = attach_ranks(factors, ranks)
-        try:
-            global_adapters = strategy.aggregate_adapters(
-                [trained], jnp.ones(1), r_max=args.rank,
-                client_ranks=jnp.asarray([args.rank]),
-                backend=args.agg_backend)
-            print(f"aggregated cohort upload via strategy={strategy.name} "
-                  f"backend={args.agg_backend}")
-        except NotImplementedError as e:
-            # e.g. svd on layer-stacked pairs: don't lose the run --
-            # checkpoint the raw trained adapters instead
-            print(f"WARNING: strategy={strategy.name} cannot aggregate "
-                  f"this adapter structure ({e}); saving unaggregated "
-                  "adapters")
-            global_adapters = trained
+        global_adapters = strategy.aggregate_adapters(
+            [trained], jnp.ones(1), r_max=args.rank,
+            client_ranks=jnp.asarray([args.rank]),
+            backend=args.agg_backend)
+        print(f"aggregated cohort upload via strategy={strategy.name} "
+              f"backend={args.agg_backend}")
         if args.ckpt:
             ckpt_save(args.ckpt, global_adapters)
             print(f"saved aggregated adapters to {args.ckpt}")
+    return trained
 
 
 if __name__ == "__main__":

@@ -32,6 +32,8 @@ from typing import Any
 
 import jax
 
+from repro.core.compat import trace_state_clean
+
 from .metrics import LATENCY_BUCKETS, get_registry
 
 #: the canonical round-lifecycle stages (free-form stage names are
@@ -42,10 +44,7 @@ ROUND_STAGES = ("submit", "buffer", "flush", "replay", "fold", "publish",
 
 def _trace_clean() -> bool:
     """True when JAX is *not* currently tracing (spans may run)."""
-    try:
-        return bool(jax.core.trace_state_clean())
-    except AttributeError:      # very old / very new jax: fail open as
-        return True             # "not tracing" (spans are host-called)
+    return trace_state_clean()
 
 
 class EventLog:

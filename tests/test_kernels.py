@@ -20,6 +20,7 @@ LM_SHAPES = [
     (64, 384, 512, 64),
     (100, 200, 300, 4),      # unaligned -> padding path
     (512, 256, 128, 128),
+    (64, 640, 128, 8),       # K not a multiple of the 512 k-block
 ]
 
 

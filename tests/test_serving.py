@@ -66,6 +66,22 @@ def test_batched_matches_ref(impl, interpret, dtype, tol):
                                rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 3e-2)])
+def test_batched_kernel_k_axis_not_a_multiple_of_the_block(dtype, tol):
+    """fan_in 640 is no multiple of the 512-wide k-block: the kernel must
+    still reduce exactly over the real K (a partial k-block reads past
+    the array's end -- NaN in interpret mode, garbage on the chip)."""
+    case = packed_case(m=16, k=640, n=128, dtype=dtype)
+    x, w, a_rows, b_rows, off, rank, scale, ids = case
+    got = batched_lora_matmul_inline(x, w, a_rows, b_rows, ids, off, rank,
+                                     scale, impl="pallas", interpret=True)
+    want = np.asarray(ref_out(*case), np.float32)
+    # 640-term sums of O(1) products: the tolerance scales with them
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               rtol=tol, atol=tol * np.abs(want).max())
+
+
 @pytest.mark.parametrize("impl,interpret", [("xla", None),
                                             ("pallas", True)])
 def test_adapter_id_permutation_equivariance(impl, interpret):
