@@ -29,8 +29,10 @@ script with a non-zero exit.  The last line of a passing run is one JSON
 object: ``{"ok": true, "device": {"platform", "kind", "count"}, ...}``.
 
 ``--four-chips`` runs only the rbla cohort over a 4-chip client mesh
-(``backend="distributed"``, the shard_map masked psum) against
-``backend="ref"``, and prints where each input's shards sit.
+through ``aggregate_adapters(backend="distributed", mesh=...)`` (the
+shard_map masked psum) against ``backend="ref"``, and prints which
+clients' rows of each packed buffer the collective received on each
+chip.
 
 Weights and uploads are random, made from ``--seed``.
 """
@@ -229,6 +231,9 @@ def run_single_chip(*, preset: str, seq: int, seed: int, interpret: bool,
 
     # ---- async: one fold per upload --------------------------------------
     t0 = time.perf_counter()
+    folds = get_registry().get("kernel_dispatches_total").labels(
+        entry="axpy_fold")
+    before = folds.value
     service = AsyncAggregator(
         "rbla", ServerState(adapters=prev, base_trainable={}, r_max=r_max),
         staleness="constant", backend="pallas", interpret=interpret)
@@ -238,14 +243,15 @@ def run_single_chip(*, preset: str, seq: int, seed: int, interpret: bool,
     folded = service.state.adapters
     jax.block_until_ready(folded)
     err, ratio = parity(folded, g_rbla, **PLAN_TOL)
-    buckets = sum(n for _, n in
-                  service.strategy.__dict__["_fold_plan_cache"].values())
+    launches = int(folds.value - before)
     phases["async"] = report("async fold", parity_max_abs=err,
                              parity_ratio=ratio, folds=service.n_folded,
-                             axpy_fold_launches=buckets * service.n_folded,
+                             axpy_fold_launches=launches,
                              wall_s=round(time.perf_counter() - t0, 1))
     check(ratio <= 1.0, "async: fold differs from the sync rbla round")
     check(service.n_folded == len(uploads), "async: not every upload folded")
+    if require_kernels:
+        check(launches > 0, "async: no axpy_fold kernel ran")
     del service, folded, uploads
 
     # ---- publish and serve layer 0 of the global --------------------------
@@ -289,15 +295,17 @@ def run_single_chip(*, preset: str, seq: int, seed: int, interpret: bool,
     return phases
 
 
-def run_four_chips(*, preset: str, seed: int) -> dict:
-    """The rbla cohort over a 4-chip client mesh against ``ref``."""
+def run_four_chips(*, preset: str, seed: int, interpret: bool) -> dict:
+    """The rbla cohort over a 4-chip client mesh against ``ref``, through
+    ``aggregate_adapters(backend="distributed", mesh=...)`` as a user
+    calls it; prints where the plan's packed client buffers -- what its
+    shard_map collective receives -- sit on the chips."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import Mesh
     from repro.configs import get_config
-    from repro.core import get_strategy, stack_trees
-    from repro.core.plan import build_cohort_spec
+    from repro.core import get_strategy
     from repro.models.model import make_model
 
     devs = jax.devices()
@@ -307,35 +315,37 @@ def run_four_chips(*, preset: str, seed: int) -> dict:
         cfg = cfg.reduced()
     r_max = cfg.lora_r_max
     uploads, ranks, w, prev = make_cohort(make_model(cfg), r_max, seed)
+    homes = sorted({d.id for u in uploads for x in jax.tree.leaves(u)
+                    for d in x.sharding.device_set})
+    print(f"[four-chip placement] uploads on devices {homes}", flush=True)
 
     t0 = time.perf_counter()
     mesh = Mesh(np.asarray(devs), ("clients",))
-    by_client = NamedSharding(mesh, P("clients"))
-    stacked = jax.tree.map(
-        lambda x: jax.device_put(x, by_client) if x.dtype != jnp.int32
-        else x, stack_trees(uploads))
-    for path, leaf in jax.tree_util.tree_leaves_with_path(stacked):
-        if leaf.dtype == jnp.int32:
-            continue
-        where = " ".join(
-            f"chip{s.device.id}:clients[{s.index[0].start}:"
-            f"{s.index[0].stop}]" for s in leaf.addressable_shards)
-        print(f"[four-chip placement] {jax.tree_util.keystr(path)} "
-              f"{leaf.shape} {where}", flush=True)
     strategy = get_strategy("rbla").with_options()
-    spec = build_cohort_spec(stacked, kind="distributed", r_max=r_max,
-                             client_ranks=np.asarray(ranks), prev_tree=prev,
-                             mesh=mesh, client_axis="clients")
-    rd = strategy.plan(None, spec)
-    got = rd(stacked, w, prev)
+    got = strategy.aggregate_adapters(
+        uploads, w, r_max=r_max, client_ranks=jnp.asarray(ranks),
+        prev_global=prev, backend="distributed", mesh=mesh,
+        client_axis="clients", interpret=interpret)
     jax.block_until_ready(got)
+    rd = next(r for r in strategy.__dict__["_plan_cache"].values()
+              if r.spec.kind == "distributed")
+    check(rd.input_shardings is not None, "four-chip: no packed inputs seen")
+    for bi, (shape, sharding) in enumerate(rd.input_shardings):
+        where = sorted((d.id, idx[0].start or 0, idx[0].stop or shape[0])
+                       for d, idx in sharding.devices_indices_map(
+                           shape).items())
+        print(f"[four-chip placement] bucket {bi} {shape} " + " ".join(
+            f"chip{c}:clients[{a}:{b}]" for c, a, b in where), flush=True)
+        check(len({c for c, _, _ in where}) == 4
+              and all(b - a == shape[0] // 4 for _, a, b in where),
+              f"four-chip: bucket {bi} is not split over the 4 chips")
     out = jax.tree.leaves(got)[0]
     print(f"[four-chip placement] output on devices "
           f"{sorted(d.id for d in out.sharding.device_set)}", flush=True)
     per_device = [(d.memory_stats() or {}).get("peak_bytes_in_use", "n/a")
                   for d in devs]
     got = jax.device_put(got, devs[0])
-    del stacked
+    del strategy
     with jax.default_matmul_precision("highest"):
         want = get_strategy("rbla").with_options().aggregate_adapters(
             uploads, w, r_max=r_max, client_ranks=jnp.asarray(ranks),
@@ -369,7 +379,8 @@ def main(argv=None) -> int:
     print(f"compile cache: {enable_compile_cache()}", flush=True)
     try:
         if args.four_chips:
-            phases = run_four_chips(preset="full", seed=args.seed)
+            phases = run_four_chips(preset="full", seed=args.seed,
+                                    interpret=False)
         else:
             phases = run_single_chip(preset="full", seq=512, seed=args.seed,
                                      interpret=False, impl="pallas",

@@ -21,11 +21,21 @@ def trace_state_clean() -> bool:
     return bool(_jax_core.trace_state_clean())
 
 
+def count_primitive(jaxpr, name: str) -> int:
+    """Equations of primitive ``name`` in ``jaxpr`` (open or closed)
+    and in every jaxpr nested in its equations' params."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    return sum((eqn.primitive.name == name)
+               + sum(count_primitive(sub, name)
+                     for sub in _jax_core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
 def shard_map_no_check(body, mesh, in_specs, out_specs):
     """``shard_map`` with replication checking disabled."""
     return shard_map(body, mesh=mesh, in_specs=in_specs,
                      out_specs=out_specs, check_vma=False)
 
 
-__all__ = ["axis_size", "shard_map", "shard_map_no_check",
-           "trace_state_clean"]
+__all__ = ["axis_size", "count_primitive", "shard_map",
+           "shard_map_no_check", "trace_state_clean"]

@@ -565,6 +565,10 @@ class CompiledRound:
         lowering instead -- interpret mode, unaligned stack copies, mixed
         codec means; None where the plan cannot tell: eager and jit
         rounds).
+    ``input_shardings``
+        ``(shape, sharding)`` of each packed client buffer the last call
+        handed to its collective (distributed plans; None elsewhere) --
+        where each chip's share of the cohort actually sat.
     """
 
     def __init__(self, strategy, spec: CohortSpec, kind: str,
@@ -578,6 +582,7 @@ class CompiledRound:
         self.n_kernel_launches = n_kernel_launches
         self.n_fallback_pairs = n_fallback_pairs
         self.n_pallas_launches = n_pallas_launches
+        self.input_shardings = None
         self.n_calls = 0
 
     def __call__(self, stacked_tree: PyTree, weights, prev_tree=None,
@@ -1066,7 +1071,7 @@ def _build_mean_distributed(strategy, spec, buckets, masks_const,
     """Packed shard_map: one collective round over the bucket buffers
     (clients sharded over the mesh axis, masks ride along sharded, the
     combine + prev retention computed replicated)."""
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     n = spec.n_clients
     mesh = spec.mesh
@@ -1118,6 +1123,8 @@ def _build_mean_distributed(strategy, spec, buckets, masks_const,
                          for s in b.slots]
                 prevs.append(jnp.concatenate(parts, axis=0)
                              if len(parts) > 1 else parts[0])
+        if not any(isinstance(x, jax.core.Tracer) for x in xs):
+            rd.input_shardings = [(x.shape, x.sharding) for x in xs]
         outs = shard_fn(xs, masks_const, wt, prevs)
         return [
             {s.side: _unpack_slot(outs[bi], s, spec.pairs[s.pair_idx])
@@ -1127,18 +1134,23 @@ def _build_mean_distributed(strategy, spec, buckets, masks_const,
 
     rebuild = [None]
 
+    by_client = NamedSharding(mesh, P(ax))
+
     def execute(stacked_tree, w, prev_tree, donate):
         if rebuild[0] is None:
             rebuild[0] = _make_rebuilder(stacked_tree)
-        ab = _ab_list(stacked_tree)
+        # each chip receives only its clients' rows before the packing,
+        # so no chip ever holds the whole packed cohort
+        ab = jax.device_put(_ab_list(stacked_tree), by_client)
         prev_ab = _ab_list(prev_tree) if retains else None
         outs = round_fn(ab, w, prev_ab)
         pairs = [{"A": o["A"], "B": o["B"], "rank": rank_leaves[i]}
                  for i, o in enumerate(outs)]
         return rebuild[0](pairs)
 
-    return CompiledRound(strategy, spec, "packed", execute,
-                         n_kernel_launches=len(buckets), n_pallas_launches=0)
+    rd = CompiledRound(strategy, spec, "packed", execute,
+                       n_kernel_launches=len(buckets), n_pallas_launches=0)
+    return rd
 
 
 # ----------------------------------------------------- packed stack plans --
@@ -1257,15 +1269,18 @@ def _build_stack_round(strategy, spec: CohortSpec) -> CompiledRound:
     # so the fused stacking loses to XLA there; the copies are static
     # slices either way, so the ref lowering is just as fused (and is the
     # only lowering the "ref" backend may use).  The compiled kernel moves
-    # whole row tiles, so a bucket with a copy off the tiling (a rank not
-    # a multiple of 8) stacks in XLA too.
-    from repro.kernels.rbla_agg.kernel import stack_copies_aligned
+    # whole row tiles, so a bucket with a copy off the tiling stacks in
+    # XLA too.  _pack_side packs every bucket in f32, whatever the
+    # cohort's dtype, so f32's 8-row tiling is the one that applies.
+    from repro.kernels.rbla_agg.ops import packed_stack_compiles
     from repro.kernels.runtime import auto_interpret
     use_kernel = [spec.kind == "pallas"
                   and not auto_interpret(spec.interpret)
-                  and stack_copies_aligned(m["copies_x"], m["copies_prev"],
-                                           jnp.float32)
-                  for m in bucket_meta]
+                  and packed_stack_compiles(
+                      m["copies_x"], m["copies_prev"],
+                      r_in=sum(s.rows for s in b.slots),
+                      out_rows=m["out_rows"], d=b.width, dtype=jnp.float32)
+                  for b, m in zip(buckets, bucket_meta)]
 
     def round_fn(ab, wt_raw, prev_ab):
         wt = wt_raw

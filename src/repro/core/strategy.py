@@ -1014,19 +1014,26 @@ class RBLAStrategy(AggregationStrategy):
                 or sp["B"].shape != up["B"].shape
                 for (_, sp), (_, up) in zip(state_pairs, upd_pairs)):
             return None
+        from repro.kernels.runtime import count_dispatch
+
+        from .compat import count_primitive
         cache = self.__dict__.setdefault("_fold_plan_cache", {})
         entry = cache.get(spec)
         if entry is None:
-            entry = build_fold_plan(self, spec)
-            cache[spec] = entry
-        fold_fn, _ = entry
+            entry = cache[spec] = [build_fold_plan(self, spec)[0], None]
+        fold_fn = entry[0]
         state_ab = [{"A": p["A"], "B": p["B"]} for _, p in state_pairs]
         upd_ab = [{"A": p["A"], "B": p["B"]} for _, p in upd_pairs]
         rank_leaves = [jnp.asarray(p["rank"], jnp.int32)
                        for _, p in upd_pairs]
-        mass_leaves = _flat_pair_values(row_mass)
-        new_ab, new_mass = fold_fn(state_ab, upd_ab, mass_leaves,
-                                   jnp.float32(wa), rank_leaves)
+        args = (state_ab, upd_ab, _flat_pair_values(row_mass),
+                jnp.float32(wa), rank_leaves)
+        if entry[1] is None:
+            # the kernel launches of one fold, read off its program
+            entry[1] = count_primitive(fold_fn.trace(*args).jaxpr,
+                                       "pallas_call")
+        new_ab, new_mass = fold_fn(*args)
+        count_dispatch(entry[1], kernel="axpy_fold")
         rebuild = _make_rebuilder(adapters)
         new_adapters = rebuild(
             [{"A": o["A"], "B": o["B"], "rank": p["rank"]}

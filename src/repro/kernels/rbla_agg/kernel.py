@@ -24,6 +24,7 @@ block; ``tests/test_tpu_compile.py`` compiles them for a v5e chip.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -67,7 +68,7 @@ _CLIENT_BLOCK_BYTES = 2 * 1024 * 1024
 
 def _sublanes(dtype) -> int:
     """Row tiling of ``dtype`` on TPU: 8 rows of f32, 16 of bf16, 32 of
-    int8 -- the multiple a row block (and a DMA'd row range) must be."""
+    int8 -- the multiple a row block must be."""
     return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
@@ -323,52 +324,45 @@ def packed_robust_pallas(x, masks, weights, prev=None, *, mode: str,
                         br, bd, interpret)
 
 
-def _packed_stack_kernel(*refs, groups, has_prev: bool, window: int,
-                         align: int):
-    """Fused FLoRA stacking as row-range DMAs: every (pair, layer,
-    contributor) placement copies ``nr`` rows from the packed cohort (or
-    the packed previous global) into the output, HBM to HBM.  Copies are
-    grouped by (source, ``nr``) -- the DMA shape must be static -- and
-    each group's ``(src_row, dst_row)`` table arrives by scalar prefetch,
-    so the program is O(#groups) however many layers and clients the
-    cohort has.  At most ``window`` copies are in flight.  Rows no copy
-    touches keep the zeros of the aliased output buffer."""
-    tabs, refs = refs[:len(groups)], refs[len(groups):]
-    x_ref = refs[0]
-    prev_ref = refs[1] if has_prev else None
-    o_ref, sem = refs[-2], refs[-1]
-    for tab, (from_prev, nr, count) in zip(tabs, groups):
-        src_ref = prev_ref if from_prev else x_ref
+def _packed_stack_kernel(xblk_ref, pblk_ref, tag_ref, scales_ref, x_ref,
+                         *rest):
+    """Fused FLoRA stacking, one output row tile per grid step.  The host
+    table gives each tile its source -- a tile of the packed cohort
+    (``kind`` 1), of the packed previous global (2), or none (0: the cap
+    padding, written as zeros) -- and the index of its scale, packed as
+    ``tag = 4 * scale_idx + kind``.  The index maps read the source
+    tables, so every output row is read once, scaled in VMEM and written
+    once."""
+    del xblk_ref, pblk_ref                    # read by the index maps
+    o_ref = rest[-1]
+    tag = tag_ref[pl.program_id(0)]
+    kind = tag % 4
+    scale = scales_ref[tag // 4]
 
-        def copy(c, tab=tab, src_ref=src_ref, nr=nr):
-            s0 = pl.multiple_of(tab[2 * c], align)
-            d0 = pl.multiple_of(tab[2 * c + 1], align)
-            return pltpu.make_async_copy(src_ref.at[pl.ds(s0, nr)],
-                                         o_ref.at[pl.ds(d0, nr)], sem)
+    @pl.when(kind == 0)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-        def start(c, carry, copy=copy):
-            copy(c).start()
-
-            @pl.when(c >= window)
-            def _():
-                copy(c - window).wait()
-            return carry
-
-        def drain(c, carry, copy=copy):
-            copy(c).wait()
-            return carry
-
-        jax.lax.fori_loop(0, count, start, 0)
-        jax.lax.fori_loop(max(count - window, 0), count, drain, 0)
+    for k, src_ref in enumerate((x_ref,) + tuple(rest[:-1]), start=1):
+        @pl.when(kind == k)
+        def _(src_ref=src_ref):
+            o_ref[...] = (scale * src_ref[...].astype(jnp.float32)
+                          ).astype(o_ref.dtype)
 
 
-def stack_copies_aligned(copies_x, copies_prev, dtype) -> bool:
-    """True when every copy's rows start and end on the row tiling of
-    ``dtype`` -- the condition for :func:`packed_stack_pallas` to run
-    compiled (a DMA moves whole row tiles)."""
-    sub = _sublanes(dtype)
-    return all(v % sub == 0 for c in copies_x for v in c[1:4]) and all(
-        v % sub == 0 for c in copies_prev for v in c[0:3])
+def stack_row_tile(copies_x, copies_prev, r_in: int, out_rows: int,
+                   d: int, dtype, *, interpret: bool) -> int:
+    """Rows per grid step of :func:`packed_stack_pallas`: the largest
+    divisor of every copy bound (and of ``r_in`` and ``out_rows``) whose
+    ``(tile, d)`` f32 block fits half the client block budget.  Compiled,
+    the tile must be a multiple of ``dtype``'s row tiling; 0 means no
+    such tile exists (the caller stacks in XLA)."""
+    g = math.gcd(r_in, out_rows, *(v for c in copies_x for v in c[1:4]),
+                 *(v for c in copies_prev for v in c[0:3]))
+    sub = 1 if interpret else _sublanes(dtype)
+    fits = [t for t in range(sub, g + 1, sub)
+            if g % t == 0 and t * d * 4 <= _CLIENT_BLOCK_BYTES // 2]
+    return max(fits, default=0)
 
 
 def packed_stack_pallas(x, scales, prev=None, *, copies_x=(),
@@ -378,11 +372,10 @@ def packed_stack_pallas(x, scales, prev=None, *, copies_x=(),
     (the plan path's flora bucket); :func:`flora_stack_pallas` remains
     the single-pair form.  ``copies_x`` entries are ``(client, src_row,
     dst_row, rows, scale_idx)``; ``copies_prev`` drop the client index
-    and read ``prev``.
+    and read ``prev``; a later copy overwrites an earlier one's rows.
 
-    The kernel only moves rows (DMA); each output row's scale is one
-    XLA multiply after it.  Compiled, every copy must be aligned to the
-    row tiling (:func:`stack_copies_aligned`)."""
+    The grid walks the output in row tiles (:func:`stack_row_tile`);
+    compiled, every copy must start and end on ``x``'s row tiling."""
     n, r_in, d = x.shape
     for (src, s0, d0, nr, si) in copies_x:
         if not (0 <= src < n and 0 <= s0 and s0 + nr <= r_in
@@ -394,42 +387,50 @@ def packed_stack_pallas(x, scales, prev=None, *, copies_x=(),
         if not (0 <= s0 and s0 + nr <= prev.shape[0]
                 and 0 <= d0 and d0 + nr <= out_rows):
             raise ValueError(f"packed_stack: bad prev copy {(s0, d0, nr, si)}")
-    if not interpret and not stack_copies_aligned(copies_x, copies_prev,
-                                                  x.dtype):
+    dtypes = [x.dtype] + ([prev.dtype] if prev is not None else [])
+    tile = min(stack_row_tile(copies_x, copies_prev, r_in, out_rows, d, dt,
+                              interpret=interpret) for dt in dtypes)
+    if not tile:
         raise ValueError(
             f"packed_stack: compiled copies must start and end on "
             f"{_sublanes(x.dtype)}-row tiles")
-    grouped: dict = {}
-    row_scale = np.full(out_rows, -1, np.int32)
+    nblk = out_rows // tile
+    xblk = np.full(nblk, -1, np.int32)
+    pblk = np.full(nblk, -1, np.int32)
+    tag = np.zeros(nblk, np.int32)
     for (src, s0, d0, nr, si) in copies_x:
-        grouped.setdefault((False, nr), []).extend((src * r_in + s0, d0))
-        row_scale[d0:d0 + nr] = si
+        k = np.arange(d0 // tile, (d0 + nr) // tile)
+        xblk[k] = (src * r_in + s0) // tile + (k - d0 // tile)
+        tag[k] = 4 * si + 1
     for (s0, d0, nr, si) in copies_prev:
-        grouped.setdefault((True, nr), []).extend((s0, d0))
-        row_scale[d0:d0 + nr] = si
-    keys = sorted(grouped)
-    tabs = [jnp.asarray(np.asarray(grouped[k], np.int32)) for k in keys]
-    groups = tuple((k[0], k[1], len(grouped[k]) // 2) for k in keys)
-    hbm = pl.BlockSpec(memory_space=pl.ANY)
-    ins = [x.reshape(n * r_in, d)] + ([prev] if prev is not None else [])
-    ins.append(jnp.zeros((out_rows, d), x.dtype))       # aliased output
-    out = pl.pallas_call(
-        functools.partial(_packed_stack_kernel, groups=groups,
-                          has_prev=prev is not None, window=16,
-                          align=_sublanes(x.dtype)),
+        k = np.arange(d0 // tile, (d0 + nr) // tile)
+        pblk[k] = s0 // tile + (k - d0 // tile)
+        tag[k] = 4 * si + 2
+    # a tile that does not read a source keeps that source's previous
+    # block index, so the pipeline does not fetch it again
+    for blk in (xblk, pblk):
+        last = 0
+        for k in range(nblk):
+            if blk[k] < 0:
+                blk[k] = last
+            last = blk[k]
+    row = lambda k, xb, pb, tg: (xb[k], 0)          # noqa: E731
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((tile, d), row)]
+    args = [jnp.asarray(scales, jnp.float32), x.reshape(n * r_in, d)]
+    if prev is not None:
+        in_specs.append(pl.BlockSpec((tile, d),
+                                     lambda k, xb, pb, tg: (pb[k], 0)))
+        args.append(prev)
+    return pl.pallas_call(
+        _packed_stack_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(tabs), grid=(1,),
-            in_specs=[hbm] * len(ins), out_specs=hbm,
-            scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+            num_scalar_prefetch=3, grid=(nblk,), in_specs=in_specs,
+            out_specs=pl.BlockSpec((tile, d),
+                                   lambda k, xb, pb, tg: (k, 0))),
         out_shape=jax.ShapeDtypeStruct((out_rows, d), x.dtype),
-        input_output_aliases={len(tabs) + len(ins) - 1: 0},
         interpret=interpret,
-    )(*tabs, *ins)
-    # uncovered rows index the leading 0: they stay zero
-    sc = jnp.concatenate([jnp.zeros((1,), jnp.float32),
-                          jnp.asarray(scales, jnp.float32)])
-    return (sc[jnp.asarray(row_scale + 1)][:, None]
-            * out.astype(jnp.float32)).astype(x.dtype)
+    )(jnp.asarray(xblk), jnp.asarray(pblk), jnp.asarray(tag), *args)
 
 
 def _stack_kernel(scales_ref, x_ref, o_ref, *, segs, offs):

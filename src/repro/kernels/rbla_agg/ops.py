@@ -18,9 +18,9 @@ import jax
 import jax.numpy as jnp
 
 from ..runtime import auto_interpret, count_dispatch, note_trace
-from .kernel import (axpy_fold_pallas, flora_stack_pallas,
+from .kernel import (_sublanes, axpy_fold_pallas, flora_stack_pallas,
                      packed_agg_pallas, packed_robust_pallas,
-                     packed_stack_pallas, rbla_agg_pallas)
+                     packed_stack_pallas, rbla_agg_pallas, stack_row_tile)
 from .ref import (axpy_fold_ref, flora_stack_ref, packed_agg_ref,
                   packed_robust_ref, packed_stack_ref, rbla_agg_ref)
 
@@ -202,25 +202,43 @@ def packed_robust(x, masks, weights, prev=None, *, mode: str,
                               out_dtype=out_dtype, interpret=interpret)
 
 
+def _stack_dims(r_in: int, out_rows: int, d: int, dtype):
+    """(R_in, out_rows, D) as :func:`packed_stack_inline` pads them: rows
+    to ``dtype``'s row tiling, D to lane alignment."""
+    sub = _sublanes(dtype)
+    return (_pad_to(max(r_in, 1), sub), _pad_to(max(out_rows, 1), sub),
+            _pad_to(max(d, 1), 128))
+
+
+def packed_stack_compiles(copies_x, copies_prev, *, r_in: int,
+                          out_rows: int, d: int, dtype) -> bool:
+    """True when :func:`packed_stack_inline` can run compiled on these
+    copies: each starts and ends on ``dtype``'s row tiling and a row
+    tile of the padded width fits VMEM (see ``stack_row_tile``)."""
+    rp, op, dp = _stack_dims(r_in, out_rows, d, dtype)
+    return stack_row_tile(copies_x, copies_prev, rp, op, dp, dtype,
+                          interpret=False) > 0
+
+
 def packed_stack_inline(x, scales, prev=None, *, copies_x=(),
                         copies_prev=(), out_rows: int, interpret=None):
     """Un-jitted fused stacking over a packed bucket (flora plan path).
 
     ``x``: (N, R_in, D); ``scales``: (S,); ``prev``: (R_prev, D) or None;
     the static ``copies_*`` describe every (pair, layer, contributor)
-    placement (see ``packed_stack_pallas``).  D is padded to lane
-    alignment and stripped; row padding never collides with copies.
+    placement (see ``packed_stack_pallas``).  Rows are padded to the row
+    tiling and D to lane alignment, then stripped; padding never
+    collides with copies.
     """
     interpret = auto_interpret(interpret)
     n, r_in, d = x.shape
-    rp, dp = _pad_to(max(r_in, 1), 8), _pad_to(max(d, 1), 128)
-    op = _pad_to(max(out_rows, 1), 8)
+    rp, op, dp = _stack_dims(r_in, out_rows, d, x.dtype)
     x2 = jnp.pad(x, ((0, 0), (0, rp - r_in), (0, dp - d)))
     pv = None
     if prev is not None:
         r_prev = prev.shape[0]
-        pv = jnp.pad(prev, ((0, _pad_to(max(r_prev, 1), 8) - r_prev),
-                            (0, dp - d)))
+        pv = jnp.pad(prev, ((0, _stack_dims(r_prev, 1, d, prev.dtype)[0]
+                             - r_prev), (0, dp - d)))
     out = packed_stack_pallas(x2, jnp.asarray(scales, jnp.float32), pv,
                               copies_x=tuple(copies_x),
                               copies_prev=tuple(copies_prev),
@@ -356,4 +374,5 @@ __all__ = ["rbla_agg", "rbla_agg_ref", "flora_stack", "flora_stack_ref",
            "packed_robust", "packed_robust_ref", "packed_stack",
            "packed_stack_ref", "rbla_agg_inline", "packed_agg_inline",
            "packed_robust_inline", "packed_stack_inline",
-           "flora_stack_inline", "axpy_fold_inline"]
+           "packed_stack_compiles", "flora_stack_inline",
+           "axpy_fold_inline"]

@@ -88,16 +88,20 @@ def main(argv=None):
 
         rng = np.random.default_rng(0)
         t0 = time.time()
-        for i in range(args.steps):
+        losses = []                 # on the device: the loop never syncs
+        for i in range(args.steps):     # but on its logging steps
             ix = rng.integers(0, len(data), args.batch)
             factors, opt_state, loss = step(params, factors, opt_state,
                                             jnp.asarray(data[ix]))
-            if not np.isfinite(float(loss)):
-                raise FloatingPointError(f"step {i}: loss {float(loss)}")
+            losses.append(loss)
             if i % max(1, args.steps // 10) == 0:
                 print(f"step {i:4d} loss {float(loss):.4f} "
                       f"({(time.time() - t0) / (i + 1):.2f}s/step)",
                       flush=True)
+        bad = [i for i, v in enumerate(jax.device_get(losses))
+               if not np.isfinite(v)]
+        if bad:
+            raise FloatingPointError(f"non-finite loss at steps {bad}")
         # the pod-side round ends like the FLaaS server: the cohort's
         # adapter upload goes through the registered strategy (one cohort
         # here; the FL simulator drives many).

@@ -558,6 +558,53 @@ def test_packed_stack_kernel_places_and_scales():
     np.testing.assert_allclose(np.asarray(out), want, rtol=1e-6, atol=1e-6)
 
 
+def test_packed_stack_row_tiles_match_ref():
+    """Copies on 8-row tiles: the kernel walks the output in the tiles it
+    uses compiled, zero-fills the cap padding, and a later copy wins."""
+    from repro.kernels import packed_stack, packed_stack_ref
+    from repro.kernels.rbla_agg.kernel import stack_row_tile
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.normal(size=(3, 32, 20)), jnp.float32)
+    prev = jnp.asarray(rng.normal(size=(16, 20)), jnp.float32)
+    scales = jnp.asarray([1.0, 0.5, 2.0, -3.0], jnp.float32)
+    #          (client, src_row, dst_row, rows, scale_idx)
+    copies_x = ((0, 0, 8, 16, 1), (2, 8, 24, 8, 2), (1, 16, 40, 8, 3))
+    copies_prev = ((8, 0, 8, 0), (0, 40, 8, 2))     # overwrites client 1
+    kw = dict(copies_x=copies_x, copies_prev=copies_prev, out_rows=56)
+    assert stack_row_tile(copies_x, copies_prev, 32, 56, 128, jnp.float32,
+                          interpret=False) == 8
+    got = packed_stack(x, scales, prev, interpret=True, **kw)
+    want = packed_stack_ref(x, scales, prev, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+    assert not np.asarray(got)[32:40].any() and not np.asarray(got)[48:].any()
+
+
+@pytest.mark.parametrize("rank,kernel", [(8, True), (4, False)])
+def test_flora_bf16_plan_takes_kernel_on_f32_row_tiles(rank, kernel):
+    """The stack plan packs a bf16 cohort in f32, so rank-8 copies sit on
+    f32's 8-row tiles and the compiled plan takes the kernel; rank-4
+    copies do not, and that bucket stacks in XLA."""
+    s = fresh("flora")
+    adapters, _, w = hetero_cohort(4, seed=21, r_lo=rank, r_hi=rank)
+    adapters = [jax.tree.map(lambda x: x.astype(jnp.bfloat16)
+                             if x.dtype == jnp.float32 else x, a)
+                for a in adapters]
+    ranks = np.full(4, rank)
+    spec = build_cohort_spec(stack_trees(adapters), kind="pallas",
+                             r_max=R_MAX, client_ranks=ranks,
+                             interpret=False)
+    rd = s.plan(None, spec)
+    assert rd.n_fallback_pairs == 0
+    assert rd.n_pallas_launches == (rd.n_kernel_launches if kernel else 0)
+    got = s.aggregate_adapters(adapters, w, r_max=R_MAX, client_ranks=ranks,
+                               backend="pallas")
+    want = fresh("flora").aggregate_adapters(adapters, w, r_max=R_MAX,
+                                             client_ranks=ranks,
+                                             backend="ref")
+    assert_trees_close(got, want, 1e-2, 1e-2)
+
+
 def test_packed_stack_rejects_bad_copies():
     from repro.kernels import packed_stack
     x = jnp.ones((2, 4, 8), jnp.float32)

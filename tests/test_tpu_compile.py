@@ -77,7 +77,7 @@ def _case(name: str, chip):
                 bucket(32))
     if name == "packed_stack":
         # flora over 8 clients at ranks 8..64 plus a rank-64 previous
-        # global, every layer of one pair side: 216 row-range copies
+        # global, every layer of one pair side: 216 copies
         ranks = (8, 8, 16, 16, 32, 32, 64, 64)
         cap, cx, cp = 384, [], []
         for layer in range(24):
@@ -91,6 +91,29 @@ def _case(name: str, chip):
                     x, sc, p, copies_x=tuple(cx), copies_prev=tuple(cp),
                     out_rows=24 * cap, interpret=False),
                 [s((8, R, D)), s((10,)), s((R, D))])
+    if name == "flora_plan_bf16_rank8":
+        # flora's whole compiled round on a bf16 cohort at rank 8, every
+        # projection of one layer: the plan packs in f32, so its copies
+        # sit on the kernel's 8-row tiles
+        import numpy as np
+        from repro.core import get_strategy, stack_trees
+        from repro.core.plan import build_cohort_spec
+        from repro.lora import init_adapters
+        specs = {"q": (D, D), "k": (D, 960), "gate": (D, 10240),
+                 "down": (10240, D)}
+        cohort = [jax.tree.map(
+            lambda x: x.astype(jnp.bfloat16) if x.dtype == jnp.float32
+            else x, init_adapters(jax.random.PRNGKey(i), specs, 8, 8))
+            for i in range(8)]
+        stacked = stack_trees(cohort)
+        rd = get_strategy("flora").with_options(stack_r_cap=64).plan(
+            None, build_cohort_spec(stacked, kind="pallas", r_max=8,
+                                    client_ranks=np.full(8, 8),
+                                    interpret=False))
+        assert rd.n_pallas_launches == rd.n_kernel_launches > 0
+        return (lambda st, w: rd(st, w),
+                [jax.tree.map(lambda x: s(x.shape, x.dtype), stacked),
+                 s((8,))])
     if name == "axpy_fold":
         return (lambda y, x, a: axpy_fold_inline(y, x, a, interpret=False),
                 [s((R, D)), s((R, D)), s((R,))])
@@ -108,8 +131,8 @@ def _case(name: str, chip):
 @pytest.mark.parametrize("case", [
     "packed_agg_n8", "packed_agg_n32", "packed_agg_int8_n128",
     "packed_agg_norm_restore", "packed_robust_clipped",
-    "packed_robust_median_n32", "packed_stack", "axpy_fold",
-    "batched_lora_matmul"])
+    "packed_robust_median_n32", "packed_stack", "flora_plan_bf16_rank8",
+    "axpy_fold", "batched_lora_matmul"])
 def test_kernel_compiles_for_v5e(one_chip, case):
     fn, shapes = _case(case, one_chip)
     compiled = jax.jit(fn).lower(*shapes).compile()
