@@ -41,10 +41,14 @@ from typing import Any, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.obs import host_syncs
+
 #: registered codec names, in negotiation-preference order.
 CODECS = ("none", "bf16", "int8")
 
 _INT8_QMAX = 127.0
+
+_SYNCS_SCALE = host_syncs("validate_scale")
 
 
 # ----------------------------------------------------------- tree walk ----
@@ -204,25 +208,32 @@ def validate_encoded_adapters(adapters) -> None:
     fp32 (``scale * 127 * sqrt(row_width)`` past ``finfo(f32).max`` --
     such an upload would poison ``FoldState`` masses irrecoverably;
     ``reason "overflow"``)."""
-    for path, pair in _iter_pairs(adapters):
-        name = "/".join(str(p) for p in path) or "<root>"
-        for side, key in (("A", "A_scale"), ("B", "B_scale")):
-            if key not in pair:
-                continue
-            s = jnp.asarray(pair[key], jnp.float32)
-            if not bool(jnp.all(jnp.isfinite(s) & (s > 0))):
-                raise UploadValidationError(
-                    f"non-finite or non-positive quantization scale in "
-                    f"{name}.{key}", reason="bad_scale")
-            width = (pair[side].shape[-1] if side == "A"
-                     else pair[side].shape[-2])
-            limit = float(jnp.finfo(jnp.float32).max) / (
-                _INT8_QMAX * math.sqrt(max(width, 1)))
-            if bool(jnp.any(s > limit)):
-                raise UploadValidationError(
-                    f"quantization scale overflow in {name}.{key}: decoded "
-                    f"row norm would exceed float32 range",
-                    reason="overflow")
+    reads = 0               # flags read back from the device
+    try:
+        for path, pair in _iter_pairs(adapters):
+            name = "/".join(str(p) for p in path) or "<root>"
+            for side, key in (("A", "A_scale"), ("B", "B_scale")):
+                if key not in pair:
+                    continue
+                on_device = isinstance(pair[key], jax.Array)
+                s = jnp.asarray(pair[key], jnp.float32)
+                reads += on_device
+                if not bool(jnp.all(jnp.isfinite(s) & (s > 0))):
+                    raise UploadValidationError(
+                        f"non-finite or non-positive quantization scale in "
+                        f"{name}.{key}", reason="bad_scale")
+                width = (pair[side].shape[-1] if side == "A"
+                         else pair[side].shape[-2])
+                limit = float(jnp.finfo(jnp.float32).max) / (
+                    _INT8_QMAX * math.sqrt(max(width, 1)))
+                reads += on_device
+                if bool(jnp.any(s > limit)):
+                    raise UploadValidationError(
+                        f"quantization scale overflow in {name}.{key}: "
+                        f"decoded row norm would exceed float32 range",
+                        reason="overflow")
+    finally:
+        _SYNCS_SCALE.inc(reads)
 
 
 # ---------------------------------------------- stochastic accumulators ----

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import get_registry as _obs_registry
+from repro.obs import host_syncs, span
 
 from .aggregation import _EPS
 from .compat import shard_map_no_check
@@ -136,25 +137,16 @@ class DispatchCounter:
     """Counts host->device computation dispatches issued by the tracked
     entry points: every Pallas kernel wrapper call (``repro.kernels``)
     and every :class:`CompiledRound` execution.  The aggregation
-    benchmarks read this to report dispatches per round.
-
-    The windowed ``count`` / ``reset()`` surface is the legacy public
-    API; every ``inc`` also feeds the cumulative
-    ``plan_dispatches_total`` metric (``repro.obs``), which ``reset()``
-    deliberately does *not* touch -- windows are a caller concern,
-    process totals are the registry's.
+    benchmarks read this to report dispatches per round, in windows of
+    their own: ``count`` since the last ``reset()``.  Process totals
+    per kernel are the registry's ``kernel_dispatches_total``.
     """
 
     def __init__(self):
         self.count = 0
-        self._total = _obs_registry().counter(
-            "plan_dispatches_total",
-            "tracked host->device dispatches (kernel wrappers + "
-            "compiled-plan rounds), cumulative")
 
     def inc(self, n: int = 1) -> None:
         self.count += n
-        self._total.inc(n)
 
     def reset(self) -> int:
         prev, self.count = self.count, 0
@@ -163,6 +155,8 @@ class DispatchCounter:
 
 dispatch_counter = DispatchCounter()
 
+_SYNCS_COHORT = host_syncs("cohort_spec")
+_SYNCS_STATE = host_syncs("state_spec")
 _PACK_RUNS = _obs_registry().counter(
     "plan_pack_runs_total", "packed-bucket builds, by strategy",
     labelnames=("strategy",))
@@ -210,9 +204,27 @@ def _walk_pairs(tree, path=()):
         "whole LoRA pairs")
 
 
-def _concrete(x, what: str) -> np.ndarray:
+class _Reads:
+    """The device-to-host reads of one cohort walk, added to
+    ``host_syncs_total{site="cohort_spec"}`` once, when the walk ends."""
+
+    def __init__(self):
+        self.n = 0
+
+    def __enter__(self) -> "_Reads":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _SYNCS_COHORT.inc(self.n)
+
+
+def _concrete(x, what: str, reads: _Reads) -> np.ndarray:
+    """``x`` on the host; reading a ``jax.Array`` waits on the device
+    and counts in ``reads`` (a numpy or Python value is no read)."""
     if isinstance(x, jax.core.Tracer):
         raise PlanUnavailable(f"{what} is traced; plans are host-built")
+    if isinstance(x, jax.Array):
+        reads.n += 1
     return np.asarray(jax.device_get(x))
 
 
@@ -278,37 +290,43 @@ def build_cohort_spec(stacked_tree: PyTree, *, kind: str,
     """Describe a stacked cohort host-side.  Raises
     :class:`PlanUnavailable` when the description needs values tracing
     hides (rank leaves, weights under jit) or the tree has bare leaves."""
-    if client_ranks is not None:
-        client_ranks = tuple(
-            int(v) for v in _concrete(client_ranks, "client_ranks").ravel())
-    prev_pairs = (dict(_walk_pairs(prev_tree))
-                  if prev_tree is not None else {})
-    pairs = []
-    n = None
-    for path, pair in _walk_pairs(stacked_tree):
-        A, B, rank = pair["A"], pair["B"], pair["rank"]
-        if isinstance(A, jax.core.Tracer) or isinstance(B, jax.core.Tracer):
-            raise PlanUnavailable("cohort leaves are traced")
-        if A.ndim < 3 or B.ndim < 3:
-            raise PlanUnavailable(
-                f"pair at {path} is not stacked over clients")
-        if n is None:
-            n = int(A.shape[0])
-        rk = _concrete(rank, f"rank leaf at {path}")
-        meta = dict(path=path, a_shape=tuple(A.shape), a_dtype=str(A.dtype),
-                    b_shape=tuple(B.shape), b_dtype=str(B.dtype),
-                    rank_shape=tuple(rk.shape),
-                    ranks=tuple(int(v) for v in rk.ravel()))
-        if prev_tree is not None:
-            if path not in prev_pairs:
-                raise PlanUnavailable(f"prev tree missing pair at {path}")
-            pp = prev_pairs[path]
-            prk = _concrete(pp["rank"], f"prev rank leaf at {path}")
-            meta.update(prev_a_shape=tuple(pp["A"].shape),
-                        prev_b_shape=tuple(pp["B"].shape),
-                        prev_rank_shape=tuple(prk.shape),
-                        prev_ranks=tuple(int(v) for v in prk.ravel()))
-        pairs.append(PairMeta(**meta))
+    with _Reads() as reads:
+        if client_ranks is not None:
+            client_ranks = tuple(
+                int(v) for v in _concrete(client_ranks, "client_ranks",
+                                          reads).ravel())
+        prev_pairs = (dict(_walk_pairs(prev_tree))
+                      if prev_tree is not None else {})
+        pairs = []
+        n = None
+        for path, pair in _walk_pairs(stacked_tree):
+            A, B, rank = pair["A"], pair["B"], pair["rank"]
+            if (isinstance(A, jax.core.Tracer)
+                    or isinstance(B, jax.core.Tracer)):
+                raise PlanUnavailable("cohort leaves are traced")
+            if A.ndim < 3 or B.ndim < 3:
+                raise PlanUnavailable(
+                    f"pair at {path} is not stacked over clients")
+            if n is None:
+                n = int(A.shape[0])
+            rk = _concrete(rank, f"rank leaf at {path}", reads)
+            meta = dict(path=path, a_shape=tuple(A.shape),
+                        a_dtype=str(A.dtype), b_shape=tuple(B.shape),
+                        b_dtype=str(B.dtype),
+                        rank_shape=tuple(rk.shape),
+                        ranks=tuple(int(v) for v in rk.ravel()))
+            if prev_tree is not None:
+                if path not in prev_pairs:
+                    raise PlanUnavailable(
+                        f"prev tree missing pair at {path}")
+                pp = prev_pairs[path]
+                prk = _concrete(pp["rank"], f"prev rank leaf at {path}",
+                                reads)
+                meta.update(prev_a_shape=tuple(pp["A"].shape),
+                            prev_b_shape=tuple(pp["B"].shape),
+                            prev_rank_shape=tuple(prk.shape),
+                            prev_ranks=tuple(int(v) for v in prk.ravel()))
+            pairs.append(PairMeta(**meta))
     if not pairs:
         raise PlanUnavailable("no LoRA pairs in the cohort tree")
     return CohortSpec(n_clients=n, kind=kind, r_max=r_max,
@@ -345,47 +363,52 @@ def build_encoded_cohort_spec(client_trees: Sequence, codecs, *, kind: str,
         if [p for p, _ in wl] != paths:
             raise PlanUnavailable(
                 f"client {i}'s tree structure differs from client 0's")
-    if client_ranks is not None:
-        client_ranks = tuple(
-            int(v) for v in _concrete(client_ranks, "client_ranks").ravel())
-    inferred: list | None = [] if client_ranks is None else None
-    pairs = []
-    for pi, path in enumerate(paths):
-        metas = []
-        rks = []
-        for i in range(n):
-            pair = walked[i][pi][1]
-            A, B = pair["A"], pair["B"]
-            if (isinstance(A, jax.core.Tracer)
-                    or isinstance(B, jax.core.Tracer)):
-                raise PlanUnavailable("cohort leaves are traced")
-            metas.append((tuple(A.shape), tuple(B.shape)))
-            rks.append(_concrete(pair["rank"], f"rank leaf at {path}"))
-        if any(m != metas[0] for m in metas[1:]):
-            raise PlanUnavailable(
-                f"clients disagree on pair shapes at {path}")
-        rk = np.stack(rks)
-        if inferred is not None and pi == 0 and rk.ndim == 1:
-            inferred.extend(int(v) for v in rk)
-        a_shape = (n,) + metas[0][0]
-        b_shape = (n,) + metas[0][1]
-        # decoded dtype: wire dtypes dequantize to f32; an all-"none"
-        # pair keeps its own dtype (can't happen cohort-wide -- that
-        # cohort has codecs=None and takes the stacked path)
-        meta = dict(path=path, a_shape=a_shape, a_dtype="float32",
-                    b_shape=b_shape, b_dtype="float32",
-                    rank_shape=tuple(rk.shape),
-                    ranks=tuple(int(v) for v in rk.ravel()))
-        if prev_tree is not None:
-            if path not in prev_pairs:
-                raise PlanUnavailable(f"prev tree missing pair at {path}")
-            pp = prev_pairs[path]
-            prk = _concrete(pp["rank"], f"prev rank leaf at {path}")
-            meta.update(prev_a_shape=tuple(pp["A"].shape),
-                        prev_b_shape=tuple(pp["B"].shape),
-                        prev_rank_shape=tuple(prk.shape),
-                        prev_ranks=tuple(int(v) for v in prk.ravel()))
-        pairs.append(PairMeta(**meta))
+    with _Reads() as reads:
+        if client_ranks is not None:
+            client_ranks = tuple(
+                int(v) for v in _concrete(client_ranks, "client_ranks",
+                                          reads).ravel())
+        inferred: list | None = [] if client_ranks is None else None
+        pairs = []
+        for pi, path in enumerate(paths):
+            metas = []
+            rks = []
+            for i in range(n):
+                pair = walked[i][pi][1]
+                A, B = pair["A"], pair["B"]
+                if (isinstance(A, jax.core.Tracer)
+                        or isinstance(B, jax.core.Tracer)):
+                    raise PlanUnavailable("cohort leaves are traced")
+                metas.append((tuple(A.shape), tuple(B.shape)))
+                rks.append(_concrete(pair["rank"], f"rank leaf at {path}",
+                                     reads))
+            if any(m != metas[0] for m in metas[1:]):
+                raise PlanUnavailable(
+                    f"clients disagree on pair shapes at {path}")
+            rk = np.stack(rks)
+            if inferred is not None and pi == 0 and rk.ndim == 1:
+                inferred.extend(int(v) for v in rk)
+            a_shape = (n,) + metas[0][0]
+            b_shape = (n,) + metas[0][1]
+            # decoded dtype: wire dtypes dequantize to f32; an all-"none"
+            # pair keeps its own dtype (can't happen cohort-wide -- that
+            # cohort has codecs=None and takes the stacked path)
+            meta = dict(path=path, a_shape=a_shape, a_dtype="float32",
+                        b_shape=b_shape, b_dtype="float32",
+                        rank_shape=tuple(rk.shape),
+                        ranks=tuple(int(v) for v in rk.ravel()))
+            if prev_tree is not None:
+                if path not in prev_pairs:
+                    raise PlanUnavailable(
+                        f"prev tree missing pair at {path}")
+                pp = prev_pairs[path]
+                prk = _concrete(pp["rank"], f"prev rank leaf at {path}",
+                                reads)
+                meta.update(prev_a_shape=tuple(pp["A"].shape),
+                            prev_b_shape=tuple(pp["B"].shape),
+                            prev_rank_shape=tuple(prk.shape),
+                            prev_ranks=tuple(int(v) for v in prk.ravel()))
+            pairs.append(PairMeta(**meta))
     if not pairs:
         raise PlanUnavailable("no LoRA pairs in the cohort trees")
     if client_ranks is None and inferred:
@@ -790,21 +813,23 @@ def _build_mean_round(strategy, spec: CohortSpec,
         # releases the packed payload as soon as the cohort's buffers
         # die (BufferMemo), so stale plans never pin cohort bytes
         leaves = [v for d in ab for v in (d["A"], d["B"])]
-        xs = pack_memo.lookup(leaves)
-        if xs is not None:
-            stats["pack_reuses"] = stats.get("pack_reuses", 0) + 1
-            _PACK_REUSES.labels(strategy=strategy.name).inc()
-        else:
-            xs = pack(ab)
-            pack_memo.store(leaves, xs)
-            stats["pack_runs"] = stats.get("pack_runs", 0) + 1
-            _PACK_RUNS.labels(strategy=strategy.name).inc()
-        prev_ab = _ab_list(prev_tree) if retains else None
-        run = fn_donate if (donate and retains) else fn
-        outs = run(xs, w, prev_ab, masks, cr)
-        pairs = [{"A": o["A"], "B": o["B"], "rank": rank_leaves[i]}
-                 for i, o in enumerate(outs)]
-        return rebuild[0](pairs)
+        with span("round.pack"):
+            xs = pack_memo.lookup(leaves)
+            if xs is not None:
+                stats["pack_reuses"] = stats.get("pack_reuses", 0) + 1
+                _PACK_REUSES.labels(strategy=strategy.name).inc()
+            else:
+                xs = pack(ab)
+                pack_memo.store(leaves, xs)
+                stats["pack_runs"] = stats.get("pack_runs", 0) + 1
+                _PACK_RUNS.labels(strategy=strategy.name).inc()
+        with span("round.combine"):
+            prev_ab = _ab_list(prev_tree) if retains else None
+            run = fn_donate if (donate and retains) else fn
+            outs = run(xs, w, prev_ab, masks, cr)
+            pairs = [{"A": o["A"], "B": o["B"], "rank": rank_leaves[i]}
+                     for i, o in enumerate(outs)]
+            return rebuild[0](pairs)
 
     return CompiledRound(strategy, spec, "packed", execute,
                          n_kernel_launches=len(buckets),
@@ -1041,22 +1066,24 @@ def _build_encoded_mean_round(strategy, spec: CohortSpec,
         stats = strategy.__dict__.setdefault(
             "plan_stats", {"hits": 0, "misses": 0})
         leaves = [v for ab in clients for d in ab for v in d.values()]
-        packed = pack_memo.lookup(leaves)
-        if packed is not None:
-            stats["pack_reuses"] = stats.get("pack_reuses", 0) + 1
-            _PACK_REUSES.labels(strategy=strategy.name).inc()
-        else:
-            packed = pack(clients)
-            pack_memo.store(leaves, packed)
-            stats["pack_runs"] = stats.get("pack_runs", 0) + 1
-            _PACK_RUNS.labels(strategy=strategy.name).inc()
-        xs, ss = packed
-        prev_ab = _ab_list(prev_tree) if retains else None
-        run = fn_donate if (donate and retains) else fn
-        outs = run(xs, ss, w, prev_ab, masks, cr)
-        pairs = [{"A": o["A"], "B": o["B"], "rank": rank_leaves[i]}
-                 for i, o in enumerate(outs)]
-        return rebuild[0](pairs)
+        with span("round.pack"):
+            packed = pack_memo.lookup(leaves)
+            if packed is not None:
+                stats["pack_reuses"] = stats.get("pack_reuses", 0) + 1
+                _PACK_REUSES.labels(strategy=strategy.name).inc()
+            else:
+                packed = pack(clients)
+                pack_memo.store(leaves, packed)
+                stats["pack_runs"] = stats.get("pack_runs", 0) + 1
+                _PACK_RUNS.labels(strategy=strategy.name).inc()
+        with span("round.combine"):
+            xs, ss = packed
+            prev_ab = _ab_list(prev_tree) if retains else None
+            run = fn_donate if (donate and retains) else fn
+            outs = run(xs, ss, w, prev_ab, masks, cr)
+            pairs = [{"A": o["A"], "B": o["B"], "rank": rank_leaves[i]}
+                     for i, o in enumerate(outs)]
+            return rebuild[0](pairs)
 
     # a mixed-codec mean combines per-group partial sums in XLA
     kernel_buckets = (len(buckets) if use_kernel
@@ -1693,6 +1720,7 @@ def build_state_spec(adapters: PyTree, *, interpret=None) -> CohortSpec:
     the fold plan's cache key.  Rank values are not part of the key --
     folds take them as data so one compiled fold serves every client."""
     pairs = []
+    reads = 0
     for path, pair in _walk_pairs(adapters):
         A, B = pair["A"], pair["B"]
         if isinstance(A, jax.core.Tracer) or isinstance(B, jax.core.Tracer):
@@ -1701,12 +1729,14 @@ def build_state_spec(adapters: PyTree, *, interpret=None) -> CohortSpec:
             if not isinstance(pair["rank"], jax.core.Tracer) else None
         if rk_shape is None:
             raise PlanUnavailable("state rank leaf is traced")
+        reads += isinstance(pair["rank"], jax.Array)
         pairs.append(PairMeta(
             path=path, a_shape=(1,) + tuple(A.shape), a_dtype=str(A.dtype),
             b_shape=(1,) + tuple(B.shape), b_dtype=str(B.dtype),
             rank_shape=(1,) + rk_shape,
             ranks=tuple(0 for _ in range(int(np.prod(rk_shape,
                                                      dtype=np.int64))))))
+    _SYNCS_STATE.inc(reads)
     if not pairs:
         raise PlanUnavailable("no LoRA pairs in the state tree")
     return CohortSpec(n_clients=1, kind="pallas", r_max=None,

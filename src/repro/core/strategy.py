@@ -38,6 +38,7 @@ distributed aggregator factory, and the benchmarks all resolve it by name.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Any, Mapping, Sequence
 
 import jax
@@ -46,6 +47,7 @@ import numpy as np
 from jax import lax
 
 from repro.obs import get_registry as _obs_registry
+from repro.obs import host_syncs, span
 
 from .aggregation import _EPS, fedavg_leaf, rbla_leaf, zeropad_leaf
 from .compat import shard_map_no_check
@@ -70,6 +72,7 @@ _PLAN_CACHE_HITS = _obs_registry().counter(
 _PLAN_CACHE_MISSES = _obs_registry().counter(
     "plan_cache_misses_total", "plan-cache misses (plan builds), by strategy",
     labelnames=("strategy",))
+_SYNCS_FOLD_RANK = host_syncs("fold_rank")
 
 
 # ------------------------------------------------------------ server state --
@@ -376,7 +379,7 @@ class AggregationStrategy:
         # compiled artifacts close over self and its options: never share
         for cached in ("_dist_agg_cache", "_plan_cache", "plan_stats",
                        "_fold_plan_cache", "_plan_exec_cache",
-                       "_stack_memo"):
+                       "_stack_memo", "_round_seq"):
             inst.__dict__.pop(cached, None)
         for k, v in options.items():
             if not hasattr(inst, k) or k.startswith("_"):
@@ -425,19 +428,20 @@ class AggregationStrategy:
         cache = self.__dict__.setdefault("_plan_cache", OrderedDict())
         stats = self.__dict__.setdefault("plan_stats",
                                          {"hits": 0, "misses": 0})
-        got = cache.get(cohort_spec)
-        if got is not None:
-            stats["hits"] += 1
-            _PLAN_CACHE_HITS.labels(strategy=self.name).inc()
-            cache.move_to_end(cohort_spec)
-            return got
-        stats["misses"] += 1
-        _PLAN_CACHE_MISSES.labels(strategy=self.name).inc()
-        built = build_plan(self, cohort_spec)
-        cache[cohort_spec] = built
-        while len(cache) > PLAN_CACHE_SIZE:
-            cache.popitem(last=False)
-        return built
+        with span("round.plan"):
+            got = cache.get(cohort_spec)
+            if got is not None:
+                stats["hits"] += 1
+                _PLAN_CACHE_HITS.labels(strategy=self.name).inc()
+                cache.move_to_end(cohort_spec)
+                return got
+            stats["misses"] += 1
+            _PLAN_CACHE_MISSES.labels(strategy=self.name).inc()
+            built = build_plan(self, cohort_spec)
+            cache[cohort_spec] = built
+            while len(cache) > PLAN_CACHE_SIZE:
+                cache.popitem(last=False)
+            return built
 
     def _plan_round(self, stacked, kind, *, r_max, client_ranks, prev,
                     mesh, client_axis, interpret):
@@ -446,10 +450,11 @@ class AggregationStrategy:
         leaves) -- the caller then runs the in-trace legacy path."""
         from .plan import PlanUnavailable, build_cohort_spec
         try:
-            spec = build_cohort_spec(
-                stacked, kind=kind, r_max=r_max, client_ranks=client_ranks,
-                prev_tree=prev, interpret=interpret, mesh=mesh,
-                client_axis=client_axis)
+            with span("round.spec"):
+                spec = build_cohort_spec(
+                    stacked, kind=kind, r_max=r_max,
+                    client_ranks=client_ranks, prev_tree=prev,
+                    interpret=interpret, mesh=mesh, client_axis=client_axis)
         except PlanUnavailable:
             return None
         return self.plan(None, spec)
@@ -464,10 +469,11 @@ class AggregationStrategy:
         mix hits."""
         from .plan import PlanUnavailable, build_encoded_cohort_spec
         try:
-            spec = build_encoded_cohort_spec(
-                client_adapters, codecs, kind=kind, r_max=r_max,
-                client_ranks=client_ranks, prev_tree=prev,
-                interpret=interpret, client_axis=client_axis)
+            with span("round.spec"):
+                spec = build_encoded_cohort_spec(
+                    client_adapters, codecs, kind=kind, r_max=r_max,
+                    client_ranks=client_ranks, prev_tree=prev,
+                    interpret=interpret, client_axis=client_axis)
             return self.plan(None, spec)
         except PlanUnavailable:
             return None
@@ -691,76 +697,84 @@ class AggregationStrategy:
         from .plan import BufferMemo
 
         from .codec import cohort_codecs
-        codecs = cohort_codecs(client_adapters)
-        if codecs is not None:
-            # encoded uploads (repro.core.codec): the mean family plans
-            # them directly -- per-client wire-dtype payloads, dequant
-            # fused into the packed kernels, no stacked fp32 staging
-            # buffer.  Everything else (stack/svd/jit/eager/distributed,
-            # intra-client codec mixes, unplannable cohorts) decodes
-            # eagerly and takes the standard path below.
-            kind_enc = resolve_backend(backend, self)
-            if (use_plan and "mixed" not in codecs
-                    and getattr(self, "plan_mode", None) in ("mean",
-                                                             "mean_norm")
-                    and kind_enc in ("ref", "pallas")):
-                prev_enc = prev_global if self.retains_prev else None
-                round_ = self._plan_encoded_round(
-                    client_adapters, codecs, kind_enc, r_max=r_max,
-                    client_ranks=client_ranks, prev=prev_enc,
-                    interpret=interpret, client_axis=client_axis)
-                if round_ is not None:
-                    return round_(client_adapters, weights, prev_enc,
-                                  donate=donate)
-            from .codec import decode_adapters
-            client_adapters = [decode_adapters(a) for a in client_adapters]
+        # the call's sequence number on this instance tags its spans
+        seq = self.__dict__.setdefault("_round_seq", itertools.count())
+        with span("round", round=next(seq)):
+            codecs = cohort_codecs(client_adapters)
+            if codecs is not None:
+                # encoded uploads (repro.core.codec): the mean family
+                # plans them directly -- per-client wire-dtype payloads,
+                # dequant fused into the packed kernels, no stacked fp32
+                # staging buffer.  Everything else (stack/svd/jit/eager/
+                # distributed, intra-client codec mixes, unplannable
+                # cohorts) decodes eagerly and takes the standard path
+                # below.
+                kind_enc = resolve_backend(backend, self)
+                if (use_plan and "mixed" not in codecs
+                        and getattr(self, "plan_mode", None) in ("mean",
+                                                                 "mean_norm")
+                        and kind_enc in ("ref", "pallas")):
+                    prev_enc = prev_global if self.retains_prev else None
+                    round_ = self._plan_encoded_round(
+                        client_adapters, codecs, kind_enc, r_max=r_max,
+                        client_ranks=client_ranks, prev=prev_enc,
+                        interpret=interpret, client_axis=client_axis)
+                    if round_ is not None:
+                        return round_(client_adapters, weights, prev_enc,
+                                      donate=donate)
+                from .codec import decode_adapters
+                client_adapters = [decode_adapters(a)
+                                   for a in client_adapters]
 
-        leaves = [leaf for ad in client_adapters
-                  for leaf in jax.tree.leaves(ad)]
-        memo = self.__dict__.get("_stack_memo")
-        if memo is None:
-            # require_repeat: a normal FL loop (fresh uploads every
-            # round) must retain only a fingerprint between rounds, not
-            # a cohort-sized stacked copy
-            memo = self.__dict__["_stack_memo"] = BufferMemo(
-                require_repeat=True)
-        stacked = memo.lookup(leaves)
-        if stacked is None:
-            stacked = stack_trees(client_adapters)
-            # identity-memoized only for immutable non-traced jax
-            # buffers seen on consecutive rounds, released as soon as
-            # the uploads die -- the BufferMemo invariants
-            memo.store(leaves, stacked)
-        if client_ranks is None:
-            client_ranks = _infer_ranks(stacked)
-        w = jnp.asarray(weights, jnp.float32)
-        prev = prev_global if self.retains_prev else None
-        kind = resolve_backend(backend, self)
-        if use_plan:
-            round_ = self._plan_round(
-                stacked, kind, r_max=r_max, client_ranks=client_ranks,
-                prev=prev, mesh=mesh, client_axis=client_axis,
-                interpret=interpret)
-            if round_ is not None:
-                return round_(stacked, w, prev, donate=donate)
-        if kind == "pallas":
-            out = self.aggregate_tree_pallas(stacked, w, client_ranks, prev,
-                                             r_max=r_max,
-                                             interpret=interpret)
-        else:
-            # the kernel path derives masks from ranks; only the jnp/psum
-            # paths need the materialized delta_{i,r} mask tree
-            masks = stack_trees([adapter_masks(a) for a in client_adapters])
-            if kind == "distributed":
-                out = self.aggregate_tree_distributed(
-                    stacked, masks, w, prev, r_max=r_max,
-                    client_ranks=client_ranks, mesh=mesh,
-                    client_axis=client_axis)
+            leaves = [leaf for ad in client_adapters
+                      for leaf in jax.tree.leaves(ad)]
+            memo = self.__dict__.get("_stack_memo")
+            if memo is None:
+                # require_repeat: a normal FL loop (fresh uploads every
+                # round) must retain only a fingerprint between rounds,
+                # not a cohort-sized stacked copy
+                memo = self.__dict__["_stack_memo"] = BufferMemo(
+                    require_repeat=True)
+            with span("round.stack"):
+                stacked = memo.lookup(leaves)
+                if stacked is None:
+                    stacked = stack_trees(client_adapters)
+                    # identity-memoized only for immutable non-traced jax
+                    # buffers seen on consecutive rounds, released as soon
+                    # as the uploads die -- the BufferMemo invariants
+                    memo.store(leaves, stacked)
+            if client_ranks is None:
+                client_ranks = _infer_ranks(stacked)
+            w = jnp.asarray(weights, jnp.float32)
+            prev = prev_global if self.retains_prev else None
+            kind = resolve_backend(backend, self)
+            if use_plan:
+                round_ = self._plan_round(
+                    stacked, kind, r_max=r_max, client_ranks=client_ranks,
+                    prev=prev, mesh=mesh, client_axis=client_axis,
+                    interpret=interpret)
+                if round_ is not None:
+                    return round_(stacked, w, prev, donate=donate)
+            if kind == "pallas":
+                out = self.aggregate_tree_pallas(
+                    stacked, w, client_ranks, prev, r_max=r_max,
+                    interpret=interpret)
             else:
-                out = self.aggregate_tree(stacked, masks, w, prev,
-                                          r_max=r_max,
-                                          client_ranks=client_ranks)
-        return self.finalize_tree(out, r_max)
+                # the kernel path derives masks from ranks; only the
+                # jnp/psum paths need the materialized delta_{i,r} mask
+                # tree
+                masks = stack_trees([adapter_masks(a)
+                                     for a in client_adapters])
+                if kind == "distributed":
+                    out = self.aggregate_tree_distributed(
+                        stacked, masks, w, prev, r_max=r_max,
+                        client_ranks=client_ranks, mesh=mesh,
+                        client_axis=client_axis)
+                else:
+                    out = self.aggregate_tree(stacked, masks, w, prev,
+                                              r_max=r_max,
+                                              client_ranks=client_ranks)
+            return self.finalize_tree(out, r_max)
 
     def finalize_tree(self, out: PyTree, r_max: int | None) -> PyTree:
         """Post-aggregation rank bookkeeping.  Fixed-rank strategies reset
@@ -1004,7 +1018,8 @@ class RBLAStrategy(AggregationStrategy):
         from .plan import (PlanUnavailable, _make_rebuilder, _walk_pairs,
                            build_fold_plan, build_state_spec)
         try:
-            spec = build_state_spec(adapters, interpret=interpret)
+            with span("fold.state_spec"):
+                spec = build_state_spec(adapters, interpret=interpret)
             state_pairs = list(_walk_pairs(adapters))
             upd_pairs = list(_walk_pairs(upd))
         except PlanUnavailable:
@@ -1022,23 +1037,24 @@ class RBLAStrategy(AggregationStrategy):
         if entry is None:
             entry = cache[spec] = [build_fold_plan(self, spec)[0], None]
         fold_fn = entry[0]
-        state_ab = [{"A": p["A"], "B": p["B"]} for _, p in state_pairs]
-        upd_ab = [{"A": p["A"], "B": p["B"]} for _, p in upd_pairs]
-        rank_leaves = [jnp.asarray(p["rank"], jnp.int32)
-                       for _, p in upd_pairs]
-        args = (state_ab, upd_ab, _flat_pair_values(row_mass),
-                jnp.float32(wa), rank_leaves)
-        if entry[1] is None:
-            # the kernel launches of one fold, read off its program
-            entry[1] = count_primitive(fold_fn.trace(*args).jaxpr,
-                                       "pallas_call")
-        new_ab, new_mass = fold_fn(*args)
-        count_dispatch(entry[1], kernel="axpy_fold")
-        rebuild = _make_rebuilder(adapters)
-        new_adapters = rebuild(
-            [{"A": o["A"], "B": o["B"], "rank": p["rank"]}
-             for o, (_, p) in zip(new_ab, state_pairs)])
-        return new_adapters, rebuild(new_mass)
+        with span("fold.dispatch"):
+            state_ab = [{"A": p["A"], "B": p["B"]} for _, p in state_pairs]
+            upd_ab = [{"A": p["A"], "B": p["B"]} for _, p in upd_pairs]
+            rank_leaves = [jnp.asarray(p["rank"], jnp.int32)
+                           for _, p in upd_pairs]
+            args = (state_ab, upd_ab, _flat_pair_values(row_mass),
+                    jnp.float32(wa), rank_leaves)
+            if entry[1] is None:
+                # the kernel launches of one fold, read off its program
+                entry[1] = count_primitive(fold_fn.trace(*args).jaxpr,
+                                           "pallas_call")
+            new_ab, new_mass = fold_fn(*args)
+            count_dispatch(entry[1], kernel="axpy_fold")
+            rebuild = _make_rebuilder(adapters)
+            new_adapters = rebuild(
+                [{"A": o["A"], "B": o["B"], "rank": p["rank"]}
+                 for o, (_, p) in zip(new_ab, state_pairs)])
+            return new_adapters, rebuild(new_mass)
 
     def fold(self, state, update, weight=None, *, fold_state=None,
              backend="auto", interpret=None):
@@ -1064,9 +1080,12 @@ class RBLAStrategy(AggregationStrategy):
         if state.adapters is not None and update.adapters is not None:
             upd = update.adapters
             if rank_seen is None:
-                ranks = []
-                _map_pairs(lambda p: ranks.append(int(np.max(np.asarray(
-                    jax.device_get(p["rank"]))))) or p, upd)
+                leaves = []
+                _map_pairs(lambda p: leaves.append(p["rank"]) or p, upd)
+                ranks = [int(np.max(np.asarray(jax.device_get(x))))
+                         for x in leaves]
+                _SYNCS_FOLD_RANK.inc(
+                    sum(isinstance(x, jax.Array) for x in leaves))
                 rank_seen = max(ranks) if ranks else None
             wa = self._fold_adapter_weight(update, w, int(rank_seen or 1))
             if kind == "pallas":

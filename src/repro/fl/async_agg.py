@@ -47,6 +47,7 @@ example.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 from typing import Callable
 
@@ -62,7 +63,7 @@ from repro.core.strategy import (ClientUpdate, FoldState, ServerState,
                                  get_strategy)
 from repro.fl.comm import (BufferedUpdate, DedupWindow, UpdateBuffer,
                            tree_bytes)
-from repro.obs import STALENESS_BUCKETS, get_registry, span
+from repro.obs import STALENESS_BUCKETS, get_registry, host_syncs, span
 
 #: the machine-readable rejection reasons ``fl_updates_rejected_total``
 #: counts (see ``docs/observability.md``); every ingestion raise, the
@@ -297,6 +298,10 @@ class AsyncAggregator:
             "fl_publishes_total", "states handed to the publish hook")
         self._m_buffer_depth = reg.gauge(
             "fl_buffer_depth", "updates currently buffered")
+        self._m_syncs_finite = host_syncs("validate_finite", reg)
+        #: sequence numbers of ``submit`` calls: the ``upload=`` of each
+        #: call's ``submit`` span
+        self._upload_seq = itertools.count()
         self._quantize_live()          # bf16 storage from the first fold on
 
     # ------------------------------------------------------------- intake --
@@ -327,51 +332,58 @@ class AsyncAggregator:
         Every raise increments ``fl_updates_rejected_total`` under
         exactly one reason.  Returns the set of wire codecs the upload
         used (for the codec-mix counters)."""
-        n = float(update.n_examples)
-        if not (math.isfinite(n) and n > 0.0):
-            self._reject("bad_mass")
-            raise ValueError(
-                "rejected client update: n_examples must be positive and "
-                f"finite, got {update.n_examples!r}")
-        used = set()
-        for path, p in _iter_adapter_pairs(update.adapters):
-            used.add(codec_of_pair(p))
-            # structural integrity: a truncated/garbled upload (lost
-            # frames, a proxy cutting the payload short) must be rejected
-            # here, not crash a fused kernel three layers down
-            a, b = jnp.asarray(p["A"]), jnp.asarray(p["B"])
-            if (a.ndim < 2 or b.ndim < 2
-                    or a.shape[-2] != b.shape[-1]):
-                self._reject("malformed")
-                name = "/".join(str(s) for s in path) or "<root>"
+        with span("submit.validate", registry=self.obs_registry):
+            n = float(update.n_examples)
+            if not (math.isfinite(n) and n > 0.0):
+                self._reject("bad_mass")
                 raise ValueError(
-                    f"rejected client update: truncated or malformed "
-                    f"pair {name}: A {tuple(a.shape)} / B "
-                    f"{tuple(b.shape)} do not share a rank axis")
-        bad = sorted(used - set(self.codecs))
-        if bad:
-            self._reject("codec_not_allowed")
-            raise ValueError(
-                f"rejected client update: upload codec {bad} not in the "
-                f"negotiated set {list(self.codecs)}")
-        # scale sanity first: a NaN scale should name the scale, not fall
-        # through to the generic non-finite message below
-        try:
-            validate_encoded_adapters(update.adapters)
-        except UploadValidationError as e:
-            self._reject(e.reason)      # "bad_scale" | "overflow"
-            raise
-        for name, tree in (("adapters", update.adapters),
-                           ("base_trainable", update.base_trainable)):
-            for leaf in jax.tree.leaves(tree):
-                x = jnp.asarray(leaf)
-                if (jnp.issubdtype(x.dtype, jnp.floating)
-                        and not bool(jnp.all(jnp.isfinite(x)))):
-                    self._reject("nan_tensor")
+                    "rejected client update: n_examples must be positive and "
+                    f"finite, got {update.n_examples!r}")
+            used = set()
+            for path, p in _iter_adapter_pairs(update.adapters):
+                used.add(codec_of_pair(p))
+                # structural integrity: a truncated/garbled upload (lost
+                # frames, a proxy cutting the payload short) must be rejected
+                # here, not crash a fused kernel three layers down
+                a, b = jnp.asarray(p["A"]), jnp.asarray(p["B"])
+                if (a.ndim < 2 or b.ndim < 2
+                        or a.shape[-2] != b.shape[-1]):
+                    self._reject("malformed")
+                    name = "/".join(str(s) for s in path) or "<root>"
                     raise ValueError(
-                        "rejected client update: non-finite values in "
-                        f"{name}")
-        return used
+                        f"rejected client update: truncated or malformed "
+                        f"pair {name}: A {tuple(a.shape)} / B "
+                        f"{tuple(b.shape)} do not share a rank axis")
+            bad = sorted(used - set(self.codecs))
+            if bad:
+                self._reject("codec_not_allowed")
+                raise ValueError(
+                    f"rejected client update: upload codec {bad} not in the "
+                    f"negotiated set {list(self.codecs)}")
+            # scale sanity first: a NaN scale should name the scale, not fall
+            # through to the generic non-finite message below
+            try:
+                validate_encoded_adapters(update.adapters)
+            except UploadValidationError as e:
+                self._reject(e.reason)      # "bad_scale" | "overflow"
+                raise
+            reads = 0          # finiteness flags read back from the device
+            try:
+                for name, tree in (("adapters", update.adapters),
+                                   ("base_trainable", update.base_trainable)):
+                    for leaf in jax.tree.leaves(tree):
+                        x = jnp.asarray(leaf)
+                        if not jnp.issubdtype(x.dtype, jnp.floating):
+                            continue
+                        reads += isinstance(leaf, jax.Array)
+                        if not bool(jnp.all(jnp.isfinite(x))):
+                            self._reject("nan_tensor")
+                            raise ValueError(
+                                "rejected client update: non-finite values in "
+                                f"{name}")
+            finally:
+                self._m_syncs_finite.inc(reads)
+            return used
 
     def submit(self, update: ClientUpdate, model_version: int | None = None,
                now: float = 0.0, pulled_at: float | None = None,
@@ -401,7 +413,8 @@ class AsyncAggregator:
         if update_id is not None and update_id in self.dedup:
             self._reject("duplicate")
             return False
-        with span("submit", registry=self.obs_registry):
+        with span("submit", registry=self.obs_registry,
+                  upload=next(self._upload_seq)):
             used = self._validate_update(update)
             if update_id is not None:
                 self.dedup.add(update_id)

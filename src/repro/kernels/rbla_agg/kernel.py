@@ -133,8 +133,8 @@ def _packed_kernel(weights_ref, masks_ref, x_ref, *rest, n_clients: int,
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _bucket_call(kernel, x, masks, weights, prev, scales, out_dtype, br, bd,
-                 interpret):
+def _bucket_call(name, kernel, x, masks, weights, prev, scales, out_dtype, br,
+                 bd, interpret):
     """Launch a packed-bucket kernel over a ``(R/br, D/bd)`` grid: the
     client axis rides whole in each block, bounded by
     :func:`_client_blocks`; masks and scales go in as ``(R, N)`` so their
@@ -158,6 +158,7 @@ def _bucket_call(kernel, x, masks, weights, prev, scales, out_dtype, br, bd,
         out_specs=pl.BlockSpec((br, bd), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, d), out_dtype),
         interpret=interpret,
+        name=name,
     )(*args)
 
 
@@ -204,10 +205,10 @@ def packed_agg_pallas(x, masks, weights, prev=None, *,
                                has_prev=prev is not None,
                                has_scales=scales is not None)
     if not norm_restore:
-        return _bucket_call(kernel, x, masks, weights, prev, scales,
-                            out_dtype, br, bd, interpret)
-    out = _bucket_call(kernel, x, masks, weights, prev, scales, jnp.float32,
-                       br, bd, interpret)
+        return _bucket_call("packed_agg", kernel, x, masks, weights, prev,
+                            scales, out_dtype, br, bd, interpret)
+    out = _bucket_call("packed_agg", kernel, x, masks, weights, prev, scales,
+                       jnp.float32, br, bd, interpret)
     m = masks.astype(jnp.float32)
     xf = _dequant(x, scales)
     row_norms = m * jnp.sqrt(jnp.sum(xf * xf, axis=-1))          # (N, R)
@@ -314,14 +315,14 @@ def packed_robust_pallas(x, masks, weights, prev=None, *, mode: str,
                                    norm_by="mask",
                                    has_prev=prev is not None,
                                    has_scales=True)
-        return _bucket_call(kernel, x, masks, weights, prev, row_scales,
-                            out_dtype, br, bd, interpret)
+        return _bucket_call("packed_robust", kernel, x, masks, weights,
+                            prev, row_scales, out_dtype, br, bd, interpret)
     kernel = functools.partial(_packed_robust_kernel, n_clients=n, mode=mode,
                                trim_frac=float(trim_frac),
                                has_prev=prev is not None,
                                has_scales=scales is not None)
-    return _bucket_call(kernel, x, masks, weights, prev, scales, out_dtype,
-                        br, bd, interpret)
+    return _bucket_call("packed_robust", kernel, x, masks, weights, prev,
+                        scales, out_dtype, br, bd, interpret)
 
 
 def _packed_stack_kernel(xblk_ref, pblk_ref, tag_ref, scales_ref, x_ref,
@@ -430,6 +431,7 @@ def packed_stack_pallas(x, scales, prev=None, *, copies_x=(),
                                    lambda k, xb, pb, tg: (k, 0))),
         out_shape=jax.ShapeDtypeStruct((out_rows, d), x.dtype),
         interpret=interpret,
+        name="packed_stack",
     )(jnp.asarray(xblk), jnp.asarray(pblk), jnp.asarray(tag), *args)
 
 
@@ -482,6 +484,7 @@ def flora_stack_pallas(x, scales, *, segs: tuple[int, ...], out_rows: int,
         out_specs=pl.BlockSpec((out_rows, bd), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((out_rows, d), x.dtype),
         interpret=interpret,
+        name="flora_stack",
     )(scales.astype(jnp.float32), x)
 
 
@@ -527,6 +530,7 @@ def axpy_fold_pallas(y, x, alpha, *, br=DEFAULT_BR, bd=DEFAULT_BD,
         out_specs=pl.BlockSpec((br, bd), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, d), y.dtype),
         interpret=interpret,
+        name="axpy_fold",
     )(alpha.astype(jnp.float32).reshape(r, 1), x, y)
 
 
@@ -551,4 +555,5 @@ def rbla_agg_pallas(x, ranks, weights, *, norm_by: str = "mask",
         out_specs=pl.BlockSpec((br, bd), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, d), x.dtype),
         interpret=interpret,
+        name="rbla_agg",
     )(ranks, weights.astype(jnp.float32), x)

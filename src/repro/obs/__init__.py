@@ -7,9 +7,11 @@ The operational substrate for the FLaaS server (see
   (counters / gauges / fixed-bucket histograms, lock-safe, cheap no-op
   when disabled, ``reset()`` / ``scoped()`` for tests);
 * :mod:`repro.obs.trace` -- span-based round-lifecycle tracing
-  (``submit -> buffer -> flush/replay -> fold -> publish -> serve``)
-  with JAX-aware timers that block only at span boundaries and degrade
-  to no-ops under jit (the zero-retrace guarantee);
+  (``submit -> buffer -> flush/replay -> fold -> publish -> serve``, and
+  a synchronous ``round``'s stages) on the host clock and, as
+  ``obs.<stage>`` annotations, on the profiler's; spans degrade to
+  no-ops under jit (the zero-retrace guarantee); and the
+  ``host_syncs_total{site}`` counter of device-to-host reads;
 * :mod:`repro.obs.export` -- Prometheus text format, JSON-lines, and
   the in-memory :meth:`MetricsRegistry.snapshot`;
 * :mod:`repro.obs.health` -- :class:`ServiceHealth`, the one-call
@@ -20,7 +22,7 @@ The operational substrate for the FLaaS server (see
 from .metrics import (LATENCY_BUCKETS, REGISTRY, STALENESS_BUCKETS,
                       Counter, Gauge, Histogram, MetricsRegistry,
                       get_registry, metrics_enabled, set_enabled)
-from .trace import EVENT_LOG, ROUND_STAGES, EventLog, Span, span
+from .trace import ROUND_STAGES, Span, host_syncs, span
 from .export import parse_prometheus, to_prometheus, write_jsonl_snapshot
 from .health import ServiceHealth
 from .timing import bench_payload, block, time_fn
@@ -29,7 +31,7 @@ __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "REGISTRY",
     "get_registry", "set_enabled", "metrics_enabled",
     "LATENCY_BUCKETS", "STALENESS_BUCKETS",
-    "span", "Span", "EventLog", "EVENT_LOG", "ROUND_STAGES",
+    "span", "Span", "ROUND_STAGES", "host_syncs",
     "to_prometheus", "parse_prometheus", "write_jsonl_snapshot",
     "ServiceHealth",
     "block", "time_fn", "bench_payload",

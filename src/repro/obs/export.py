@@ -8,7 +8,7 @@ Three ways out of a :class:`~repro.obs.MetricsRegistry`:
   (counters get a ``_total``-as-written name, histograms expand into
   cumulative ``_bucket{le=...}`` series plus ``_sum`` / ``_count``);
 * :func:`write_jsonl_snapshot` -- one JSON line per call, for an
-  append-only metrics log next to the span :class:`~repro.obs.EventLog`.
+  append-only metrics log.
 
 :func:`parse_prometheus` parses the text format back into flat samples
 -- the round-trip property (export -> parse == the registry's own

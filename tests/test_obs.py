@@ -219,30 +219,148 @@ def test_span_is_inert_under_jit_tracing():
     assert hist is None or ("fold",) not in hist._children
 
 
+def _obs_events(log_dir):
+    """``(stage, start_ns, end_ns, thread, metadata)`` of every span
+    annotation (``obs.*``) in the profiler trace under ``log_dir``."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("obs."):
+                    out.append((e.name.split("#")[0][len("obs."):],
+                                e.start_ns, e.start_ns + e.duration_ns,
+                                (plane.name, line.name), dict(e.stats)))
+    return out
+
+
+def _nested(events, child, parent) -> bool:
+    """Every ``child`` span lies inside a ``parent`` span on its thread."""
+    kids = [e for e in events if e[0] == child]
+    return bool(kids) and all(
+        any(p[0] == parent and p[3] == k[3] and p[1] <= k[1]
+            and k[2] <= p[2] for p in events) for k in kids)
+
+
+def test_spans_write_nested_annotations_into_the_profiler_trace(tmp_path):
+    """A round's and an upload's spans reach the profiler trace as
+    ``obs.<stage>`` annotations, each inside its parent, and the
+    outermost carries the request's sequence number."""
+    adapters, ranks, w = _warm_cohort(seed=21)
+    prev = init_adapters(jax.random.PRNGKey(5), SPECS, R_MAX, R_MAX)
+    s = get_strategy("rbla").with_options()
+    agg = AsyncAggregator(
+        get_strategy("rbla").with_options(),
+        ServerState(adapters=init_adapters(jax.random.PRNGKey(6), SPECS,
+                                           R_MAX, R_MAX),
+                    base_trainable={}, r_max=R_MAX),
+        backend="pallas", interpret=True)
+
+    def run():
+        out = s.aggregate_adapters(adapters, w, r_max=R_MAX,
+                                   client_ranks=ranks, prev_global=prev,
+                                   backend="pallas", interpret=True)
+        agg.submit(ClientUpdate(adapters=adapters[0], base_trainable={},
+                                n_examples=2.0, rank=int(ranks[0])))
+        jax.block_until_ready((out, agg.state.adapters))
+    run()                                   # compiles stay out of the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        run()
+    finally:
+        jax.profiler.stop_trace()
+    ev = _obs_events(tmp_path)
+    for stage in ("stack", "spec", "plan", "pack", "combine"):
+        assert _nested(ev, "round." + stage, "round"), stage
+    assert _nested(ev, "submit.validate", "submit")
+    assert _nested(ev, "fold", "flush")
+    for stage in ("state_spec", "dispatch"):
+        assert _nested(ev, "fold." + stage, "fold"), stage
+    assert [set(e[4]) for e in ev if e[0] in ("round", "submit")] == [
+        {"round"}, {"upload"}]
+
+
 # ---------------------------------------------------------- zero-retrace ----
 def _warm_cohort(n=4, seed=11):
     adapters, ranks, w = hetero_cohort(n, seed=seed)
     return adapters, ranks, w
 
 
+ROUND_SPANS = ("round", "round.stack", "round.spec", "round.plan",
+               "round.pack", "round.combine")
+
+
 def test_metrics_toggle_never_retraces_warm_plan_path():
+    """Every span of a round (and of an encoded round, which stacks
+    nothing) runs on the warm path without a new executor or trace."""
     from repro.kernels.runtime import trace_counts
     adapters, ranks, w = _warm_cohort()
+    enc = [codec.encode_adapters(a, "int8") for a in adapters]
     s = get_strategy("rbla").with_options()
-    run = lambda: s.aggregate_adapters(adapters, w, r_max=R_MAX,
-                                       client_ranks=ranks, backend="ref")
-    jax.block_until_ready(jax.tree.leaves(run()))        # warm
+
+    def run():
+        for cohort in (adapters, enc):
+            jax.block_until_ready(jax.tree.leaves(s.aggregate_adapters(
+                cohort, w, r_max=R_MAX, client_ranks=ranks,
+                backend="ref")))
+    run()                                                # warm
     execs = len(s.__dict__.get("_plan_exec_cache", {}))
     traces = dict(trace_counts)
+    hist = get_registry().get("obs_span_seconds")
+    seen = {st: hist.labels(stage=st).count for st in ROUND_SPANS}
     prev = set_enabled(True)
     try:
         for enabled in (True, False, True):
             set_enabled(enabled)
-            jax.block_until_ready(jax.tree.leaves(run()))
+            run()
     finally:
         set_enabled(prev)
     assert len(s.__dict__.get("_plan_exec_cache", {})) == execs
     assert dict(trace_counts) == traces
+    # two enabled passes of two rounds each; the int8 round stacks nothing
+    assert {st: hist.labels(stage=st).count - seen[st]
+            for st in ROUND_SPANS} == {
+        **{st: 4 for st in ROUND_SPANS}, "round.stack": 2}
+
+
+def test_metrics_toggle_never_retraces_warm_fold_path():
+    """The upload's and the packed fold's spans run on the warm path
+    without a new fold executor or kernel trace."""
+    from repro.kernels.runtime import trace_counts
+    adapters, ranks, w = _warm_cohort(seed=13)
+    s = get_strategy("rbla").with_options()
+    agg = AsyncAggregator(
+        s, ServerState(adapters=init_adapters(jax.random.PRNGKey(3), SPECS,
+                                              R_MAX, R_MAX),
+                       base_trainable={}, r_max=R_MAX),
+        backend="pallas", interpret=True)
+
+    def submit(i):
+        agg.submit(ClientUpdate(adapters=adapters[i], base_trainable={},
+                                n_examples=float(w[i]), rank=int(ranks[i])))
+        jax.block_until_ready(agg.state.adapters)
+    submit(0)                                            # warm
+    plans = len(s.__dict__["_fold_plan_cache"])
+    traces = dict(trace_counts)
+    stages = ("submit", "submit.validate", "fold", "fold.state_spec",
+              "fold.dispatch")
+    hist = get_registry().get("obs_span_seconds")
+    seen = {st: hist.labels(stage=st).count for st in stages}
+    prev = set_enabled(True)
+    try:
+        for i, enabled in enumerate((True, False, True), start=1):
+            set_enabled(enabled)
+            submit(i)
+    finally:
+        set_enabled(prev)
+    assert len(s.__dict__["_fold_plan_cache"]) == plans
+    assert dict(trace_counts) == traces
+    assert {st: hist.labels(stage=st).count - seen[st]
+            for st in stages} == {st: 2 for st in stages}
 
 
 def test_metrics_toggle_never_retraces_warm_serving_path():

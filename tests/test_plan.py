@@ -754,3 +754,77 @@ def test_encoded_plan_matches_decoded_oracle_with_prev():
                                     backend="ref")
     assert_trees_close(want, got, 1e-5, 1e-6)
     assert s_enc.plan_stats["misses"] == 1      # planned, not eager
+
+
+# ------------------------------------------------- host syncs, no blocking --
+SYNC_SITES = ("cohort_spec", "state_spec", "fold_rank", "validate_finite",
+              "validate_scale")
+
+
+def _host_syncs():
+    from repro.obs import host_syncs
+    return {site: host_syncs(site).value for site in SYNC_SITES}
+
+
+@pytest.mark.parametrize("case", ["f32_round", "int8_round", "fold",
+                                  "fold_unranked", "int8_fold"])
+def test_host_syncs_count_every_read_the_host_waits_on(case):
+    """``host_syncs_total{site}`` against the reads the code makes on a
+    tiny cohort: a round reads each stacked (or per-client) rank leaf
+    and the previous global's; an upload reads a finiteness flag per
+    float leaf, two flags per int8 scale leaf, and its fold the state's
+    rank leaves (and the upload's, when it names no rank).  Numpy
+    values (the client ranks here) are no reads."""
+    from repro.core import codec
+    from repro.fl import AsyncAggregator
+    n, pairs = 4, len(SPECS)
+    adapters, ranks, w = hetero_cohort(n, seed=41)
+    prev = init_adapters(jax.random.PRNGKey(8), SPECS, R_MAX, R_MAX)
+    before = _host_syncs()
+    if case.endswith("round"):
+        cohort = (adapters if case == "f32_round"
+                  else [codec.encode_adapters(a, "int8") for a in adapters])
+        fresh("rbla").aggregate_adapters(
+            cohort, w, r_max=R_MAX, client_ranks=np.asarray(ranks),
+            prev_global=prev, backend="pallas", interpret=True)
+        # one stacked rank leaf per pair, or one per client and pair
+        leaves = 1 if case == "f32_round" else n
+        want = {"cohort_spec": leaves * pairs + pairs}
+    else:
+        upload = (codec.encode_adapters(adapters[0], "int8")
+                  if case == "int8_fold" else adapters[0])
+        agg = AsyncAggregator(
+            fresh("rbla"), ServerState(adapters=prev, base_trainable={},
+                                       r_max=R_MAX),
+            backend="pallas", interpret=True)
+        agg.submit(ClientUpdate(
+            adapters=upload, base_trainable={}, n_examples=2.0,
+            rank=None if case == "fold_unranked" else int(ranks[0])))
+        # f32: A and B are float; int8: only the two scale leaves are
+        want = {"validate_finite": 2 * pairs, "state_spec": pairs}
+        if case == "fold_unranked":
+            want["fold_rank"] = pairs
+        if case == "int8_fold":
+            want["validate_scale"] = 2 * 2 * pairs
+    after = _host_syncs()
+    assert {k: after[k] - before[k] for k in SYNC_SITES} == {
+        **dict.fromkeys(SYNC_SITES, 0), **want}
+
+
+def test_aggregate_adapters_never_blocks(monkeypatch):
+    """Spans measure host time: nothing inside ``aggregate_adapters``
+    waits for the device, cold or warm, stacked or encoded."""
+    from repro.core import codec
+    calls = []
+    block = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append(1) or block(x))
+    adapters, ranks, w = hetero_cohort(4, seed=43)
+    enc = [codec.encode_adapters(a, "int8") for a in adapters]
+    prev = init_adapters(jax.random.PRNGKey(9), SPECS, R_MAX, R_MAX)
+    s = fresh("rbla")
+    for cohort in (adapters, adapters, enc, enc):
+        s.aggregate_adapters(cohort, w, r_max=R_MAX, client_ranks=ranks,
+                             prev_global=prev, backend="pallas",
+                             interpret=True)
+    assert calls == []

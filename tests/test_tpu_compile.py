@@ -137,3 +137,15 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     fn, shapes = _case(case, one_chip)
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("case,kernel", [("packed_agg_n8", "packed_agg"),
+                                         ("axpy_fold", "axpy_fold")])
+def test_compiled_kernel_carries_its_name(one_chip, case, kernel):
+    """The Mosaic custom call is named after its kernel, so a trace names
+    the kernel and not only the program that runs it."""
+    fn, shapes = _case(case, one_chip)
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all(f"%{kernel}" in line for line in calls), calls
