@@ -204,28 +204,31 @@ def _walk_pairs(tree, path=()):
         "whole LoRA pairs")
 
 
-class _Reads:
-    """The device-to-host reads of one cohort walk, added to
-    ``host_syncs_total{site="cohort_spec"}`` once, when the walk ends."""
+def _read_ranks(ranks: Sequence, prevs: Sequence, client_ranks):
+    """A walk's rank values on the host, in ONE ``jax.device_get`` over
+    all of them: the walk waits on the device once, not once per rank
+    leaf.  ``ranks`` are ``(what, leaf)`` pairs, ``prevs`` the previous
+    global's ``(path, pair)`` pairs, ``client_ranks`` may be None.  Each
+    ``jax.Array`` among them counts as one read in
+    ``host_syncs_total{site="cohort_spec"}``; a numpy or Python value is
+    no read.  Returns ``(rank arrays, prev rank arrays, client ranks
+    tuple or None)``."""
+    named = list(ranks) + [(f"prev rank leaf at {path}", pp["rank"])
+                           for path, pp in prevs]
+    if client_ranks is not None:
+        named.append(("client_ranks", client_ranks))
+    for what, x in named:
+        if isinstance(x, jax.core.Tracer):
+            raise PlanUnavailable(f"{what} is traced; plans are host-built")
+    _SYNCS_COHORT.inc(sum(isinstance(x, jax.Array) for _, x in named))
+    got = [np.asarray(v) for v in jax.device_get([x for _, x in named])]
+    if client_ranks is not None:
+        client_ranks = _rank_tuple(got.pop())
+    return got[:len(ranks)], got[len(ranks):], client_ranks
 
-    def __init__(self):
-        self.n = 0
 
-    def __enter__(self) -> "_Reads":
-        return self
-
-    def __exit__(self, *exc) -> None:
-        _SYNCS_COHORT.inc(self.n)
-
-
-def _concrete(x, what: str, reads: _Reads) -> np.ndarray:
-    """``x`` on the host; reading a ``jax.Array`` waits on the device
-    and counts in ``reads`` (a numpy or Python value is no read)."""
-    if isinstance(x, jax.core.Tracer):
-        raise PlanUnavailable(f"{what} is traced; plans are host-built")
-    if isinstance(x, jax.Array):
-        reads.n += 1
-    return np.asarray(jax.device_get(x))
+def _rank_tuple(x: np.ndarray) -> tuple:
+    return tuple(int(v) for v in x.ravel())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -270,8 +273,9 @@ class CohortSpec:
     interpret: bool | None = None
     mesh: Any = None
     client_axis: str = "clients"
-    #: per-client upload codec names ("none"|"bf16"|"int8") for encoded
-    #: cohorts (see repro.core.codec); None = plain fp32 stacked cohort.
+    #: per-client upload codec names ("none"|"bf16"|"int8") for a cohort
+    #: planned from per-client trees (see repro.core.codec; a plain
+    #: cohort is all "none"); None = a stacked cohort.
     #: Part of the key: a codec-mix change re-plans (and re-traces the
     #: executor), a rank-multiset repeat under the same mix still hits.
     codecs: tuple | None = None
@@ -282,6 +286,24 @@ class CohortSpec:
         return jnp.asarray(self.client_ranks, jnp.int32)
 
 
+def _prev_pairs(prev_tree, paths) -> list:
+    """``(path, pair)`` of ``prev_tree`` at each of ``paths`` (empty
+    without one)."""
+    if prev_tree is None:
+        return []
+    have = dict(_walk_pairs(prev_tree))
+    for path in paths:
+        if path not in have:
+            raise PlanUnavailable(f"prev tree missing pair at {path}")
+    return [(path, have[path]) for path in paths]
+
+
+def _with_prev(meta: dict, pp, prk: np.ndarray) -> None:
+    meta.update(prev_a_shape=tuple(pp["A"].shape),
+                prev_b_shape=tuple(pp["B"].shape),
+                prev_rank_shape=tuple(prk.shape), prev_ranks=_rank_tuple(prk))
+
+
 def build_cohort_spec(stacked_tree: PyTree, *, kind: str,
                       r_max: int | None = None, client_ranks=None,
                       prev_tree: PyTree | None = None,
@@ -290,47 +312,32 @@ def build_cohort_spec(stacked_tree: PyTree, *, kind: str,
     """Describe a stacked cohort host-side.  Raises
     :class:`PlanUnavailable` when the description needs values tracing
     hides (rank leaves, weights under jit) or the tree has bare leaves."""
-    with _Reads() as reads:
-        if client_ranks is not None:
-            client_ranks = tuple(
-                int(v) for v in _concrete(client_ranks, "client_ranks",
-                                          reads).ravel())
-        prev_pairs = (dict(_walk_pairs(prev_tree))
-                      if prev_tree is not None else {})
-        pairs = []
-        n = None
-        for path, pair in _walk_pairs(stacked_tree):
-            A, B, rank = pair["A"], pair["B"], pair["rank"]
-            if (isinstance(A, jax.core.Tracer)
-                    or isinstance(B, jax.core.Tracer)):
-                raise PlanUnavailable("cohort leaves are traced")
-            if A.ndim < 3 or B.ndim < 3:
-                raise PlanUnavailable(
-                    f"pair at {path} is not stacked over clients")
-            if n is None:
-                n = int(A.shape[0])
-            rk = _concrete(rank, f"rank leaf at {path}", reads)
-            meta = dict(path=path, a_shape=tuple(A.shape),
-                        a_dtype=str(A.dtype), b_shape=tuple(B.shape),
-                        b_dtype=str(B.dtype),
-                        rank_shape=tuple(rk.shape),
-                        ranks=tuple(int(v) for v in rk.ravel()))
-            if prev_tree is not None:
-                if path not in prev_pairs:
-                    raise PlanUnavailable(
-                        f"prev tree missing pair at {path}")
-                pp = prev_pairs[path]
-                prk = _concrete(pp["rank"], f"prev rank leaf at {path}",
-                                reads)
-                meta.update(prev_a_shape=tuple(pp["A"].shape),
-                            prev_b_shape=tuple(pp["B"].shape),
-                            prev_rank_shape=tuple(prk.shape),
-                            prev_ranks=tuple(int(v) for v in prk.ravel()))
-            pairs.append(PairMeta(**meta))
-    if not pairs:
+    walked = list(_walk_pairs(stacked_tree))
+    if not walked:
         raise PlanUnavailable("no LoRA pairs in the cohort tree")
-    return CohortSpec(n_clients=n, kind=kind, r_max=r_max,
-                      pairs=tuple(pairs), client_ranks=client_ranks,
+    for path, pair in walked:
+        if (isinstance(pair["A"], jax.core.Tracer)
+                or isinstance(pair["B"], jax.core.Tracer)):
+            raise PlanUnavailable("cohort leaves are traced")
+        if pair["A"].ndim < 3 or pair["B"].ndim < 3:
+            raise PlanUnavailable(
+                f"pair at {path} is not stacked over clients")
+    prevs = _prev_pairs(prev_tree, [path for path, _ in walked])
+    rks, prks, client_ranks = _read_ranks(
+        [(f"rank leaf at {path}", pair["rank"]) for path, pair in walked],
+        prevs, client_ranks)
+    pairs = []
+    for i, (path, pair) in enumerate(walked):
+        A, B, rk = pair["A"], pair["B"], rks[i]
+        meta = dict(path=path, a_shape=tuple(A.shape), a_dtype=str(A.dtype),
+                    b_shape=tuple(B.shape), b_dtype=str(B.dtype),
+                    rank_shape=tuple(rk.shape), ranks=_rank_tuple(rk))
+        if prevs:
+            _with_prev(meta, prevs[i][1], prks[i])
+        pairs.append(PairMeta(**meta))
+    return CohortSpec(n_clients=int(walked[0][1]["A"].shape[0]), kind=kind,
+                      r_max=r_max, pairs=tuple(pairs),
+                      client_ranks=client_ranks,
                       has_prev=prev_tree is not None, interpret=interpret,
                       mesh=mesh if kind == "distributed" else None,
                       client_axis=client_axis)
@@ -341,78 +348,70 @@ def build_encoded_cohort_spec(client_trees: Sequence, codecs, *, kind: str,
                               prev_tree: PyTree | None = None,
                               interpret: bool | None = None,
                               client_axis: str = "clients") -> CohortSpec:
-    """Describe an *encoded* cohort: per-client adapter trees carrying
-    wire dtypes (``repro.core.codec``), never leafwise-stacked -- stacking
-    int8 next to fp32 would either fail or promote, i.e. the forbidden
-    fp32 staging buffer.  ``codecs`` is the per-client codec-name tuple
-    (``cohort_codecs``); pair metadata records the **decoded** (f32)
-    dtypes so bucketing and unpacking match the fp32 cohort exactly and
-    only ``spec.codecs`` distinguishes the wire layout."""
+    """Describe a cohort from its *per-client* adapter trees, never
+    leafwise-stacked: plain uploads (codec ``"none"``) and encoded ones
+    carrying wire dtypes (``repro.core.codec``) alike -- stacking int8
+    next to fp32 would either fail or promote, and stacking at all makes
+    a cohort-sized copy the packed buckets do not need.  ``codecs`` is
+    the per-client codec-name tuple (``cohort_codecs``, or all
+    ``"none"`` for a plain cohort).  Pair metadata records the
+    **decoded** dtypes -- a wire dtype dequantizes to f32, a ``"none"``
+    client's leaves keep their own, and the pair takes the dtype
+    stacking would promote the clients' to -- so bucketing and unpacking
+    match the stacked cohort's exactly and only ``spec.codecs``
+    distinguishes the layout.  Every rank leaf of the walk (the
+    previous global's and ``client_ranks`` included) is read in one
+    :func:`_read_ranks`."""
     codecs = tuple(codecs)
     n = len(client_trees)
+    if n == 0:
+        raise PlanUnavailable("empty cohort")
     if len(codecs) != n:
         raise PlanUnavailable(f"{len(codecs)} codecs for {n} clients")
     if any(c not in ("none", "bf16", "int8") for c in codecs):
         raise PlanUnavailable(
             "per-pair mixed codecs inside one client are not plannable")
-    prev_pairs = (dict(_walk_pairs(prev_tree))
-                  if prev_tree is not None else {})
     walked = [list(_walk_pairs(t)) for t in client_trees]
     paths = [p for p, _ in walked[0]]
+    if not paths:
+        raise PlanUnavailable("no LoRA pairs in the cohort trees")
     for i, wl in enumerate(walked[1:], start=1):
         if [p for p, _ in wl] != paths:
             raise PlanUnavailable(
                 f"client {i}'s tree structure differs from client 0's")
-    with _Reads() as reads:
-        if client_ranks is not None:
-            client_ranks = tuple(
-                int(v) for v in _concrete(client_ranks, "client_ranks",
-                                          reads).ravel())
-        inferred: list | None = [] if client_ranks is None else None
-        pairs = []
-        for pi, path in enumerate(paths):
-            metas = []
-            rks = []
-            for i in range(n):
-                pair = walked[i][pi][1]
-                A, B = pair["A"], pair["B"]
-                if (isinstance(A, jax.core.Tracer)
-                        or isinstance(B, jax.core.Tracer)):
-                    raise PlanUnavailable("cohort leaves are traced")
-                metas.append((tuple(A.shape), tuple(B.shape)))
-                rks.append(_concrete(pair["rank"], f"rank leaf at {path}",
-                                     reads))
-            if any(m != metas[0] for m in metas[1:]):
-                raise PlanUnavailable(
-                    f"clients disagree on pair shapes at {path}")
-            rk = np.stack(rks)
-            if inferred is not None and pi == 0 and rk.ndim == 1:
-                inferred.extend(int(v) for v in rk)
-            a_shape = (n,) + metas[0][0]
-            b_shape = (n,) + metas[0][1]
-            # decoded dtype: wire dtypes dequantize to f32; an all-"none"
-            # pair keeps its own dtype (can't happen cohort-wide -- that
-            # cohort has codecs=None and takes the stacked path)
-            meta = dict(path=path, a_shape=a_shape, a_dtype="float32",
-                        b_shape=b_shape, b_dtype="float32",
-                        rank_shape=tuple(rk.shape),
-                        ranks=tuple(int(v) for v in rk.ravel()))
-            if prev_tree is not None:
-                if path not in prev_pairs:
-                    raise PlanUnavailable(
-                        f"prev tree missing pair at {path}")
-                pp = prev_pairs[path]
-                prk = _concrete(pp["rank"], f"prev rank leaf at {path}",
-                                reads)
-                meta.update(prev_a_shape=tuple(pp["A"].shape),
-                            prev_b_shape=tuple(pp["B"].shape),
-                            prev_rank_shape=tuple(prk.shape),
-                            prev_ranks=tuple(int(v) for v in prk.ravel()))
-            pairs.append(PairMeta(**meta))
-    if not pairs:
-        raise PlanUnavailable("no LoRA pairs in the cohort trees")
-    if client_ranks is None and inferred:
-        client_ranks = tuple(inferred)
+    prevs = _prev_pairs(prev_tree, paths)
+    geometry = []
+    for pi, path in enumerate(paths):
+        cps = [wl[pi][1] for wl in walked]
+        if any(isinstance(p[s], jax.core.Tracer) for p in cps for s in "AB"):
+            raise PlanUnavailable("cohort leaves are traced")
+        shapes = {(tuple(p["A"].shape), tuple(p["B"].shape)) for p in cps}
+        if len(shapes) > 1:
+            raise PlanUnavailable(
+                f"clients disagree on pair shapes at {path}")
+        (a_shape, b_shape), = shapes
+        dtypes = [str(jnp.result_type(*{
+            p[s].dtype if c == "none" else jnp.float32
+            for p, c in zip(cps, codecs)})) for s in "AB"]
+        geometry.append(((n,) + a_shape, (n,) + b_shape, dtypes))
+    rks, prks, client_ranks = _read_ranks(
+        [(f"rank leaf at {path}", wl[pi][1]["rank"])
+         for pi, path in enumerate(paths) for wl in walked],
+        prevs, client_ranks)
+    pairs = []
+    for pi, path in enumerate(paths):
+        rk = np.stack(rks[pi * n:(pi + 1) * n])
+        if client_ranks is None and rk.ndim == 1:
+            # the first scalar-rank pair names each client's rank, as
+            # for a stacked cohort (``strategy._infer_ranks``)
+            client_ranks = _rank_tuple(rk)
+        a_shape, b_shape, (a_dtype, b_dtype) = geometry[pi]
+        meta = dict(path=path, a_shape=a_shape, a_dtype=a_dtype,
+                    b_shape=b_shape, b_dtype=b_dtype,
+                    rank_shape=tuple(rk.shape), ranks=_rank_tuple(rk))
+        if prevs:
+            _with_prev(meta, prevs[pi][1], prks[pi])
+        pairs.append(PairMeta(**meta))
     return CohortSpec(n_clients=n, kind=kind, r_max=r_max,
                       pairs=tuple(pairs), client_ranks=client_ranks,
                       has_prev=prev_tree is not None, interpret=interpret,
@@ -592,12 +591,16 @@ class CompiledRound:
         ``(shape, sharding)`` of each packed client buffer the last call
         handed to its collective (distributed plans; None elsewhere) --
         where each chip's share of the cohort actually sat.
+    ``pack_memo``
+        the :class:`BufferMemo` of packed buckets a re-participating
+        cohort reuses (mean plans; None elsewhere).
     """
 
     def __init__(self, strategy, spec: CohortSpec, kind: str,
                  execute: Callable, *, n_kernel_launches: int | None = None,
                  n_fallback_pairs: int = 0,
-                 n_pallas_launches: int | None = None):
+                 n_pallas_launches: int | None = None,
+                 pack_memo: BufferMemo | None = None):
         self.strategy = strategy
         self.spec = spec
         self.kind = kind
@@ -606,6 +609,7 @@ class CompiledRound:
         self.n_fallback_pairs = n_fallback_pairs
         self.n_pallas_launches = n_pallas_launches
         self.input_shardings = None
+        self.pack_memo = pack_memo
         self.n_calls = 0
 
     def __call__(self, stacked_tree: PyTree, weights, prev_tree=None,
@@ -833,10 +837,11 @@ def _build_mean_round(strategy, spec: CohortSpec,
 
     return CompiledRound(strategy, spec, "packed", execute,
                          n_kernel_launches=len(buckets),
-                         n_pallas_launches=len(buckets) if use_kernel else 0)
+                         n_pallas_launches=len(buckets) if use_kernel else 0,
+                         pack_memo=pack_memo)
 
 
-# ---------------------------------------------- encoded (quantized) plans --
+# ------------------------------------ per-client (plain, encoded) plans --
 def _enc_ab_list(tree) -> list:
     """Like :func:`_ab_list` but keeps the int8 codec's per-row scale
     leaves riding with each pair."""
@@ -869,12 +874,14 @@ def _pack_client_scale(pair, slot: Slot):
 
 def _build_encoded_mean_round(strategy, spec: CohortSpec,
                               norm_restore: bool = False) -> CompiledRound:
-    """Mean/robust packed round over an *encoded* cohort (per-client wire
-    dtypes from ``spec.codecs``).
+    """Mean/robust packed round over a cohort of per-client trees (codecs
+    from ``spec.codecs``; a plain cohort is one ``"none"`` group).
 
-    Clients group by codec (static index tuples); each bucket packs one
-    ``(n_g, rows, width)`` payload per group in the group's wire dtype
-    plus ``(n_g, rows)`` f32 scales for int8 groups.  A uniform-codec
+    Clients group by codec (static index tuples); one jitted ``pack_fn``
+    packs each bucket's ``(n_g, rows, width)`` payload per group straight
+    from the client leaves -- in the group's wire dtype, or f32 for
+    ``"none"`` -- plus ``(n_g, rows)`` f32 scales for int8 groups; no
+    stacked copy of the cohort is ever made.  A uniform-codec
     cohort keeps the one-fused-launch-per-bucket property -- the scales
     ride into ``packed_agg``/``packed_robust`` as runtime data and
     dequantization happens inside the kernel.  A mixed mean combines
@@ -1057,7 +1064,11 @@ def _build_encoded_mean_round(strategy, spec: CohortSpec,
         exec_cache[key] = fns
     pack, fn, fn_donate = fns
     rebuild = [None]
-    pack_memo = BufferMemo()
+    # require_repeat: the fingerprinted buffers are the uploads
+    # themselves, which may live for many rounds (a pool the cohort is
+    # drawn from) -- a cohort that does not repeat leaves a fingerprint,
+    # not a cohort-sized payload or a finalizer on every upload
+    pack_memo = BufferMemo(require_repeat=True)
 
     def execute(client_trees, w, prev_tree, donate):
         if rebuild[0] is None:
@@ -1090,7 +1101,8 @@ def _build_encoded_mean_round(strategy, spec: CohortSpec,
                       and (len(groups) == 1 or robust != "none") else 0)
     return CompiledRound(strategy, spec, "packed", execute,
                          n_kernel_launches=len(buckets),
-                         n_pallas_launches=kernel_buckets)
+                         n_pallas_launches=kernel_buckets,
+                         pack_memo=pack_memo)
 
 
 def _build_mean_distributed(strategy, spec, buckets, masks_const,
@@ -1618,10 +1630,10 @@ def build_plan(strategy, spec: CohortSpec) -> CompiledRound:
     mode = getattr(strategy, "plan_mode", None)
     if spec.codecs is not None and (mode not in ("mean", "mean_norm")
                                     or spec.kind == "distributed"):
-        # encoded cohorts lower through the packed mean family only; the
-        # caller decodes eagerly for stack/svd/jit/eager/distributed
+        # per-client cohorts lower through the packed mean family only;
+        # the caller decodes and stacks for stack/svd/jit/eager/distributed
         raise PlanUnavailable(
-            "encoded cohorts plan only on the mean family")
+            "per-client cohorts plan only on the mean family")
     try:
         if mode == "mean":
             return _build_mean_round(strategy, spec)
@@ -1630,7 +1642,7 @@ def build_plan(strategy, spec: CohortSpec) -> CompiledRound:
                     len(m.a_shape) != 3 for m in spec.pairs):
                 if spec.codecs is not None:
                     raise PlanUnavailable(
-                        "encoded mean_norm needs scalar-rank pairs")
+                        "per-client mean_norm needs scalar-rank pairs")
                 return _build_eager_round(strategy, spec)
             return _build_mean_round(strategy, spec, norm_restore=True)
         if mode == "stack":
@@ -1649,8 +1661,8 @@ def build_plan(strategy, spec: CohortSpec) -> CompiledRound:
             return _build_jit_round(strategy, spec)
     except PlanUnavailable:
         if spec.codecs is not None:
-            # the eager round expects a stacked fp32 tree -- propagate so
-            # the caller decodes and retries on the standard path
+            # the eager round expects a stacked tree -- propagate so the
+            # caller decodes, stacks and retries on the stacked path
             raise
         return _build_eager_round(strategy, spec)
     return _build_eager_round(strategy, spec)

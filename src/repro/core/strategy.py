@@ -47,7 +47,7 @@ import numpy as np
 from jax import lax
 
 from repro.obs import get_registry as _obs_registry
-from repro.obs import host_syncs, span
+from repro.obs import cohort_stacks, host_syncs, span
 
 from .aggregation import _EPS, fedavg_leaf, rbla_leaf, zeropad_leaf
 from .compat import shard_map_no_check
@@ -462,11 +462,11 @@ class AggregationStrategy:
     def _plan_encoded_round(self, client_adapters, codecs, kind, *, r_max,
                             client_ranks, prev, interpret,
                             client_axis="clients"):
-        """Best-effort plan for an *encoded* (quantized-upload) cohort --
-        per-client trees, never stacked; ``None`` sends the caller to the
-        decode-eagerly fallback.  Shares :meth:`plan`'s cache, so a codec
-        mix change re-plans while a rank-multiset repeat under the same
-        mix hits."""
+        """Best-effort plan for a cohort of per-client trees, plain
+        (``codecs`` all ``"none"``) or encoded -- never stacked; ``None``
+        sends the caller to the decode-and-stack fallback.  Shares
+        :meth:`plan`'s cache, so a codec mix change re-plans while a
+        rank-multiset repeat under the same mix hits."""
         from .plan import PlanUnavailable, build_encoded_cohort_spec
         try:
             with span("round.spec"):
@@ -671,13 +671,21 @@ class AggregationStrategy:
                            donate: bool = False) -> PyTree:
         """Aggregate per-client adapter trees into the global adapter.
 
-        Stacks the uploads and routes the round through a cached
+        Routes the round through a cached
         :class:`~repro.core.plan.CompiledRound` (packed buffers, one
-        fused launch per bucket -- see :meth:`plan`); the per-leaf
-        ``aggregate_tree*`` paths remain the plan's oracles and the
-        in-trace fallback (``use_plan=False``, or leaves/ranks hidden by
-        jit tracing).  ``donate=True`` donates ``prev_global``'s A/B
-        buffers to the round -- the caller must not touch them after.
+        fused launch per bucket -- see :meth:`plan`).  The mean family
+        (``plan_mode`` ``"mean"``/``"mean_norm"``) on the ref and pallas
+        backends plans the per-client trees directly, plain or encoded
+        (``repro.core.codec``): one jitted pack writes the client leaves
+        straight into the bucket buffers, and the cohort is never
+        stacked.  Every other path -- distributed, the stack/svd/jit/
+        eager plans, cohorts the per-client walk cannot describe --
+        decodes and stacks the uploads first (``cohort_stacks_total``
+        counts those cohorts).  The per-leaf ``aggregate_tree*`` paths
+        remain the plans' oracles and the in-trace fallback
+        (``use_plan=False``, or leaves/ranks hidden by jit tracing).
+        ``donate=True`` donates ``prev_global``'s A/B buffers to the
+        round -- the caller must not touch them after.
 
         Output rank bookkeeping follows :meth:`finalize_tree`: fixed-rank
         strategies reset the live rank to ``r_max`` (clients re-slice,
@@ -685,44 +693,39 @@ class AggregationStrategy:
         keep the live rank their aggregation wrote -- read it from the
         output pairs.
 
-        When the same cohort re-participates (the same client arrays
-        resubmitted -- benchmarks, replay, weight-only re-aggregation),
-        the host-side re-stacking is skipped: uploads are fingerprinted
-        by buffer identity (jax arrays are immutable) and the previous
-        stacked tree is reused, which also lets the compiled round reuse
-        its packed buckets (see ``plan_stats['pack_reuses']``).
+        When the same cohort re-participates on consecutive rounds (the
+        same client arrays resubmitted -- benchmarks, replay,
+        weight-only re-aggregation), the packing is skipped: uploads are
+        fingerprinted by buffer identity (jax arrays are immutable) and
+        the packed buckets, or on the stacking paths the stacked tree,
+        are reused (see ``plan_stats['pack_reuses']``).
         """
         from repro.lora import adapter_masks
 
+        from .codec import cohort_codecs, decode_adapters
         from .plan import BufferMemo
-
-        from .codec import cohort_codecs
         # the call's sequence number on this instance tags its spans
         seq = self.__dict__.setdefault("_round_seq", itertools.count())
         with span("round", round=next(seq)):
             codecs = cohort_codecs(client_adapters)
+            kind = resolve_backend(backend, self)
+            prev = prev_global if self.retains_prev else None
+            if (use_plan and kind in ("ref", "pallas")
+                    and getattr(self, "plan_mode", None) in ("mean",
+                                                             "mean_norm")
+                    and (codecs is None or "mixed" not in codecs)):
+                # per-client trees, packed inside the plan's one jitted
+                # pack: no stacked copy, and encoded uploads keep their
+                # wire dtypes (dequant fused into the packed kernels)
+                round_ = self._plan_encoded_round(
+                    client_adapters,
+                    codecs or ("none",) * len(client_adapters), kind,
+                    r_max=r_max, client_ranks=client_ranks, prev=prev,
+                    interpret=interpret, client_axis=client_axis)
+                if round_ is not None:
+                    return round_(client_adapters, weights, prev,
+                                  donate=donate)
             if codecs is not None:
-                # encoded uploads (repro.core.codec): the mean family
-                # plans them directly -- per-client wire-dtype payloads,
-                # dequant fused into the packed kernels, no stacked fp32
-                # staging buffer.  Everything else (stack/svd/jit/eager/
-                # distributed, intra-client codec mixes, unplannable
-                # cohorts) decodes eagerly and takes the standard path
-                # below.
-                kind_enc = resolve_backend(backend, self)
-                if (use_plan and "mixed" not in codecs
-                        and getattr(self, "plan_mode", None) in ("mean",
-                                                                 "mean_norm")
-                        and kind_enc in ("ref", "pallas")):
-                    prev_enc = prev_global if self.retains_prev else None
-                    round_ = self._plan_encoded_round(
-                        client_adapters, codecs, kind_enc, r_max=r_max,
-                        client_ranks=client_ranks, prev=prev_enc,
-                        interpret=interpret, client_axis=client_axis)
-                    if round_ is not None:
-                        return round_(client_adapters, weights, prev_enc,
-                                      donate=donate)
-                from .codec import decode_adapters
                 client_adapters = [decode_adapters(a)
                                    for a in client_adapters]
 
@@ -739,6 +742,7 @@ class AggregationStrategy:
                 stacked = memo.lookup(leaves)
                 if stacked is None:
                     stacked = stack_trees(client_adapters)
+                    cohort_stacks(self.name).inc()
                     # identity-memoized only for immutable non-traced jax
                     # buffers seen on consecutive rounds, released as soon
                     # as the uploads die -- the BufferMemo invariants
@@ -746,8 +750,6 @@ class AggregationStrategy:
             if client_ranks is None:
                 client_ranks = _infer_ranks(stacked)
             w = jnp.asarray(weights, jnp.float32)
-            prev = prev_global if self.retains_prev else None
-            kind = resolve_backend(backend, self)
             if use_plan:
                 round_ = self._plan_round(
                     stacked, kind, r_max=r_max, client_ranks=client_ranks,

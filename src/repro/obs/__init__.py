@@ -11,7 +11,8 @@ The operational substrate for the FLaaS server (see
   a synchronous ``round``'s stages) on the host clock and, as
   ``obs.<stage>`` annotations, on the profiler's; spans degrade to
   no-ops under jit (the zero-retrace guarantee); and the
-  ``host_syncs_total{site}`` counter of device-to-host reads;
+  ``host_syncs_total{site}`` counter of device-to-host reads and the
+  ``cohort_stacks_total{strategy}`` counter of eagerly stacked cohorts;
 * :mod:`repro.obs.export` -- Prometheus text format, JSON-lines, and
   the in-memory :meth:`MetricsRegistry.snapshot`;
 * :mod:`repro.obs.health` -- :class:`ServiceHealth`, the one-call
@@ -22,7 +23,7 @@ The operational substrate for the FLaaS server (see
 from .metrics import (LATENCY_BUCKETS, REGISTRY, STALENESS_BUCKETS,
                       Counter, Gauge, Histogram, MetricsRegistry,
                       get_registry, metrics_enabled, set_enabled)
-from .trace import ROUND_STAGES, Span, host_syncs, span
+from .trace import ROUND_STAGES, Span, cohort_stacks, host_syncs, span
 from .export import parse_prometheus, to_prometheus, write_jsonl_snapshot
 from .health import ServiceHealth
 from .timing import bench_payload, block, time_fn
@@ -31,7 +32,7 @@ __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "REGISTRY",
     "get_registry", "set_enabled", "metrics_enabled",
     "LATENCY_BUCKETS", "STALENESS_BUCKETS",
-    "span", "Span", "ROUND_STAGES", "host_syncs",
+    "span", "Span", "ROUND_STAGES", "host_syncs", "cohort_stacks",
     "to_prometheus", "parse_prometheus", "write_jsonl_snapshot",
     "ServiceHealth",
     "block", "time_fn", "bench_payload",

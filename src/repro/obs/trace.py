@@ -30,6 +30,8 @@ them):
 
 :func:`host_syncs` is the ``host_syncs_total{site}`` counter: every
 device-to-host read the host waits on, counted where it happens.
+:func:`cohort_stacks` is ``cohort_stacks_total{strategy}``: every cohort
+``aggregate_adapters`` still stacks leaf by leaf before planning.
 """
 from __future__ import annotations
 
@@ -141,5 +143,15 @@ def host_syncs(site: str, registry=None):
         labelnames=("site",)).labels(site=site)
 
 
+def cohort_stacks(strategy: str):
+    """The ``cohort_stacks_total{strategy=...}`` child: cohorts stacked
+    eagerly (``stack_trees``) on the paths that still need the stacked
+    ``(n, ...)`` tree."""
+    return get_registry().counter(
+        "cohort_stacks_total",
+        "cohorts aggregate_adapters stacked leaf by leaf, by strategy",
+        labelnames=("strategy",)).labels(strategy=strategy)
+
+
 __all__ = ["span", "Span", "ROUND_STAGES", "ANNOTATION_PREFIX",
-           "host_syncs"]
+           "host_syncs", "cohort_stacks"]

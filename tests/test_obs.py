@@ -264,9 +264,13 @@ def test_spans_write_nested_annotations_into_the_profiler_trace(tmp_path):
         out = s.aggregate_adapters(adapters, w, r_max=R_MAX,
                                    client_ranks=ranks, prev_global=prev,
                                    backend="pallas", interpret=True)
+        # the per-leaf path still stacks the cohort: ``round.stack``
+        ref = s.aggregate_adapters(adapters, w, r_max=R_MAX,
+                                   client_ranks=ranks, prev_global=prev,
+                                   backend="ref", use_plan=False)
         agg.submit(ClientUpdate(adapters=adapters[0], base_trainable={},
                                 n_examples=2.0, rank=int(ranks[0])))
-        jax.block_until_ready((out, agg.state.adapters))
+        jax.block_until_ready((out, ref, agg.state.adapters))
     run()                                   # compiles stay out of the trace
     jax.profiler.start_trace(str(tmp_path))
     try:
@@ -281,7 +285,7 @@ def test_spans_write_nested_annotations_into_the_profiler_trace(tmp_path):
     for stage in ("state_spec", "dispatch"):
         assert _nested(ev, "fold." + stage, "fold"), stage
     assert [set(e[4]) for e in ev if e[0] in ("round", "submit")] == [
-        {"round"}, {"upload"}]
+        {"round"}, {"round"}, {"upload"}]
 
 
 # ---------------------------------------------------------- zero-retrace ----
@@ -295,18 +299,20 @@ ROUND_SPANS = ("round", "round.stack", "round.spec", "round.plan",
 
 
 def test_metrics_toggle_never_retraces_warm_plan_path():
-    """Every span of a round (and of an encoded round, which stacks
-    nothing) runs on the warm path without a new executor or trace."""
+    """Every span of a round -- plain and encoded rounds pack per client,
+    the per-leaf path stacks -- runs on the warm path without a new
+    executor or trace."""
     from repro.kernels.runtime import trace_counts
     adapters, ranks, w = _warm_cohort()
     enc = [codec.encode_adapters(a, "int8") for a in adapters]
     s = get_strategy("rbla").with_options()
 
     def run():
-        for cohort in (adapters, enc):
+        for cohort, use_plan in ((adapters, True), (enc, True),
+                                 (adapters, False)):
             jax.block_until_ready(jax.tree.leaves(s.aggregate_adapters(
                 cohort, w, r_max=R_MAX, client_ranks=ranks,
-                backend="ref")))
+                backend="ref", use_plan=use_plan)))
     run()                                                # warm
     execs = len(s.__dict__.get("_plan_exec_cache", {}))
     traces = dict(trace_counts)
@@ -321,10 +327,11 @@ def test_metrics_toggle_never_retraces_warm_plan_path():
         set_enabled(prev)
     assert len(s.__dict__.get("_plan_exec_cache", {})) == execs
     assert dict(trace_counts) == traces
-    # two enabled passes of two rounds each; the int8 round stacks nothing
+    # two enabled passes of three rounds each: two planned per client,
+    # one stacked and unplanned
     assert {st: hist.labels(stage=st).count - seen[st]
             for st in ROUND_SPANS} == {
-        **{st: 4 for st in ROUND_SPANS}, "round.stack": 2}
+        **{st: 4 for st in ROUND_SPANS}, "round": 6, "round.stack": 2}
 
 
 def test_metrics_toggle_never_retraces_warm_fold_path():

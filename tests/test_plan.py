@@ -264,17 +264,21 @@ def test_mutable_numpy_uploads_are_never_memoized():
 
 
 def test_stack_memo_releases_payload_when_uploads_die():
-    """The memos must not pin cohort-sized buffers for the process
-    lifetime: a one-shot cohort leaves only a fingerprint behind, and
-    once a repeating cohort's uploads die the payload is released
-    eagerly -- without waiting for the same plan to execute again."""
+    """The per-client pack memo must not pin cohort-sized buffers for
+    the process lifetime: a one-shot cohort leaves only a fingerprint
+    behind, a repeat keeps the packed buckets, and once the repeating
+    cohort's uploads die the payload is released eagerly -- without
+    waiting for the same plan to execute again."""
     import gc
     s = fresh("rbla")
     adapters, ranks, w = hetero_cohort(4, seed=41)
     s.aggregate_adapters(adapters, w, r_max=R_MAX, client_ranks=ranks,
                          backend="ref")
-    memo = s.__dict__["_stack_memo"]
+    rd, = s.__dict__["_plan_cache"].values()
+    assert rd.spec.codecs == ("none",) * 4      # planned per client
+    memo = rd.pack_memo
     assert memo._entry is None         # first sight: fingerprint only
+    assert memo._candidate is not None
     s.aggregate_adapters(adapters, w, r_max=R_MAX, client_ranks=ranks,
                          backend="ref")
     assert memo._entry is not None     # repeat: payload kept
@@ -756,6 +760,72 @@ def test_encoded_plan_matches_decoded_oracle_with_prev():
     assert s_enc.plan_stats["misses"] == 1      # planned, not eager
 
 
+# ------------------------------------------- plain cohorts, per client --
+MEAN_FAMILY = ("rbla", "zeropad", "fedavg", "rbla_ranked", "rbla_norm",
+               "rbla_clipped", "rbla_trimmed", "rbla_median")
+
+
+def _no_stack(*_, **__):
+    raise AssertionError("stack_trees called on the per-client path")
+
+
+@pytest.mark.parametrize("donate", [False, True])
+@pytest.mark.parametrize("with_prev", [False, True])
+@pytest.mark.parametrize("method", MEAN_FAMILY)
+def test_mean_family_plans_plain_cohorts_per_client(method, with_prev,
+                                                    donate, monkeypatch):
+    """A plain f32 cohort of the mean family on ref is packed per client
+    inside the plan: ``stack_trees`` never runs and
+    ``cohort_stacks_total`` stays put.  The round equals the per-leaf
+    oracle, and bit for bit the stacked plan over the same buckets."""
+    from repro.core import strategy as strategy_mod
+    from repro.obs import cohort_stacks
+    adapters, ranks, w = hetero_cohort(4, seed=44, r_lo=2, r_hi=6)
+
+    def prev():                       # fresh buffers: donation eats them
+        return (init_adapters(jax.random.PRNGKey(45), SPECS, R_MAX, R_MAX)
+                if with_prev else None)
+    s = fresh(method)
+    want = s.aggregate_adapters(adapters, w, r_max=R_MAX, client_ranks=ranks,
+                                prev_global=prev(), backend="ref",
+                                use_plan=False)
+    spec = build_cohort_spec(stack_trees(adapters), kind="ref", r_max=R_MAX,
+                             client_ranks=ranks,
+                             prev_tree=prev() if s.retains_prev else None)
+    stacked = fresh(method).plan(None, spec)(
+        stack_trees(adapters), w, prev() if s.retains_prev else None)
+    monkeypatch.setattr(strategy_mod, "stack_trees", _no_stack)
+    stacks = cohort_stacks(s.name)
+    before = stacks.value
+    got = s.aggregate_adapters(adapters, w, r_max=R_MAX, client_ranks=ranks,
+                               prev_global=prev(), backend="ref",
+                               donate=donate)
+    assert stacks.value == before
+    rd, = s.__dict__["_plan_cache"].values()
+    assert rd.kind == "packed" and rd.spec.codecs == ("none",) * 4
+    assert_trees_close(got, want, msg=method)
+    for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(stacked)):
+        assert x.dtype == y.dtype
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_stacking_paths_count_their_cohort_stacks():
+    """Paths that need the ``(n, ...)`` tree still stack, once a cohort,
+    and say so in ``cohort_stacks_total``: the stack plan (flora), the
+    per-leaf path and the distributed plan."""
+    from repro.obs import cohort_stacks
+    adapters, ranks, w = hetero_cohort(3, seed=46)
+    for method, kw in (("flora", dict(backend="ref")),
+                       ("rbla", dict(backend="ref", use_plan=False)),
+                       ("rbla", dict(backend="distributed"))):
+        s = fresh(method)
+        stacks = cohort_stacks(s.name)
+        before = stacks.value
+        s.aggregate_adapters(adapters, w, r_max=R_MAX, client_ranks=ranks,
+                             **kw)
+        assert stacks.value == before + 1, (method, kw)
+
+
 # ------------------------------------------------- host syncs, no blocking --
 SYNC_SITES = ("cohort_spec", "state_spec", "fold_rank", "validate_finite",
               "validate_scale")
@@ -770,8 +840,8 @@ def _host_syncs():
                                   "fold_unranked", "int8_fold"])
 def test_host_syncs_count_every_read_the_host_waits_on(case):
     """``host_syncs_total{site}`` against the reads the code makes on a
-    tiny cohort: a round reads each stacked (or per-client) rank leaf
-    and the previous global's; an upload reads a finiteness flag per
+    tiny cohort: a round reads each client's rank leaves and the
+    previous global's; an upload reads a finiteness flag per
     float leaf, two flags per int8 scale leaf, and its fold the state's
     rank leaves (and the upload's, when it names no rank).  Numpy
     values (the client ranks here) are no reads."""
@@ -787,9 +857,8 @@ def test_host_syncs_count_every_read_the_host_waits_on(case):
         fresh("rbla").aggregate_adapters(
             cohort, w, r_max=R_MAX, client_ranks=np.asarray(ranks),
             prev_global=prev, backend="pallas", interpret=True)
-        # one stacked rank leaf per pair, or one per client and pair
-        leaves = 1 if case == "f32_round" else n
-        want = {"cohort_spec": leaves * pairs + pairs}
+        # one rank leaf per client and pair, plain or encoded
+        want = {"cohort_spec": n * pairs + pairs}
     else:
         upload = (codec.encode_adapters(adapters[0], "int8")
                   if case == "int8_fold" else adapters[0])
@@ -809,6 +878,37 @@ def test_host_syncs_count_every_read_the_host_waits_on(case):
     after = _host_syncs()
     assert {k: after[k] - before[k] for k in SYNC_SITES} == {
         **dict.fromkeys(SYNC_SITES, 0), **want}
+
+
+@pytest.mark.parametrize("wire", ["none", "int8"])
+@pytest.mark.parametrize("with_prev", [False, True])
+def test_cohort_walk_reads_every_rank_leaf_in_one_device_get(
+        wire, with_prev, monkeypatch):
+    """The per-client walk fetches all its rank leaves (the previous
+    global's too) with ONE ``jax.device_get`` a round, warm or cold,
+    while ``host_syncs_total{site="cohort_spec"}`` still counts each
+    array read: ``n x pairs`` (+ ``pairs`` with a previous global)."""
+    from repro.core import codec
+    from repro.obs import host_syncs
+    n, pairs = 4, len(SPECS)
+    adapters, ranks, w = hetero_cohort(n, seed=48)
+    if wire != "none":
+        adapters = [codec.encode_adapters(a, wire) for a in adapters]
+    prev = (init_adapters(jax.random.PRNGKey(49), SPECS, R_MAX, R_MAX)
+            if with_prev else None)
+    calls = []
+    get = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: calls.append(1) or get(x))
+    reads = host_syncs("cohort_spec")
+    s = fresh("rbla")
+    for rnd in (1, 2):
+        before = reads.value
+        s.aggregate_adapters(adapters, w, r_max=R_MAX,
+                             client_ranks=np.asarray(ranks),
+                             prev_global=prev, backend="ref")
+        assert len(calls) == rnd
+        assert reads.value - before == n * pairs + pairs * with_prev
 
 
 def test_aggregate_adapters_never_blocks(monkeypatch):
