@@ -7,6 +7,34 @@ planes).  Every pair is checked against the configuration file's own
 widths before anything is generated, so a change to the program's
 adapter layout cannot silently change what is measured.
 
+A configuration file's ``adapter`` states the adapter tree in one of two
+forms.  One stage of a single repeating layer::
+
+    {"arch": <registry name>, "r_max": 64, "layers_key": <depth key>,
+     "targets": {<target>: [fan_out, fan_in], ...}}
+
+or one entry per stage of the program's architecture, in its order::
+
+    {"arch": ..., "r_max": 64, "stages": [
+       {"depth": [<key>, <minus key>, ...],
+        "targets": {<target>: [fan_out, fan_in], ...},
+        "lead": {<target>: [<axis key>, ...]}}, ...]}
+
+A stage's depth, in layers, is the file's value of its first depth key
+less those of the others (``["num_hidden_layers",
+"first_k_dense_replace"]`` is the depth after a dense prefix); it must be
+a whole number of repeats of the stage's layer pattern.  Its published
+depth is the same sum over the published values
+(``reduced[key]["published"]`` where the key is cut).  ``targets`` are
+keyed by the program's target name (the last part of the pair's path)
+and hold the widths of that stage alone: two stages that share a target
+name state its widths twice.  ``lead`` names, for a target with axes of
+its own between the layer axis and the rank axis (the experts held), the
+keys whose values are those axes.  With ``n`` the stage's repeats, every
+pair must be A ``(n, *axes, r_max, fan_in)``, B ``(n, *axes, fan_out,
+r_max)``, rank ``(n,)``.  ``program_overrides`` (optional) are set on
+the program's architecture before it is built.
+
 Host draws (cohort order, weights, staleness) come from NumPy generators
 seeded with ``(seed, stream, index)``: round ``k`` or ring cycle ``c``
 draws the same values whatever ran before it, which lets the reference
@@ -65,9 +93,11 @@ class Layout:
     """Adapter tree of one client: the program's container structure with
     every pair's shapes taken from the configuration file."""
     template: object           # the program's tree of ShapeDtypeStructs
-    layers: int
     r_max: int
-    widths: dict               # target -> (fan_out, fan_in)
+    #: path -> (fan_out, fan_in, lead) in tree order; ``lead`` is the
+    #: stage's repeats of its layer pattern (its depth, for a pattern of
+    #: one layer) followed by the pair's own axes (the experts held)
+    pairs: dict
     makers: dict = dataclasses.field(default_factory=dict)
 
     def maker(self, codec: str):
@@ -79,45 +109,79 @@ class Layout:
         return self.makers[codec]
 
 
+def config_stages(config: dict) -> list:
+    """The adapter's stages in the form with ``stages``: the one-stage
+    form becomes one stage of depth ``[layers_key]`` with no lead."""
+    ad = config["adapter"]
+    if "stages" in ad:
+        return ad["stages"]
+    return [{"depth": [ad["layers_key"]], "targets": ad["targets"]}]
+
+
+def stage_depth(depth_keys, values) -> int:
+    """The first key's value less the others' (``values``: key -> int)."""
+    first, *minus = depth_keys
+    return int(values[first]) - sum(int(values[k]) for k in minus)
+
+
 def program_layout(config: dict) -> Layout:
     """Build the client adapter layout through the program's public
-    ``Model.init_adapters`` (shapes only) and assert every pair against
-    the configuration file: ``A (L, r_max, fan_in)``, ``B (L, fan_out,
-    r_max)``, ``rank (L,)``, one pair per listed target and no other."""
+    ``Model.init_adapters`` (shapes only) over every stage the
+    configuration keeps, each at its stated depth, and assert every pair
+    against the configuration file: ``A (*lead, r_max, fan_in)``, ``B
+    (*lead, fan_out, r_max)``, ``rank (lead[0],)``, one pair per listed
+    target of each stage and no other."""
     from repro.configs import get_config
     from repro.configs.base import Stage
     from repro.models.model import make_model
 
     ad = config["adapter"]
-    layers = int(config[ad["layers_key"]])
     r_max = int(ad["r_max"])
-    widths = {t: (int(fo), int(fi)) for t, (fo, fi) in ad["targets"].items()}
+    stages = config_stages(config)
     arch = get_config(ad["arch"])
+    if len(stages) != len(arch.stages):
+        raise ValueError(f"the configuration states {len(stages)} stages, "
+                         f"{ad['arch']} has {len(arch.stages)}")
+    depths = [stage_depth(st["depth"], config) for st in stages]
+    periods = [len(s.unit) for s in arch.stages]
+    if any(d < p or d % p for d, p in zip(depths, periods)):
+        raise ValueError(f"stage depths {depths} are not whole periods of "
+                         f"{periods} layers")
+    repeats = [d // p for d, p in zip(depths, periods)]
     arch = dataclasses.replace(
-        arch, stages=(Stage(unit=arch.stages[0].unit, repeat=layers),),
+        arch, stages=tuple(Stage(unit=s.unit, repeat=n)
+                           for s, n in zip(arch.stages, repeats)),
         lora_r_max=r_max, **config.get("program_overrides", {}))
     template = jax.eval_shape(
         lambda k: make_model(arch).init_adapters(k, r_max=r_max),
         jax.random.PRNGKey(0))
-    seen = []
+    pairs, seen = {}, [set() for _ in stages]
     for path, pair in pair_list(template):
+        i = path[1] if path[0] == "stages" else None
         target = path[-1]
-        if target not in widths:
+        if i is None or target not in stages[i]["targets"]:
             raise ValueError(f"program adapts {path}, which the "
                              "configuration file does not list")
-        fo, fi = widths[target]
-        want = {"A": (layers, r_max, fi), "B": (layers, fo, r_max),
-                "rank": (layers,)}
+        fo, fi = (int(w) for w in stages[i]["targets"][target])
+        axes = stages[i].get("lead", {}).get(target, [])
+        missing = [k for k in axes if k not in config]
+        if missing:
+            raise ValueError(f"pair {path}: lead keys {missing} are not "
+                             "in the configuration file")
+        lead = (repeats[i], *(int(config[k]) for k in axes))
+        want = {"A": (*lead, r_max, fi), "B": (*lead, fo, r_max),
+                "rank": (repeats[i],)}
         got = {k: tuple(pair[k].shape) for k in want}
         if got != want:
             raise ValueError(f"pair {path}: program shapes {got} differ "
                              f"from the configuration's {want}")
-        seen.append(target)
-    if sorted(seen) != sorted(widths):
-        raise ValueError(f"program adapts {sorted(seen)}, configuration "
-                         f"lists {sorted(widths)}")
-    return Layout(template=template, layers=layers, r_max=r_max,
-                  widths=widths)
+        pairs[path] = (fo, fi, lead)
+        seen[i].add(target)
+    for i, st in enumerate(stages):
+        if seen[i] != set(st["targets"]):
+            raise ValueError(f"stage {i}: program adapts {sorted(seen[i])}, "
+                             f"configuration lists {sorted(st['targets'])}")
+    return Layout(template=template, r_max=r_max, pairs=pairs)
 
 
 # ------------------------------------------------------------- uploads ----
@@ -134,23 +198,27 @@ def pool_ranks(traffic: dict, r_max: int, size: int) -> list:
 
 def _client(layout: Layout, codec: str, key, rank):
     """One upload: live rows (A) / columns (B) below ``rank`` random,
-    padding zero, as a trained and masked LoRA pair would be."""
-    L, r = layout.layers, layout.r_max
+    padding zero, as a trained and masked LoRA pair would be.  Each
+    pair's stream is keyed by its index in tree order."""
+    r = layout.r_max
     live = jnp.arange(r) < rank
-    index = {p: i for i, (p, _) in enumerate(pair_list(layout.template))}
+    index = {p: i for i, p in enumerate(layout.pairs)}
 
     def make(path, pair):
         k = jax.random.fold_in(key, index[path])
         ka, kb, ksa, ksb = jax.random.split(k, 4)
-        fo, fi = layout.widths[path[-1]]
-        out = {"rank": jnp.full((L,), rank, jnp.int32)}
+        fo, fi, lead = layout.pairs[path]
+        out = {"rank": jnp.full(lead[:1], rank, jnp.int32)}
         for side, kx, ks, shape, mask in (
-                ("A", ka, ksa, (L, r, fi), live[:, None]),
-                ("B", kb, ksb, (L, fo, r), live[None, :])):
+                ("A", ka, ksa, (*lead, r, fi), live[:, None]),
+                ("B", kb, ksb, (*lead, fo, r), live[None, :])):
             if codec == "int8":
                 q = jax.random.randint(kx, shape, -127, 128, jnp.int8)
                 out[side] = jnp.where(mask, q, 0).astype(jnp.int8)
-                s = jax.random.uniform(ks, (L, r), jnp.float32, 1e-4, 1e-3)
+                # one scale per row of every layer and expert, the
+                # codec's (*lead, r) plane
+                s = jax.random.uniform(ks, (*lead, r), jnp.float32, 1e-4,
+                                       1e-3)
                 # the codec's scale of an all-zero row is 1
                 out[side + "_scale"] = jnp.where(live, s, 1.0)
             elif codec == "none":

@@ -7,7 +7,9 @@ names the cell's configuration file and traffic mix; the traffic's
 its ``strategy`` the plain reference the answers are held to
 (``references/<strategy>.py``), and each metric's value comes from
 ``metrics/<name>.py``.  A new cell, loop, reference or metric is a new
-file.
+file.  Each mode declares the loop family it belongs to (``FAMILY``,
+one of ``FAMILIES``), and readers test the family, never the mode's
+name, so a new mode of a family reports that family's metrics.
 """
 from __future__ import annotations
 
@@ -87,16 +89,26 @@ def load_reader(name: str):
     return load_module("metrics", name).read
 
 
+#: loop families: ``sync`` runs rounds over a cohort, ``async`` folds one
+#: upload at a time
+FAMILIES = ("sync", "async")
+
+
 def load_traffic(traffic: dict):
-    """The traffic's loop class and reference module.  A traffic file
-    with a key its mode does not read is refused: a knob the loop
-    ignores would claim a choice the benchmark does not honour."""
+    """The traffic's loop class, its family and its reference module.  A
+    traffic file with a key its mode does not read is refused: a knob the
+    loop ignores would claim a choice the benchmark does not honour.  So
+    is a mode that declares no family: no reader would know it."""
     mode = load_module("modes", traffic["mode"])
     extra = set(traffic) - mode.KEYS
     if extra:
         raise ValueError(f"mode {traffic['mode']!r} reads no traffic key "
                          f"{sorted(extra)}")
-    return mode.Loop, load_module("references", traffic["strategy"])
+    family = getattr(mode, "FAMILY", None)
+    if family not in FAMILIES:
+        raise ValueError(f"mode {traffic['mode']!r} declares no family: "
+                         f"FAMILY is {family!r}, not one of {FAMILIES}")
+    return mode.Loop, family, load_module("references", traffic["strategy"])
 
 
 def nearest_rank(values, q: float) -> float:
@@ -112,6 +124,7 @@ class Run:
     """What a metric reader sees of one run."""
     cell: Cell
     seed: int
+    family: str = ""            # the loop's family, one of FAMILIES
     setup_s: float = 0.0
     window_s: float = 0.0
     steps: int = 0              # rounds or folds completed in the window
@@ -307,7 +320,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         raise NoChip(f"no peaks for device kind {kind!r} in peaks.json")
 
     t = cell.traffic
-    loop_class, reference = load_traffic(t)
+    loop_class, family, reference = load_traffic(t)
     loop = loop_class(cell, seed, reference)
     loop.warm()
     keep = Reservoir(seed, int(t["sampled"]), loop.last()[1])
@@ -328,9 +341,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         after = _counters()
         mem = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                   for d in devs[:cell.chips])
-        run_ = Run(cell=cell, seed=seed, setup_s=setup_s, window_s=window_s,
-                   steps=len(lat), units=units, latency_ms=lat,
-                   host_ms=host, work=loop.window_work(len(lat)),
+        run_ = Run(cell=cell, seed=seed, family=family, setup_s=setup_s,
+                   window_s=window_s, steps=len(lat), units=units,
+                   latency_ms=lat, host_ms=host,
+                   work=loop.window_work(len(lat)),
                    counters={k: after[k] - before[k] for k in after},
                    peaks=peaks.get(kind))
         if trace:

@@ -8,7 +8,7 @@ import work
 
 def read(run):
     tr = run.trace
-    if run.cell.traffic["mode"] != "async" or not tr or not run.peaks:
+    if run.family != "async" or not tr or not run.peaks:
         return None
     sec, n = tracing.op_seconds(tr, r"fold_fn", tracing.PALLAS_KERNEL)
     if not n or sec <= 0:

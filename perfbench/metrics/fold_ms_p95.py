@@ -5,6 +5,6 @@ from harness import nearest_rank
 
 
 def read(run):
-    if run.cell.traffic["mode"] != "async" or not run.latency_ms:
+    if run.family != "async" or not run.latency_ms:
         return None
     return nearest_rank(run.latency_ms, 0.95)

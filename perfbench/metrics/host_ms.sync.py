@@ -3,6 +3,6 @@
 
 
 def read(run):
-    if run.cell.traffic["mode"] != "sync" or not run.host_ms:
+    if run.family != "sync" or not run.host_ms:
         return None
     return sum(run.host_ms) / len(run.host_ms)

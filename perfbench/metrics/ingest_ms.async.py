@@ -5,6 +5,6 @@ before the fold), its sum over its count across the window."""
 
 def read(run):
     c = run.counters
-    if run.cell.traffic["mode"] != "async" or not c.get("submit_count"):
+    if run.family != "async" or not c.get("submit_count"):
         return None
     return 1e3 * c["submit_sum_s"] / c["submit_count"]

@@ -9,6 +9,6 @@ STAGING = r"^jit_(broadcast_in_dim|concatenate|stack|pack_fn)$"
 
 def read(run):
     tr = run.trace
-    if run.cell.traffic["mode"] != "sync" or not tr or not run.steps:
+    if run.family != "sync" or not tr or not run.steps:
         return None
     return 1e3 * tracing.module_seconds(tr, STAGING) / run.steps

@@ -14,6 +14,9 @@ import gen
 import work
 from tracing import phase
 
+#: the loop family, which decides the metrics its cells report
+FAMILY = "async"
+
 #: the traffic keys this mode reads; a traffic file with others is refused
 KEYS = frozenset({"mode", "strategy", "codec", "ring", "rank_mix",
                   "weights", "staleness_a", "staleness_max", "warmup_folds",
@@ -81,8 +84,7 @@ class Loop:
         lay, codec = self.layout, self.cell.traffic["codec"]
         tot = {"bytes": 0, "flops": 0}
         for rank in self.folded_ranks[-steps:] if steps else []:
-            w = work.fold_work(lay.widths, lay.layers, lay.r_max, rank,
-                               codec)
+            w = work.fold_work(lay.pairs.values(), lay.r_max, rank, codec)
             tot["bytes"] += w["bytes"]
             tot["flops"] += w["flops"]
         return tot

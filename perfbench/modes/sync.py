@@ -17,6 +17,9 @@ import gen
 import work
 from tracing import phase
 
+#: the loop family, which decides the metrics its cells report
+FAMILY = "sync"
+
 #: the traffic keys this mode reads; a traffic file with others is refused
 KEYS = frozenset({"mode", "strategy", "codec", "cohort", "rank_mix",
                   "weights", "warmup_rounds", "sampled", "limits"})
@@ -79,7 +82,7 @@ class Loop:
 
     def window_work(self, steps: int) -> dict:
         lay = self.layout
-        w = work.round_work(lay.widths, lay.layers, lay.r_max,
+        w = work.round_work(lay.pairs.values(), lay.r_max,
                             self.ranks.tolist(), self.cell.traffic["codec"])
         return {k: v * steps for k, v in w.items()}
 

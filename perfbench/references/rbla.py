@@ -24,9 +24,12 @@ import jax
 import jax.numpy as jnp
 
 
-def _rows(v, side):
-    """(L, r) per-row values -> broadcast on the side's row axis."""
-    return v[:, :, None] if side == "A" else v[:, None, :]
+def _rows(v, side, ndim):
+    """Per-row values, ``(L, r)`` or ``(L, *mid, r)``, broadcast against
+    a side of ``ndim`` axes: over every axis between the layer axis and
+    the row axis (``mid``, the experts held) and on the side's row axis."""
+    v = v.reshape(v.shape[:1] + (1,) * (ndim - 1 - v.ndim) + v.shape[1:])
+    return v[..., :, None] if side == "A" else v[..., None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("side", "dtype"))
@@ -34,22 +37,24 @@ def _add_client(num, den, x, scale, w, rank, *, side: str, dtype):
     """Add one client's weighted owned rows to the running sums."""
     x = x.astype(dtype)
     if scale is not None:
-        x = x * _rows(scale.astype(dtype), side)
+        x = x * _rows(scale.astype(dtype), side, x.ndim)
     own = (jnp.arange(den.shape[-1]) < rank).astype(dtype)
     wm = jnp.broadcast_to(w.astype(dtype) * own, den.shape)
-    return num + _rows(wm, side) * x, den + wm
+    return num + _rows(wm, side, x.ndim) * x, den + wm
 
 
 @functools.partial(jax.jit, static_argnames=("side", "dtype"))
 def _finish(num, den, prev, *, side: str, dtype):
-    d = _rows(den, side)
+    d = _rows(den, side, prev.ndim)
     return jnp.where(d > 0, num / jnp.where(d > 0, d, 1), prev.astype(dtype))
 
 
 def rbla_side(xs, scales, ws, ranks, prev, *, side: str, dtype):
-    """xs: per-client ``(L, r, fan_in)`` (side A) or ``(L, fan_out, r)``
-    (side B) arrays; scales: per-client ``(L, r)`` or None; ws, ranks:
-    per-client scalars; prev: the previous global's side."""
+    """xs: per-client ``(L, *mid, r, fan_in)`` (side A) or ``(L, *mid,
+    fan_out, r)`` (side B) arrays, ``mid`` the pair's own axes (the
+    experts held; none for a dense pair); scales: per-client ``(L, *mid,
+    r)`` or None; ws, ranks: per-client scalars, one rank for every layer
+    and expert; prev: the previous global's side."""
     r = xs[0].shape[-2] if side == "A" else xs[0].shape[-1]
     num = jnp.zeros(xs[0].shape, dtype)
     den = jnp.zeros((xs[0].shape[0], r), dtype)

@@ -2,8 +2,10 @@
 look for a chip: sound runs come out correct, the bfloat16 control reads
 above every limit, and a timed path broken underneath (a state left
 unchanged, half the cohort left out, an altered answer, the previous
-global lost) comes out not correct.  The traffic files and their limits are the committed ones;
-only the widths, the depth and the pool are cut."""
+global lost) comes out not correct.  The traffic files and their limits
+are the committed ones; only the widths, the depth and the pool are cut.
+The ``moe-`` cases run a two-stage MLA + MoE tree, whose expert pairs
+carry an expert axis unlike the depth, through the same loops."""
 import json
 import sys
 import time
@@ -14,35 +16,35 @@ import pytest
 HERE = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(HERE.parent / "src"))
+sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import harness  # noqa: E402
+from tiny_configs import TINY, TINY_MOE  # noqa: E402
 
-D, F = 128, 256
-TINY = {
-    "hidden_size": D, "num_hidden_layers": 1,
-    "adapter": {"arch": "h2o-danube-3-4b", "layers_key": "num_hidden_layers",
-                "r_max": 8,
-                "targets": {"mix/q": [D, D], "mix/k": [64, D],
-                            "mix/v": [64, D], "mix/o": [D, D],
-                            "ffn/gate": [F, D], "ffn/up": [F, D],
-                            "ffn/down": [D, F]}},
-    "program_overrides": {"d_model": D, "n_heads": 2, "n_kv_heads": 1,
-                          "head_dim": 64, "d_ff": F},
-}
-TRAFFIC = {"sync": ("sync-rbla-f32.n32", {"cohort": 8}),
-           "async": ("async-rbla.ring64", {"ring": 8}),
+DENSE_PROBE = (("stages", 0, "b0", "mix/q"), (0, 0, 0))
+# the last expert of the last MoE layer
+MOE_PROBE = (("stages", 1, "b0", "ffn/experts/gate"), (3, 7, 0, 0))
+#: case -> (config, traffic file, its cut, the pair and entry a fault alters)
+TRAFFIC = {"sync": (TINY, "sync-rbla-f32.n32", {"cohort": 8}, DENSE_PROBE),
+           "async": (TINY, "async-rbla.ring64", {"ring": 8}, DENSE_PROBE),
            # no client at r_max: the top rows keep the chained global
-           "sync-retain": ("sync-rbla-f32.n24-retain", {"cohort": 8})}
+           "sync-retain": (TINY, "sync-rbla-f32.n24-retain", {"cohort": 8},
+                           DENSE_PROBE),
+           "moe-sync": (TINY_MOE, "sync-rbla-f32.n32", {"cohort": 8},
+                        MOE_PROBE),
+           "moe-async": (TINY_MOE, "async-rbla.ring64", {"ring": 8},
+                         MOE_PROBE)}
 MODES = list(TRAFFIC)
 
 
 def tiny_cell(mode):
-    name, cut = TRAFFIC[mode]
+    config, name, cut, _ = TRAFFIC[mode]
     traffic = json.loads((HERE / "traffic" / f"{name}.json").read_text())
     traffic.update(cut)
+    family = harness.load_traffic(traffic)[1]
     e2e = ["uploads_per_s", "setup_s",
-           "round_ms_p95" if traffic["mode"] == "sync" else "fold_ms_p95"]
-    return harness.Cell(name=f"tiny-{mode}", chips=1, config=TINY,
+           "round_ms_p95" if family == "sync" else "fold_ms_p95"]
+    return harness.Cell(name=f"tiny-{mode}", chips=1, config=config,
                         traffic=traffic,
                         end_to_end=[{"name": n, "unit": "u"} for n in e2e],
                         per_layer=[])
@@ -71,7 +73,16 @@ def test_sound_run_is_correct_and_the_control_is_not(mode):
     assert list(result)[-1] == "checks"
 
 
-def _sync_fault(kind):
+def _alter(adapters, probe, side):
+    """Add 1 to one entry of one pair side, where it is produced."""
+    path, index = probe
+    pair = adapters
+    for k in path:
+        pair = pair[k]
+    pair[side] = pair[side].at[index].add(1.0)
+
+
+def _sync_fault(kind, probe=DENSE_PROBE):
     import jax
     import jax.numpy as jnp
     from repro.core.strategy import AggregationStrategy
@@ -90,13 +101,12 @@ def _sync_fault(kind):
             kw["client_ranks"] = kw["client_ranks"][:h]
             return real(self, uploads[:h], weights[:h], **kw)
         out = real(self, uploads, weights, **kw)
-        pair = out["stages"][0]["b0"]["mix/q"]
-        pair["A"] = pair["A"].at[0, 0, 0].add(1.0)
+        _alter(out, probe, "A")
         return out
     return AggregationStrategy, "aggregate_adapters", broken
 
 
-def _async_fault(kind):
+def _async_fault(kind, probe):
     from repro.fl import AsyncAggregator
     real = AsyncAggregator.submit
     calls = []
@@ -107,8 +117,7 @@ def _async_fault(kind):
                                          and len(calls) % 2):
             return True
         advanced = real(self, update, **kw)
-        pair = self.state.adapters["stages"][0]["b0"]["mix/q"]
-        pair["B"] = pair["B"].at[0, 0, 0].add(1.0)
+        _alter(self.state.adapters, probe, "B")
         return advanced
     return AsyncAggregator, "submit", broken
 
@@ -117,8 +126,9 @@ def _async_fault(kind):
                                   "answer_altered"])
 @pytest.mark.parametrize("mode", MODES)
 def test_broken_timed_path_is_not_correct(mode, kind, monkeypatch):
-    cls, attr, broken = (_async_fault if mode == "async"
-                         else _sync_fault)(kind)
+    family = harness.load_traffic(tiny_cell(mode).traffic)[1]
+    cls, attr, broken = (_async_fault if family == "async"
+                         else _sync_fault)(kind, TRAFFIC[mode][3])
     monkeypatch.setattr(cls, attr, broken)
     result, lines, _ = run(mode)
     assert not result["correct"], lines

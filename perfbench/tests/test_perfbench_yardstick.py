@@ -9,7 +9,9 @@ import pytest
 
 HERE = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(HERE))
+sys.path.insert(0, str(HERE.parent / "src"))
 
+import gen  # noqa: E402
 import harness  # noqa: E402
 import tracing  # noqa: E402
 import work  # noqa: E402
@@ -133,6 +135,11 @@ def test_a_trace_without_one_window_is_refused():
 WIDTHS = {"q": (96, 64), "down": (64, 160)}       # fan_out, fan_in
 
 
+def pairs(layers):
+    """``(fan_out, fan_in, lead)`` of WIDTHS' pairs at ``layers`` deep."""
+    return [(fo, fi, (layers,)) for fo, fi in WIDTHS.values()]
+
+
 def by_hand(ranks, layers, wire, scales):
     """Live rows x row width per side, counted pair by pair."""
     nbytes = 0
@@ -149,7 +156,7 @@ def by_hand(ranks, layers, wire, scales):
 def test_round_bytes_count_live_rows_of_a_mixed_rank_cohort(codec, wire,
                                                             scales):
     ranks = [2, 2, 4, 8]
-    got = work.round_work(WIDTHS, 3, 8, ranks, codec)
+    got = work.round_work(pairs(3), 8, ranks, codec)
     assert got["bytes"] == by_hand(ranks, 3, wire, scales)
 
 
@@ -157,16 +164,16 @@ def test_round_bytes_count_live_rows_of_a_mixed_rank_cohort(codec, wire,
 def test_padding_rows_add_no_bytes(kind):
     """The same live ranks stored at a larger r_max need the same work."""
     if kind == "round":
-        small = work.round_work(WIDTHS, 2, 8, [2, 4], "none")
-        large = work.round_work(WIDTHS, 2, 64, [2, 4], "none")
+        small = work.round_work(pairs(2), 8, [2, 4], "none")
+        large = work.round_work(pairs(2), 64, [2, 4], "none")
     else:
-        small = work.fold_work(WIDTHS, 2, 8, 4, "none")
-        large = work.fold_work(WIDTHS, 2, 64, 4, "none")
+        small = work.fold_work(pairs(2), 8, 4, "none")
+        large = work.fold_work(pairs(2), 64, 4, "none")
     assert small == large
 
 
 def test_fold_bytes_read_the_upload_and_read_and_write_the_state():
-    got = work.fold_work({"q": (96, 64)}, 2, 8, 4, "none")
+    got = work.fold_work([(96, 64, (2,))], 8, 4, "none")
     rows = 2 * 4
     assert got["bytes"] == rows * (64 + 96) * 12 + 2 * rows * 8
     assert got["flops"] == rows * (64 + 96) * 3
@@ -219,6 +226,53 @@ def test_a_mode_or_strategy_without_its_file_is_refused(key, value):
         harness.load_traffic(t)
 
 
+@pytest.mark.parametrize("family", [None, "open_loop"])
+def test_a_mode_without_a_family_is_refused(family, monkeypatch):
+    real = harness.load_module
+
+    def loaded(kind, name):
+        mod = real(kind, name)
+        if kind == "modes":
+            del mod.FAMILY
+            if family is not None:
+                mod.FAMILY = family
+        return mod
+    monkeypatch.setattr(harness, "load_module", loaded)
+    with pytest.raises(ValueError, match="declares no family"):
+        harness.load_traffic(traffic())
+
+
+def family_run(family):
+    """A run of a mode no reader names, of ``family``, with something for
+    every family-keyed reader to read."""
+    ev = [ann("window", 0, 1000), prog("jit_pack_fn", 0, 100),
+          op("%fusion.1 = f32[8] fusion()", 0, 100),
+          prog("jit_combine_fn", 200, 100), op(KERNEL, 200, 100),
+          prog("jit_fold_fn", 400, 100), op(KERNEL, 400, 100)]
+    cell = harness.Cell(name="c", chips=1, config={},
+                        traffic={"mode": f"{family}_new"},
+                        end_to_end=[], per_layer=[])
+    return harness.Run(cell=cell, seed=0, family=family, window_s=1.0,
+                       steps=2, units=2, latency_ms=[3.0, 5.0],
+                       host_ms=[1.0, 2.0], work={"bytes": 100, "flops": 1},
+                       counters={"submit_sum_s": 0.5, "submit_count": 2},
+                       trace=tracing.reduce(ev),
+                       peaks={"bf16_flops_per_s": 1e12,
+                              "hbm_bytes_per_s": 1e9})
+
+
+@pytest.mark.parametrize("name,family", [
+    ("round_ms_p95", "sync"), ("host_ms.sync", "sync"),
+    ("staging_ms.sync", "sync"), ("packed_agg_roofline", "sync"),
+    ("fold_ms_p95", "async"), ("axpy_fold_roofline", "async"),
+    ("ingest_ms.async", "async")])
+def test_readers_key_to_the_loop_family_not_the_mode_name(name, family):
+    read = harness.load_reader(name)
+    other = {"sync": "async", "async": "sync"}[family]
+    assert read(family_run(family)) is not None
+    assert read(family_run(other)) is None
+
+
 def test_every_metric_has_a_reader_and_every_cell_enough_metrics():
     b = bench()
     for m in b["end_to_end"] + b["per_layer"]:
@@ -234,13 +288,142 @@ def test_every_metric_has_a_reader_and_every_cell_enough_metrics():
                 assert m["moves"] in e2e
 
 
-def test_configs_state_published_widths_and_only_cut_depth():
-    for c in bench()["configs"]:
-        cfg = json.loads((HERE.parent / c["file"]).read_text())
-        assert c["reduced"] == [cfg["adapter"]["layers_key"]]
-        assert set(cfg["reduced"]) == set(c["reduced"])
-        published = cfg["reduced"][c["reduced"][0]]["published"]
-        assert published % cfg[cfg["adapter"]["layers_key"]] == 0
-        for fo, fi in cfg["adapter"]["targets"].values():
+def check_config(entry: dict, cfg: dict):
+    """A configuration states published widths and makes only the cuts
+    of model-configs section 4, each with ``published``, ``here`` and
+    ``why``: the depth of a stage, the routed experts held (a key that
+    names an expert axis under a stage's ``lead``), a vocabulary slice.
+    What is left keeps the floors: whole periods of each stage's layer
+    pattern, at least 4 layers after the leading dense ones (the stages
+    before the first with an expert axis), at least 8 experts held, at
+    least an eighth of the vocabulary; a model of one stage holds an
+    even share of its published depth.  ``program_overrides`` may set the
+    program's architecture only to such a cut (``n_experts`` to the
+    experts held), never to a width: the pairs are held to the stated
+    widths through the program, and an override would let both shrink."""
+    reduced = cfg["reduced"]
+    assert set(entry["reduced"]) == set(reduced)
+    stages = gen.config_stages(cfg)
+    depth_keys = {k for st in stages for k in st["depth"]}
+    expert_keys = {k for st in stages
+                   for axes in st.get("lead", {}).values() for k in axes}
+    vocab_keys = {k for k in cfg if "vocab" in k}
+    for key, cut in reduced.items():
+        assert key in depth_keys | expert_keys | vocab_keys, (
+            f"{key} is no cut of depth, experts held or vocabulary")
+        assert {"published", "here", "why"} <= set(cut), key
+        assert isinstance(cut["why"], str) and cut["why"], key
+        assert isinstance(cut["published"], int), key
+        assert cfg[key] == cut["here"], key
+        assert 0 < cut["here"] < cut["published"], key
+    published = {k: reduced[k]["published"] if k in reduced else cfg[k]
+                 for k in depth_keys | expert_keys}
+    depths = [gen.stage_depth(st["depth"], cfg) for st in stages]
+    full = [gen.stage_depth(st["depth"], published) for st in stages]
+    gen.program_layout(cfg)               # whole periods, every pair
+    if len(stages) == 1:
+        assert full[0] % depths[0] == 0
+    lead = next((i for i, st in enumerate(stages) if st.get("lead")), 0)
+    assert sum(depths[lead:]) >= min(4, sum(full[lead:]))
+    for k in expert_keys:
+        assert cfg[k] >= min(8, published[k]), k
+    for k in vocab_keys & set(reduced):
+        assert 8 * cfg[k] >= reduced[k]["published"], k
+    for key, value in cfg.get("program_overrides", {}).items():
+        held = {cfg[k] for k in expert_keys & set(reduced)}
+        assert key == "n_experts" and held == {value}, (
+            f"program_overrides {key}={value} is no cut of the experts held")
+    for st in stages:
+        for fo, fi in st["targets"].values():
             assert isinstance(fo, int) and isinstance(fi, int)
             assert fo > 0 and fi > 0
+
+
+def test_configs_state_published_widths_and_only_cut_depth():
+    """A file in the one-stage form (``layers_key``) is a dense model whose
+    one cut is its depth; every other cut needs the ``stages`` form."""
+    for c in bench()["configs"]:
+        cfg = json.loads((HERE.parent / c["file"]).read_text())
+        if "stages" not in cfg["adapter"]:
+            assert c["reduced"] == [cfg["adapter"]["layers_key"]]
+        check_config(c, cfg)
+
+
+def sizing():
+    """DeepSeek-V3's one-chip share: depth, experts held, both cut."""
+    cfg = json.loads((HERE / "tests" / "deepseek-v3.l5e8.json")
+                     .read_text())
+    return {"reduced": list(cfg["reduced"])}, cfg
+
+
+def test_a_stage_and_expert_cut_within_the_floors_is_taken():
+    check_config(*sizing())
+
+
+def test_a_stages_form_configuration_in_benchmark_json_is_taken(monkeypatch):
+    """The conformance test itself takes a BENCHMARK.json that lists the
+    DeepSeek-V3 share beside the committed configurations."""
+    b = bench()
+    entry, cfg = sizing()
+    b["configs"].append({"name": cfg["name"], "source": cfg["source"],
+                         "file": "perfbench/tests/deepseek-v3.l5e8.json",
+                         "reduced": entry["reduced"],
+                         "why": "one chip's share of the experts and layers"})
+    monkeypatch.setitem(globals(), "bench", lambda: b)
+    test_configs_state_published_widths_and_only_cut_depth()
+
+
+def _cut(key, here, published=None, why="a cut"):
+    def edit(entry, cfg):
+        cfg[key] = here
+        cfg["reduced"][key] = {"published": published or cfg[key],
+                               "here": here, "why": why}
+        entry["reduced"].append(key)
+    return edit
+
+
+def _experts(n):
+    def edit(entry, cfg):
+        cfg["n_routed_experts"] = cfg["reduced"]["n_routed_experts"][
+            "here"] = cfg["program_overrides"]["n_experts"] = n
+    return edit
+
+
+def _moe_layers(n):
+    def edit(entry, cfg):
+        cfg["num_hidden_layers"] = cfg["reduced"]["num_hidden_layers"][
+            "here"] = 1 + n
+    return edit
+
+
+def _no_why(entry, cfg):
+    del cfg["reduced"]["first_k_dense_replace"]["why"]
+
+
+def _override_width(entry, cfg):
+    """The routed and shared expert width cut through the program, the
+    targets shrunk to match, the file's ``moe_intermediate_size`` left at
+    its published value: the layout agrees, the override is refused."""
+    cfg["program_overrides"]["moe_d_ff"] = 1024
+    for st in cfg["adapter"]["stages"]:
+        for t, (fo, fi) in st["targets"].items():
+            if "experts" in t or "shared" in t:
+                st["targets"][t] = [1024 if fo == 2048 else fo,
+                                    1024 if fi == 2048 else fi]
+    gen.program_layout(cfg)
+
+
+@pytest.mark.parametrize("what,edit", [
+    ("fewer than 8 experts held", _experts(4)),
+    ("fewer than 4 layers after the dense one", _moe_layers(3)),
+    ("less than an eighth of the vocabulary",
+     _cut("vocab_size", 129280 // 16, published=129280)),
+    ("a width cut", _cut("moe_intermediate_size", 1024, published=2048)),
+    ("a cut without its reason", _no_why),
+    ("a width cut through program_overrides", _override_width),
+])
+def test_a_cut_past_what_model_configs_allows_is_refused(what, edit):
+    entry, cfg = sizing()
+    edit(entry, cfg)
+    with pytest.raises(AssertionError):
+        check_config(entry, cfg)

@@ -2,7 +2,8 @@
 ranks alone -- the numerator of every roofline and ``mfu`` share.
 
 Only live rows count: a client at rank ``k`` sends ``k`` rows of each A
-and ``k`` columns of each B per layer, whatever its storage rank.
+and ``k`` columns of each B per layer and per expert held (per index of
+the pair's leading axes), whatever its storage rank.
 Padding rows never count, so a share reads the same work whatever layout
 implements it, and no layout can push one past 100%.
 
@@ -20,42 +21,46 @@ subtract, a multiply and an add per live element.
 """
 from __future__ import annotations
 
+import math
+
 WIRE_BYTES = {"none": 4, "int8": 1}
 F32 = 4
 
 
-def _sides(widths: dict):
-    """Row widths of every pair side: A rows span fan_in, B's packed rows
-    (its columns) span fan_out."""
-    for fo, fi in widths.values():
-        yield fi
-        yield fo
+def _sides(pairs):
+    """``(row width, rows per rank row)`` of every pair side: A rows span
+    fan_in, B's packed rows (its columns) span fan_out, and a rank row
+    repeats over the pair's leading axes (layers, experts held).
+    ``pairs``: ``(fan_out, fan_in, lead)`` per pair."""
+    for fo, fi, lead in pairs:
+        n = math.prod(lead)
+        yield fi, n
+        yield fo, n
 
 
-def round_work(widths: dict, layers: int, r_max: int, ranks,
-               codec: str) -> dict:
+def round_work(pairs, r_max: int, ranks, codec: str) -> dict:
     """Necessary ``bytes`` and ``flops`` of one sync RBLA round."""
     wire = WIRE_BYTES[codec]
     owned = min(max(ranks), r_max)
+    live_ranks = sum(min(k, r_max) for k in ranks)
     nbytes = flops = 0
-    for width in _sides(widths):
-        live = sum(min(k, r_max) for k in ranks) * layers
+    for width, n in _sides(pairs):
+        live = live_ranks * n
         nbytes += live * width * wire
         flops += live * width * (2 + (codec == "int8"))
         if codec == "int8":
             nbytes += live * F32
-        nbytes += owned * layers * width * F32          # output written
-        flops += owned * layers * width
+        nbytes += owned * n * width * F32               # output written
+        flops += owned * n * width
     return {"bytes": nbytes, "flops": flops}
 
 
-def fold_work(widths: dict, layers: int, r_max: int, rank: int,
-              codec: str) -> dict:
+def fold_work(pairs, r_max: int, rank: int, codec: str) -> dict:
     """Necessary ``bytes`` and ``flops`` of folding one upload."""
     wire = WIRE_BYTES[codec]
-    live_rows = min(rank, r_max) * layers
     nbytes = flops = 0
-    for width in _sides(widths):
+    for width, n in _sides(pairs):
+        live_rows = min(rank, r_max) * n
         nbytes += live_rows * width * (wire + 2 * F32)
         nbytes += live_rows * 2 * F32                   # row mass
         if codec == "int8":
