@@ -1,7 +1,8 @@
 """Config registry: 10 assigned architectures + the paper's own models."""
 from __future__ import annotations
 
-from .base import ArchConfig, BlockSpec, InputShape, Stage, INPUT_SHAPES
+from .base import (ArchConfig, BlockSpec, InputShape, Stage, YaRN,
+                   INPUT_SHAPES)
 
 from .h2o_danube_3_4b import CONFIG as h2o_danube_3_4b
 from .deepseek_v3_671b import CONFIG as deepseek_v3_671b
@@ -30,5 +31,5 @@ def get_config(name: str) -> ArchConfig:
         raise KeyError(f"unknown arch {name!r}; known: {sorted(ARCHS)}")
 
 
-__all__ = ["ArchConfig", "BlockSpec", "InputShape", "Stage", "INPUT_SHAPES",
-           "ARCHS", "get_config"]
+__all__ = ["ArchConfig", "BlockSpec", "InputShape", "Stage", "YaRN",
+           "INPUT_SHAPES", "ARCHS", "get_config"]

@@ -39,6 +39,18 @@ class Stage:
 
 
 @dataclass(frozen=True)
+class YaRN:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V3 publishes it
+    (``rope_scaling`` of its config.json)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class ArchConfig:
     name: str
     arch_type: str                        # dense|moe|ssm|audio|hybrid|vlm
@@ -56,6 +68,7 @@ class ArchConfig:
     # attention details
     rope_kind: str = "full"               # full | half | none
     rope_theta: float = 10_000.0
+    rope_scaling: YaRN | None = None      # None: plain rope
     attn_softcap: float = 0.0
     final_softcap: float = 0.0
     query_scale: float | None = None      # None -> 1/sqrt(head_dim)
@@ -66,12 +79,19 @@ class ArchConfig:
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
-    # MoE
+    # MoE.  ``n_experts`` counts the routed experts this layer holds:
+    # ids ``[expert_offset, expert_offset + n_experts)`` of the router's
+    # ``n_routed_experts`` (0: the router's width is ``n_experts``).
     n_experts: int = 0
+    n_routed_experts: int = 0
+    expert_offset: int = 0
     n_shared_experts: int = 0
     experts_per_token: int = 0
     moe_d_ff: int = 0
-    router_scale: float = 1.0
+    router_scoring: str = "softmax"       # softmax | sigmoid (deepseek-v3)
+    router_groups: int = 1                # group-limited choice: the top
+    router_topk_groups: int = 1           #   groups by their top-2 sum
+    router_scale: float = 1.0             # routed_scaling_factor
     capacity_factor: float = 1.25
     moe_mode: str = "sort"                # sort | ep_a2a (perf variant)
     moe_pad_experts: int = 0              # physical padding for EP
@@ -105,6 +125,35 @@ class ArchConfig:
     def n_layers(self) -> int:
         return sum(s.n_layers for s in self.stages)
 
+    def __post_init__(self):
+        if self.n_experts:
+            if not (0 <= self.expert_offset
+                    and self.expert_offset + self.n_experts
+                    <= self.n_routed):
+                raise ValueError(
+                    f"{self.name}: experts [{self.expert_offset}, "
+                    f"{self.expert_offset + self.n_experts}) held of "
+                    f"{self.n_routed} routed")
+            per_group = self.n_routed // self.router_groups
+            if (self.n_routed % self.router_groups
+                    or not 0 < self.router_topk_groups
+                    <= self.router_groups
+                    or self.router_topk_groups * per_group
+                    < self.experts_per_token):
+                raise ValueError(
+                    f"{self.name}: {self.n_routed} routed experts in "
+                    f"{self.router_groups} groups, top "
+                    f"{self.router_topk_groups}, {self.experts_per_token} "
+                    "a token")
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_scoring {self.router_scoring!r}")
+
+    @property
+    def n_routed(self) -> int:
+        """The router's width: every routed expert of the layer, held
+        here or not."""
+        return self.n_routed_experts or self.n_experts
+
     @property
     def is_encdec(self) -> bool:
         return bool(self.encoder_stages)
@@ -123,7 +172,8 @@ class ArchConfig:
         return len(unbounded) < len(blocks)
 
     def reduced(self, **overrides) -> "ArchConfig":
-        """Smoke-test variant: 2 layers, d_model<=512, <=4 experts."""
+        """Smoke-test variant: 2 layers, d_model<=512, <=4 experts held of
+        at most 8 routed, in at most 4 router groups."""
         small_stages = tuple(
             Stage(unit=s.unit, repeat=1) for s in self.stages[:2]) or \
             self.stages
@@ -146,6 +196,10 @@ class ArchConfig:
             vocab_size=min(self.vocab_size, 512),
             stages=tuple(trimmed),
             n_experts=min(self.n_experts, 4),
+            n_routed_experts=min(self.n_routed_experts, 8),
+            expert_offset=0,
+            router_groups=min(self.router_groups, 4),
+            router_topk_groups=min(self.router_topk_groups, 2),
             experts_per_token=min(self.experts_per_token, 2),
             moe_d_ff=min(self.moe_d_ff, 128) if self.moe_d_ff else 0,
             n_shared_experts=min(self.n_shared_experts, 1),

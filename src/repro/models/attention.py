@@ -20,7 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .common import apply_rope, dense, dense_init, norm, norm_init, softcap
+from .common import (apply_rope, dense, dense_init, norm, norm_init, softcap,
+                     yarn_mscale)
 
 Array = jax.Array
 
@@ -142,8 +143,10 @@ def gqa_forward(p: Mapping, lora: Mapping | None, x: Array, cfg, block, *,
         q = _split_heads(proj("q", hx), h)
         kk = _split_heads(proj("k", hx), kv)
         vv = _split_heads(proj("v", hx), kv)
-        q = apply_rope(q, positions[None], cfg.rope_theta, cfg.rope_kind)
-        kk = apply_rope(kk, positions[None], cfg.rope_theta, cfg.rope_kind)
+        q = apply_rope(q, positions[None], cfg.rope_theta, cfg.rope_kind,
+                       cfg.rope_scaling)
+        kk = apply_rope(kk, positions[None], cfg.rope_theta, cfg.rope_kind,
+                        cfg.rope_scaling)
         qg = q.reshape(q.shape[:2] + (kv, g, hd))
         out = _attend_chunked(qg, kk, vv, causal=block.causal,
                               window=block.window, q_positions=positions,
@@ -166,8 +169,10 @@ def gqa_forward(p: Mapping, lora: Mapping | None, x: Array, cfg, block, *,
         kk = _split_heads(proj("k", hx), kv)
         vv = _split_heads(proj("v", hx), kv)
         posb = jnp.full((1, 1), pos)
-        q = apply_rope(q, posb, cfg.rope_theta, cfg.rope_kind)
-        kk = apply_rope(kk, posb, cfg.rope_theta, cfg.rope_kind)
+        q = apply_rope(q, posb, cfg.rope_theta, cfg.rope_kind,
+                       cfg.rope_scaling)
+        kk = apply_rope(kk, posb, cfg.rope_theta, cfg.rope_kind,
+                        cfg.rope_scaling)
         t = cache["k"].shape[1]
         # ring buffer slot; cache may be smaller than the window when the
         # serving context itself is shorter (t == min(window, seq_len))
@@ -260,6 +265,16 @@ def mla_init(key, cfg, block) -> dict:
 MLA_LORA_TARGETS = ("q_a", "q_b", "kv_a", "kv_b", "o")
 
 
+def mla_softmax_scale(cfg) -> float:
+    """``qk_dim ** -0.5``; under YaRN times ``mscale(factor,
+    mscale_all_dim) ** 2``, as DeepSeek-V3 publishes it."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    yarn = cfg.rope_scaling
+    if yarn is not None and yarn.mscale_all_dim:
+        scale *= yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+    return scale
+
+
 def mla_forward(p: Mapping, lora: Mapping | None, x: Array, cfg, block, *,
                 mode: str, positions: Array | None = None,
                 cache: Mapping | None = None, pos: Array | None = None,
@@ -277,7 +292,8 @@ def mla_forward(p: Mapping, lora: Mapping | None, x: Array, cfg, block, *,
     h = cfg.n_heads
     nope, rope_d, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     qk_dim = nope + rope_d
-    scale = qk_dim ** -0.5
+    scale = mla_softmax_scale(cfg)
+    yarn = cfg.rope_scaling
     hx = norm(p["ln"], x, cfg.norm_eps)
 
     def proj(name, inp):
@@ -297,9 +313,10 @@ def mla_forward(p: Mapping, lora: Mapping | None, x: Array, cfg, block, *,
     if mode in ("full", "prefill"):
         s = x.shape[1]
         positions = jnp.arange(s) if positions is None else positions
-        q_rope = apply_rope(q_rope, positions[None], cfg.rope_theta, "full")
+        q_rope = apply_rope(q_rope, positions[None], cfg.rope_theta, "full",
+                            yarn)
         k_rope_r = apply_rope(k_rope[..., None, :], positions[None],
-                              cfg.rope_theta, "full")[..., 0, :]
+                              cfg.rope_theta, "full", yarn)[..., 0, :]
         kv = proj("kv_b", ckv).reshape(hx.shape[:2] + (h, nope + vd))
         k_nope, v = kv[..., :nope], kv[..., nope:]
         k = jnp.concatenate(
@@ -322,9 +339,9 @@ def mla_forward(p: Mapping, lora: Mapping | None, x: Array, cfg, block, *,
 
     # ---------------------------- decode --------------------------------
     posb = jnp.full((1, 1), pos)
-    q_rope = apply_rope(q_rope, posb, cfg.rope_theta, "full")
+    q_rope = apply_rope(q_rope, posb, cfg.rope_theta, "full", yarn)
     k_rope_new = apply_rope(k_rope[..., None, :], posb, cfg.rope_theta,
-                            "full")[..., 0, :]
+                            "full", yarn)[..., 0, :]
     ckv_c = lax.dynamic_update_slice_in_dim(cache["ckv"], ckv, pos, axis=1)
     kr_c = lax.dynamic_update_slice_in_dim(cache["kr"], k_rope_new, pos,
                                            axis=1)

@@ -9,6 +9,7 @@ Conventions (differ from the small paper nets, chosen for TPU einsums):
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Mapping
 
 import jax
@@ -92,22 +93,53 @@ def softcap(x: Array, cap: float) -> Array:
 
 
 # ------------------------------------------------------------------ rope ----
-def rope_freqs(head_dim: int, theta: float) -> Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                            / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature ``0.1 m ln s + 1`` (1 for s <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> Array:
+    """Rope's inverse frequencies; with ``scaling`` (a ``YaRN``) those of
+    DeepSeek's YaRN: interpolated by ``factor`` below the dimension where
+    ``beta_slow`` rotations fit the original context, kept above the one
+    where ``beta_fast`` do, and ramped linearly between."""
+    extra = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    if scaling is None:
+        return extra
+
+    def dim_of(rotations):
+        return (head_dim * math.log(scaling.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(scaling.beta_fast)), 0)
+    high = min(math.ceil(dim_of(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / scaling.factor * ramp + extra * (1.0 - ramp)
 
 
 def apply_rope(x: Array, positions: Array, theta: float,
-               kind: str = "full") -> Array:
-    """x: (..., seq, heads, head_dim); positions: broadcastable (..., seq)."""
+               kind: str = "full", scaling=None) -> Array:
+    """x: (..., seq, heads, head_dim); positions: broadcastable (..., seq).
+    ``scaling``: None or a ``YaRN``, whose cos and sin are also scaled by
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``."""
     if kind == "none":
         return x
     hd = x.shape[-1]
     rot = hd if kind == "full" else hd // 2
     xr, xp = x[..., :rot], x[..., rot:]
-    freqs = rope_freqs(rot, theta)                           # (rot/2,)
+    freqs = rope_freqs(rot, theta, scaling)                  # (rot/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs   # (..., s, rot/2)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(xr.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                           axis=-1).astype(x.dtype)

@@ -14,6 +14,7 @@ from jax import lax
 
 from repro.fl.client import softmax_xent  # reuse CE impl
 from repro.lora import init_pair
+from repro.obs import get_registry
 from .common import (dense, dense_init, dtype_of, embed, embed_init, norm,
                      norm_init, softcap, unembed)
 from .transformer import (block_init_cache, stage_forward, stage_init,
@@ -231,5 +232,12 @@ class Model:
         return logits[:, 0], tuple(new_caches)
 
 
+_EXPERTS_HELD = get_registry().gauge(
+    "moe_experts_held", "routed experts each expert layer of the last MoE "
+    "model built holds, by the router's width", labelnames=("routed",))
+
+
 def make_model(cfg, remat=True, mla_absorbed: bool = False) -> Model:
+    if any(b.ffn == "moe" for s in cfg.stages for b in s.unit):
+        _EXPERTS_HELD.labels(routed=str(cfg.n_routed)).set(cfg.n_experts)
     return Model(cfg=cfg, remat=remat, mla_absorbed=mla_absorbed)

@@ -17,13 +17,13 @@ Per-device a2a volume = 2 * C_local * E * d * bytes -- independent of the
 expert count replication that the all-gather pays.  Used as the SSPerf
 iteration A6 for deepseek-v3 (``ArchConfig.moe_mode = 'ep_a2a'``).
 
-Restrictions (asserted): n_experts divisible by the model-axis size,
+Restrictions (checked): the experts held (``n_experts`` plus padding)
+divisible by the model-axis size,
 tokens divisible by the data sharding; LoRA per-expert adapters must be
 sharded over 'model' (rules.adapter_specs does this).
 """
 from __future__ import annotations
 
-import math
 from typing import Mapping
 
 import jax
@@ -32,8 +32,9 @@ from jax import lax
 
 from repro.core.compat import axis_size, shard_map_no_check
 
-from .common import dense, norm
-from .moe import _route, expert_dense
+from .common import norm
+from .moe import (_capacity, _route, _shared, _slots, _swiglu_experts,
+                  router_logits)
 
 Array = jax.Array
 
@@ -83,70 +84,53 @@ def moe_forward_ep_wrapped(p: Mapping, lora: Mapping | None, x: Array,
 def moe_forward_ep(p: Mapping, lora: Mapping | None, x: Array, cfg, *,
                    model_axis: str = "model", alpha: float = 16.0) -> Array:
     """shard_map body: x is the LOCAL shard (b_local, s, d); expert weights
-    in ``p`` are the LOCAL expert slice (E/ep, d, f).  Must run inside a
-    shard_map over (data..., model) with tokens sharded on data and
-    experts on model."""
+    in ``p`` are the LOCAL expert slice (E/ep, d, f): model shard ``i``
+    holds experts ``expert_offset + i * E/ep`` onwards.  Tokens are routed
+    over all ``n_routed`` experts with the same router as the sort path.
+    Must run inside a shard_map over (data..., model) with tokens sharded
+    on data and experts on model."""
     lora = lora or {}
     ep = axis_size(model_axis)
     e = cfg.n_experts + cfg.moe_pad_experts
-    e_local = e // ep
-    k = cfg.experts_per_token
+    if e % ep:
+        raise ValueError(f"{e} experts held over {ep} model shards")
     b, s, d = x.shape
     n = b * s
-    cap = int(math.ceil(n * k / e * cfg.capacity_factor))
+    cap = _capacity(cfg, n)
 
-    h = norm(p["ln"], x, cfg.norm_eps)
-    flat = h.reshape(n, d)
-    # router weights are replicated; logits over ALL experts
-    logits = jnp.einsum("nd,de->ne", flat.astype(jnp.float32),
-                        p["router"]["w"])
-    w, ix = _route(cfg, logits)                       # (n, k)
+    with jax.named_scope("moe.route"):
+        h = norm(p["ln"], x, cfg.norm_eps)
+        flat = h.reshape(n, d)
+        # router weights are replicated; logits over ALL routed experts
+        logits = router_logits(p["router"], flat)
+        w, ix = _route(cfg, logits, p["router"].get("select_bias"))
 
-    # local capacity dispatch (same sort trick as the pjit path)
-    ae = ix.reshape(-1)
-    order = jnp.argsort(ae)
-    ae_sorted = ae[order]
-    pos_in_expert = jnp.arange(n * k) - jnp.searchsorted(
-        ae_sorted, ae_sorted, side="left")
-    keep = pos_in_expert < cap
-    token_of = order // k
-    rows = jnp.where(keep, ae_sorted, e - 1)
-    cols = jnp.where(keep, pos_in_expert, cap - 1)
-    vals = flat[token_of] * keep[:, None].astype(flat.dtype)
-    einp = jnp.zeros((e, cap, d), flat.dtype).at[rows, cols].add(vals)
+    with jax.named_scope("moe.dispatch"):
+        # local capacity dispatch into every held expert's slots (the sort
+        # path's slots), then an a2a over the model axis: each peer
+        # receives the slots destined for ITS local experts from every
+        # peer.  tiled semantics:
+        # (e, cap, d) --split ax0 / concat ax1--> (e/ep, ep*cap, d)
+        rows, cols, keep, token_of, order = _slots(cfg, ix, cap)
+        vals = flat[token_of] * keep[:, None].astype(flat.dtype)
+        einp = jnp.zeros((e, cap, d), flat.dtype).at[rows, cols].add(vals)
+        einp = lax.all_to_all(einp, model_axis, split_axis=0,
+                              concat_axis=1, tiled=True)
 
-    # a2a over the model axis: each peer receives the slots destined for
-    # ITS local experts from every peer.  tiled semantics:
-    # (e, cap, d) --split ax0 / concat ax1--> (e_local, ep*cap, d)
-    einp = lax.all_to_all(einp, model_axis, split_axis=0, concat_axis=1,
-                          tiled=True)
-    einp = einp[None]                                 # group dim of 1
+    with jax.named_scope("moe.experts"):
+        eo = _swiglu_experts(p, lora, einp[None], alpha)  # (1,e/ep,ep*cap,d)
+        shared = _shared(p, lora, flat, alpha)
 
-    eg = expert_dense(p["experts"]["gate"]["w"], einp,
-                      lora.get("experts/gate"), alpha)
-    eu = expert_dense(p["experts"]["up"]["w"], einp,
-                      lora.get("experts/up"), alpha)
-    eh = jax.nn.silu(eg) * eu
-    eo = expert_dense(p["experts"]["down"]["w"], eh,
-                      lora.get("experts/down"), alpha)  # (1,e_local,ep*cap,d)
-
-    # inverse a2a back to token owners:
-    # (e_local, ep*cap, d) --split ax1 / concat ax0--> (e, cap, d)
-    eo = lax.all_to_all(eo[0], model_axis, split_axis=1, concat_axis=0,
-                        tiled=True)
-    gathered = eo[rows, cols] * keep[:, None].astype(eo.dtype)
-    wflat = w.reshape(-1)[order]
-    y = jnp.zeros((n, d), eo.dtype).at[token_of].add(
-        gathered * wflat[:, None].astype(eo.dtype))
-
-    if "shared" in p:
-        sh = p["shared"]
-        y = y + dense(sh["down"],
-                      jax.nn.silu(dense(sh["gate"], flat,
-                                        lora.get("shared/gate"), alpha)) *
-                      dense(sh["up"], flat, lora.get("shared/up"), alpha),
-                      lora.get("shared/down"), alpha)
-    y = y.reshape(b, s, d)
+    with jax.named_scope("moe.combine"):
+        # inverse a2a back to token owners:
+        # (e/ep, ep*cap, d) --split ax1 / concat ax0--> (e, cap, d)
+        eo = lax.all_to_all(eo[0], model_axis, split_axis=1, concat_axis=0,
+                            tiled=True)
+        gathered = eo[rows, cols] * keep[:, None].astype(eo.dtype)
+        wflat = w.reshape(-1)[order]
+        y = jnp.zeros((n, d), eo.dtype).at[token_of].add(
+            gathered * wflat[:, None].astype(eo.dtype))
+        y = (y + shared).reshape(b, s, d)
     if cfg.post_block_norm:
         y = norm(p["post_ln"], y, cfg.norm_eps)
     return y

@@ -179,7 +179,7 @@ def _block_params(cfg, spec) -> float:
         f = cfg.moe_d_ff or cfg.d_ff
         # dispatched compute ~ active experts x capacity factor
         n += (3 * d * f * cfg.experts_per_token * cfg.capacity_factor
-              + 3 * d * f * cfg.n_shared_experts + d * cfg.n_experts)
+              + 3 * d * f * cfg.n_shared_experts + d * cfg.n_routed)
     return n
 
 
@@ -215,6 +215,6 @@ def active_params(cfg) -> float:
                 f = cfg.moe_d_ff or cfg.d_ff
                 n += 3 * d * f * cfg.experts_per_token      # active experts
                 n += 3 * d * f * cfg.n_shared_experts
-                n += d * cfg.n_experts                      # router
+                n += d * cfg.n_routed                       # router
             total += n * stage.repeat
     return total

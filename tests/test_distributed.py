@@ -154,7 +154,9 @@ print("OK")
 @pytest.mark.slow
 def test_moe_ep_a2a_matches_pjit_path():
     """Explicit expert-parallel all-to-all dispatch (moe_ep) against the
-    sort/pjit path on a (data=2, model=4) mesh with 8 experts."""
+    sort/pjit path on a (data=2, model=4) mesh with 8 experts held:
+    granite's softmax router over those 8, and a DeepSeek-V3 share of 8
+    of 16 sigmoid-routed experts."""
     with open("/dev/null"):
         pass
     code = open(os.path.join(ROOT, "tests", "_moe_ep_child.py")).read()

@@ -1,4 +1,6 @@
 """Sharding rules unit tests (no multi-device needed: specs are data)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -53,16 +55,23 @@ def test_param_specs_yi():
     assert st["mix"]["ln"]["scale"] == P(None, None)
 
 
-def test_param_specs_moe_expert_axis():
-    cfg = get_config("deepseek-v3-671b")
+@pytest.mark.parametrize("held,expert_spec", [(256, "model"), (8, None)])
+def test_param_specs_moe_expert_axis(held, expert_spec):
+    """The registry's every routed expert, and one chip's share of 8: the
+    router keeps its published width of ``n_routed_experts``."""
+    cfg = dataclasses.replace(get_config("deepseek-v3-671b"), n_experts=held)
     model = make_model(cfg)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     specs = rules.param_specs(shapes, FakeMesh1())
     moe = specs["stages"][1]["b0"]["ffn"]
-    # experts (L, E, d, f): E sharded over model (256 % 16 == 0)
-    assert moe["experts"]["gate"]["w"] == P(None, "model", None, None)
-    # router replicated
+    moe_shapes = shapes["stages"][1]["b0"]["ffn"]
+    assert moe_shapes["router"]["w"].shape[-1] == cfg.n_routed_experts == 256
+    assert moe_shapes["experts"]["gate"]["w"].shape[1] == held
+    # experts (L, E, d, f): E sharded over model where E % 16 == 0
+    assert moe["experts"]["gate"]["w"] == P(None, expert_spec, None, None)
+    # router and its selection bias replicated
     assert moe["router"]["w"] == P(None, None, None)
+    assert moe["router"]["select_bias"] == P(None, None)
 
 
 def test_param_specs_fsdp_shards_contracting_dim():
@@ -93,14 +102,17 @@ def test_batch_and_cache_specs():
     assert dec[0]["b1"]["k"][1] == ("pod", "data")
 
 
-def test_adapter_specs_expert_axis():
-    cfg = get_config("deepseek-v3-671b")
+@pytest.mark.parametrize("held,expert_spec", [(256, "model"), (8, None)])
+def test_adapter_specs_expert_axis(held, expert_spec):
+    cfg = dataclasses.replace(get_config("deepseek-v3-671b"), n_experts=held)
     model = make_model(cfg)
     shapes = jax.eval_shape(
         lambda k: model.init_adapters(k, rank=8), jax.random.PRNGKey(0))
     specs = rules.adapter_specs(shapes, FakeMesh1())
     pair = specs["stages"][1]["b0"]["ffn/experts/gate"]
-    assert pair["A"] == P(None, "model", None, None)
+    # the adapter's expert axis is the experts held
+    assert shapes["stages"][1]["b0"]["ffn/experts/gate"]["A"].shape[1] == held
+    assert pair["A"] == P(None, expert_spec, None, None)
     # non-expert adapters replicated
     q = specs["stages"][1]["b0"]["mix/q_a"]
     assert q["A"] == P(None, None, None)
