@@ -35,11 +35,13 @@ olmax-style trick for unbiased low-precision accumulators) backs the
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Mapping, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.obs import host_syncs
 
@@ -81,12 +83,19 @@ def _iter_pairs(tree, path=()):
             yield from _iter_pairs(v, path + (i,))
 
 
+def _dtype_of(x) -> np.dtype:
+    """A leaf's dtype, read on the host: a numpy leaf is not copied to the
+    device to learn it."""
+    dtype = getattr(x, "dtype", None)
+    return np.dtype(dtype) if dtype is not None else np.result_type(x)
+
+
 # -------------------------------------------------------------- codecs ----
 def codec_of_pair(pair: Mapping) -> str:
     """Wire format of one (possibly encoded) pair."""
     if "A_scale" in pair or "B_scale" in pair:
         return "int8"
-    if jnp.asarray(pair["A"]).dtype == jnp.bfloat16:
+    if _dtype_of(pair["A"]) == jnp.bfloat16:
         return "bf16"
     return "none"
 
@@ -199,8 +208,118 @@ class UploadValidationError(ValueError):
         self.reason = reason
 
 
+def _scale_limit(width: int) -> float:
+    """Largest int8 scale whose decoded row norm, ``scale * 127 *
+    sqrt(width)``, stays inside float32."""
+    return float(jnp.finfo(jnp.float32).max) / (
+        _INT8_QMAX * math.sqrt(max(width, 1)))
+
+
+@functools.partial(jax.jit, static_argnames=("widths",))
+def _upload_flags(adapter_floats, base_floats, scales, widths):
+    """Every device check of one upload in one program: ``[adapters all
+    finite, base_trainable all finite]``, then per scale plane ``bad``
+    (non-finite or non-positive) and ``overflow`` (past
+    :func:`_scale_limit` of its static row width)."""
+    def finite(leaves):
+        return jnp.all(jnp.stack([jnp.all(jnp.isfinite(x)) for x in leaves]
+                                 or [jnp.array(True)]))
+    flags = [finite(adapter_floats), finite(base_floats)]
+    for s, width in zip(scales, widths):
+        s = s.astype(jnp.float32)
+        flags += [~jnp.all(jnp.isfinite(s) & (s > 0)),
+                  jnp.any(s > _scale_limit(width))]
+    return jnp.stack(flags)
+
+
+def _float_leaves(tree) -> list:
+    return [x for x in jax.tree.leaves(tree)
+            if jnp.issubdtype(_dtype_of(x), jnp.floating)]
+
+
+def _scale_planes(adapters) -> list:
+    """``(name, plane, row width)`` of every int8 scale plane, in walk
+    order, ``A_scale`` before ``B_scale`` within a pair."""
+    planes = []
+    for path, pair in _iter_pairs(adapters):
+        name = "/".join(str(p) for p in path) or "<root>"
+        for side, key in (("A", "A_scale"), ("B", "B_scale")):
+            if key in pair:
+                shape = np.shape(pair[side])
+                planes.append((f"{name}.{key}", pair[key],
+                               shape[-1] if side == "A" else shape[-2]))
+    return planes
+
+
+def _devices(x):
+    return (frozenset(x.sharding.device_set) if isinstance(x, jax.Array)
+            else None)
+
+
+def _read_flags(adapter_floats: list, base_floats: list,
+                planes: list) -> tuple:
+    """Run :func:`_upload_flags` over the given leaves and read every
+    flag with one ``jax.device_get``.
+
+    Leaves committed to different devices cannot meet in one program:
+    each device set runs its own (host leaves join the first), and the
+    one read takes all their flags."""
+    scales = [plane for _, plane, _ in planes]
+    lists = (adapter_floats, base_floats, scales)
+    keys = [[_devices(x) for x in xs] for xs in lists]
+    first = next((k for ks in keys for k in ks if k is not None), None)
+    # device set -> (adapter floats, base floats, indices into planes)
+    groups = {}
+    for n, (xs, ks) in enumerate(zip(lists, keys)):
+        for i, (x, k) in enumerate(zip(xs, ks)):
+            group = groups.setdefault(first if k is None else k,
+                                      ([], [], []))
+            group[n].append(i if n == 2 else x)
+    if not groups:
+        return True, True, [], 0
+    flags = jax.device_get([
+        _upload_flags(a, b, [scales[i] for i in mine],
+                      widths=tuple(planes[i][2] for i in mine))
+        for a, b, mine in groups.values()])
+    by_plane = {}
+    for (_, _, mine), f in zip(groups.values(), flags):
+        by_plane.update((i, (bool(f[2 + 2 * j]), bool(f[3 + 2 * j])))
+                        for j, i in enumerate(mine))
+    reads = int(any(isinstance(x, jax.Array) for xs in lists for x in xs))
+    return (all(f[0] for f in flags), all(f[1] for f in flags),
+            [(name, *by_plane[i]) for i, (name, _, _) in enumerate(planes)],
+            reads)
+
+
+def read_upload_flags(adapters, base_trainable) -> tuple:
+    """All device checks of one upload -- every float leaf of both trees,
+    every int8 scale plane -- in one compiled program, read once.
+
+    Returns ``(adapters_finite, base_finite, [(plane name, bad,
+    overflow)], reads)``: ``reads`` is 1 when a checked leaf was a
+    ``jax.Array`` (a read the host waited on), else 0.  Raise the scale
+    faults with :func:`raise_scale_faults`."""
+    return _read_flags(_float_leaves(adapters),
+                       _float_leaves(base_trainable),
+                       _scale_planes(adapters))
+
+
+def raise_scale_faults(per_plane: list) -> None:
+    """Raise the first scale fault in walk order, ``bad_scale`` before
+    ``overflow`` within a plane (see :func:`validate_encoded_adapters`)."""
+    for name, bad, overflow in per_plane:
+        if bad:
+            raise UploadValidationError(
+                f"non-finite or non-positive quantization scale in {name}",
+                reason="bad_scale")
+        if overflow:
+            raise UploadValidationError(
+                f"quantization scale overflow in {name}: decoded row norm "
+                f"would exceed float32 range", reason="overflow")
+
+
 def validate_encoded_adapters(adapters) -> None:
-    """Ingestion sanity for encoded uploads (host-side, eager).
+    """Ingestion sanity for encoded uploads (one compiled check, one read).
 
     Raises :class:`UploadValidationError` (a ``ValueError``) when any
     quantization scale is non-finite or non-positive (``reason
@@ -208,32 +327,9 @@ def validate_encoded_adapters(adapters) -> None:
     fp32 (``scale * 127 * sqrt(row_width)`` past ``finfo(f32).max`` --
     such an upload would poison ``FoldState`` masses irrecoverably;
     ``reason "overflow"``)."""
-    reads = 0               # flags read back from the device
-    try:
-        for path, pair in _iter_pairs(adapters):
-            name = "/".join(str(p) for p in path) or "<root>"
-            for side, key in (("A", "A_scale"), ("B", "B_scale")):
-                if key not in pair:
-                    continue
-                on_device = isinstance(pair[key], jax.Array)
-                s = jnp.asarray(pair[key], jnp.float32)
-                reads += on_device
-                if not bool(jnp.all(jnp.isfinite(s) & (s > 0))):
-                    raise UploadValidationError(
-                        f"non-finite or non-positive quantization scale in "
-                        f"{name}.{key}", reason="bad_scale")
-                width = (pair[side].shape[-1] if side == "A"
-                         else pair[side].shape[-2])
-                limit = float(jnp.finfo(jnp.float32).max) / (
-                    _INT8_QMAX * math.sqrt(max(width, 1)))
-                reads += on_device
-                if bool(jnp.any(s > limit)):
-                    raise UploadValidationError(
-                        f"quantization scale overflow in {name}.{key}: "
-                        f"decoded row norm would exceed float32 range",
-                        reason="overflow")
-    finally:
-        _SYNCS_SCALE.inc(reads)
+    *_, per_plane, reads = _read_flags([], [], _scale_planes(adapters))
+    _SYNCS_SCALE.inc(reads)
+    raise_scale_faults(per_plane)
 
 
 # ---------------------------------------------- stochastic accumulators ----
@@ -280,6 +376,6 @@ __all__ = [
     "CODECS", "codec_of_pair", "tree_codec", "cohort_codecs",
     "encode_pair", "decode_pair", "encode_adapters", "decode_adapters",
     "encode_update", "decode_update", "validate_encoded_adapters",
-    "UploadValidationError",
+    "read_upload_flags", "raise_scale_faults", "UploadValidationError",
     "stochastic_round", "stochastic_round_tree",
 ]

@@ -53,11 +53,12 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.codec import (CODECS, UploadValidationError,
                               codec_of_pair, decode_update,
-                              stochastic_round_tree, tree_codec,
-                              validate_encoded_adapters)
+                              raise_scale_faults, read_upload_flags,
+                              stochastic_round_tree, tree_codec)
 from repro.core.codec import _iter_pairs as _iter_adapter_pairs
 from repro.core.strategy import (ClientUpdate, FoldState, ServerState,
                                  get_strategy)
@@ -345,44 +346,37 @@ class AsyncAggregator:
                 # structural integrity: a truncated/garbled upload (lost
                 # frames, a proxy cutting the payload short) must be rejected
                 # here, not crash a fused kernel three layers down
-                a, b = jnp.asarray(p["A"]), jnp.asarray(p["B"])
-                if (a.ndim < 2 or b.ndim < 2
-                        or a.shape[-2] != b.shape[-1]):
+                a, b = np.shape(p["A"]), np.shape(p["B"])
+                if len(a) < 2 or len(b) < 2 or a[-2] != b[-1]:
                     self._reject("malformed")
                     name = "/".join(str(s) for s in path) or "<root>"
                     raise ValueError(
                         f"rejected client update: truncated or malformed "
-                        f"pair {name}: A {tuple(a.shape)} / B "
-                        f"{tuple(b.shape)} do not share a rank axis")
+                        f"pair {name}: A {tuple(a)} / B {tuple(b)} do not "
+                        f"share a rank axis")
             bad = sorted(used - set(self.codecs))
             if bad:
                 self._reject("codec_not_allowed")
                 raise ValueError(
                     f"rejected client update: upload codec {bad} not in the "
                     f"negotiated set {list(self.codecs)}")
+            # every device check in one program, its flags in one read
+            finite_a, finite_b, per_plane, reads = read_upload_flags(
+                update.adapters, update.base_trainable)
+            self._m_syncs_finite.inc(reads)
             # scale sanity first: a NaN scale should name the scale, not fall
             # through to the generic non-finite message below
             try:
-                validate_encoded_adapters(update.adapters)
+                raise_scale_faults(per_plane)
             except UploadValidationError as e:
                 self._reject(e.reason)      # "bad_scale" | "overflow"
                 raise
-            reads = 0          # finiteness flags read back from the device
-            try:
-                for name, tree in (("adapters", update.adapters),
-                                   ("base_trainable", update.base_trainable)):
-                    for leaf in jax.tree.leaves(tree):
-                        x = jnp.asarray(leaf)
-                        if not jnp.issubdtype(x.dtype, jnp.floating):
-                            continue
-                        reads += isinstance(leaf, jax.Array)
-                        if not bool(jnp.all(jnp.isfinite(x))):
-                            self._reject("nan_tensor")
-                            raise ValueError(
-                                "rejected client update: non-finite values in "
-                                f"{name}")
-            finally:
-                self._m_syncs_finite.inc(reads)
+            for name, finite in (("adapters", finite_a),
+                                 ("base_trainable", finite_b)):
+                if not finite:
+                    self._reject("nan_tensor")
+                    raise ValueError(
+                        f"rejected client update: non-finite values in {name}")
             return used
 
     def submit(self, update: ClientUpdate, model_version: int | None = None,

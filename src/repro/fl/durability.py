@@ -243,6 +243,7 @@ class DurableAggregator(AsyncAggregator):
         self.wal = WriteAheadLog(dir, fsync=wal_fsync)
         self._inner = 0                 # >0: inside a journaled operation
         self._replaying = False
+        self._validated = None          # (update, codecs) checked by submit
         self._ckpt_seq = 0              # wal seq covered by newest ckpt
         self._accepts_since_ckpt = 0
         self.n_recoveries = 0
@@ -295,23 +296,30 @@ class DurableAggregator(AsyncAggregator):
         if update_id is not None and update_id in self.dedup:
             self._reject("duplicate")
             return False
-        self._validate_update(update)
-        self._journal("submit", {
-            "update": _update_to_obj(update), "update_id": update_id,
-            "model_version": model_version, "now": now,
-            "pulled_at": pulled_at})
+        self._validated = (update, self._validate_update(update))
         self._inner += 1
         try:
+            self._journal("submit", {
+                "update": _update_to_obj(update), "update_id": update_id,
+                "model_version": model_version, "now": now,
+                "pulled_at": pulled_at})
             advanced = super().submit(update, model_version=model_version,
                                       now=now, pulled_at=pulled_at,
                                       update_id=update_id)
         finally:
             self._inner -= 1
+            self._validated = None
         self._accepts_since_ckpt += 1
         if (self.checkpoint_every
                 and self._accepts_since_ckpt >= self.checkpoint_every):
             self.checkpoint()
         return advanced
+
+    def _validate_update(self, update: ClientUpdate) -> set:
+        # the base submit validates again: reuse what this submit found
+        if self._validated is not None and self._validated[0] is update:
+            return self._validated[1]
+        return super()._validate_update(update)
 
     def flush(self, now: float = 0.0) -> ServerState:
         # only *externally driven* flushes are journaled -- a flush the

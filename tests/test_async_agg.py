@@ -12,6 +12,9 @@ keeps the stale contributor), and the event-driven simulator is finite
 and deterministic.
 """
 import dataclasses
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +31,8 @@ from repro.lora import init_adapters
 from _cohorts import R_MAX, SPECS, assert_trees_close, hetero_cohort
 
 jax.config.update("jax_platform_name", "cpu")
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def make_state(strategy, seed=99):
@@ -484,6 +489,123 @@ def test_each_rejection_path_increments_exactly_its_own_counter(reason):
         agg.submit(bad)
     assert _rejection_counts(agg) == {reason: 1}
     assert agg.n_received == 0 and len(agg.buffer) == 0
+
+
+@pytest.mark.parametrize("case", ["none", "int8", "base_trainable"])
+def test_submit_checks_an_upload_in_one_program_and_one_read(
+        case, monkeypatch):
+    """Every device check of an upload (finiteness of each float leaf of
+    both trees, both flags of each int8 scale plane) runs in one compiled
+    program whose flags the host reads with one ``jax.device_get``; a
+    second upload of the same shapes traces nothing.  Rejections still
+    name what the per-leaf checks named: a NaN scale next to a NaN
+    payload is ``bad_scale``, a NaN only in ``base_trainable`` names
+    ``base_trainable``."""
+    from jax._src.dispatch import JAXPR_TRACE_EVENT
+    from repro.core import codec
+    from repro.obs import MetricsRegistry
+
+    def upload(seed):
+        u = _one_update(seed=seed)
+        if case == "none":
+            return dataclasses.replace(u, base_trainable={})
+        return codec.encode_update(u, "int8") if case == "int8" else u
+
+    s = get_strategy("rbla")
+    # buffered, never due: submit validates and folds nothing
+    agg = AsyncAggregator(s, make_state(s), buffer_size=8,
+                          registry=MetricsRegistry())
+    agg.submit(upload(43))                 # compiles this signature
+    calls, traces = [], []
+    get = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: calls.append(1) or get(x))
+
+    def listen(event, _secs, **_):
+        if event == JAXPR_TRACE_EVENT:
+            traces.append(event)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        agg.submit(upload(44))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert len(calls) == 1 and traces == [] and agg.n_received == 2
+
+    bad = upload(45)
+    adapters = {k: dict(v) for k, v in bad.adapters.items()}
+    if case == "int8":
+        adapters["fc1"]["A_scale"] = \
+            adapters["fc1"]["A_scale"].at[0].set(float("nan"))
+        # a plain pair after it, its payload NaN: the scale is named
+        adapters["fc2"] = _one_update(seed=45).adapters["fc2"]
+        adapters["fc2"]["A"] = adapters["fc2"]["A"].at[0, 0].set(
+            float("nan"))
+        reason, match = "bad_scale", "scale in fc1.A_scale"
+    elif case == "none":
+        adapters["fc1"]["A"] = adapters["fc1"]["A"].at[0, 0].set(
+            float("nan"))
+        reason, match = "nan_tensor", "non-finite values in adapters"
+    else:
+        bad = dataclasses.replace(
+            bad, base_trainable={"b": jnp.full((4,), jnp.nan)})
+        reason, match = "nan_tensor", "non-finite values in base_trainable"
+    with pytest.raises(ValueError, match=match):
+        agg.submit(dataclasses.replace(bad, adapters=adapters))
+    assert _rejection_counts(agg) == {reason: 1}
+    assert len(calls) == 2 and agg.n_received == 2
+
+
+def test_upload_on_two_devices_is_checked_with_one_read():
+    """Leaves committed to different devices cannot meet in one jitted
+    program: the check runs once per device set and the host still reads
+    every flag with one ``jax.device_get`` (two host CPU devices, in a
+    child process so this suite keeps its single device)."""
+    code = r"""
+import jax, jax.numpy as jnp
+from repro.core import ClientUpdate, ServerState, get_strategy
+from repro.core.codec import encode_adapters
+from repro.fl import AsyncAggregator
+from repro.lora import init_adapters
+
+d0, d1 = jax.devices()[:2]
+specs = {"fc1": (12, 16), "fc2": (10, 12)}
+state = ServerState(adapters=init_adapters(jax.random.PRNGKey(0), specs,
+                                           8, 8),
+                    base_trainable={}, r_max=8)
+agg = AsyncAggregator(get_strategy("rbla"), state, buffer_size=8)
+up = init_adapters(jax.random.PRNGKey(1), specs, 8, 8)
+up = {"fc1": jax.device_put(up["fc1"], d0),
+      "fc2": jax.device_put(up["fc2"], d1)}
+calls = []
+get = jax.device_get
+jax.device_get = lambda x: calls.append(1) or get(x)
+upd = lambda a: ClientUpdate(adapters=a, base_trainable={}, n_examples=2.0,
+                             rank=8)
+agg.submit(upd(up))
+assert len(calls) == 1 and agg.n_received == 1, calls
+bad = {"fc1": up["fc1"],
+       "fc2": dict(up["fc2"], A=up["fc2"]["A"].at[0, 0].set(jnp.nan))}
+try:
+    agg.submit(upd(bad))
+    raise SystemExit("a NaN on the second device was accepted")
+except ValueError as e:
+    assert "non-finite values in adapters" in str(e), e
+enc = encode_adapters(up, "int8")
+enc["fc2"]["B_scale"] = enc["fc2"]["B_scale"].at[0].set(-1.0)
+try:
+    agg.submit(upd(enc))
+    raise SystemExit("a negative scale on the second device was accepted")
+except ValueError as e:
+    assert "scale in fc2.B_scale" in str(e), e
+assert len(calls) == 3 and enc["fc2"]["B_scale"].devices() == {d1}
+print("OK")
+"""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               PYTHONPATH=os.path.join(ROOT, "src"))
+    res = subprocess.run([sys.executable, "-c", code], env=env, cwd=ROOT,
+                         capture_output=True, text=True, timeout=300)
+    assert res.returncode == 0 and "OK" in res.stdout, res.stderr[-2000:]
 
 
 def test_buffer_wire_byte_accounting():

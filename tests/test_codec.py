@@ -218,6 +218,25 @@ def test_validate_rejects_bad_scales():
         codec.validate_encoded_adapters(big)
 
 
+def test_validate_reads_every_scale_plane_once():
+    """``validate_encoded_adapters`` checks all scale planes in one
+    compiled program: ``host_syncs_total{site="validate_scale"}`` grows
+    by 1 a call on device scales, faulty or not, and by 0 on a plain
+    upload, which has no plane to read."""
+    from repro.obs import host_syncs
+    reads = host_syncs("validate_scale")
+    adapters, _, _ = hetero_cohort(n=1, seed=7)
+    enc = codec.encode_adapters(adapters[0], "int8")
+    bad = {k: dict(v) for k, v in enc.items()}
+    bad["fc2"]["B_scale"] = bad["fc2"]["B_scale"].at[0].set(-1.0)
+    before = reads.value
+    codec.validate_encoded_adapters(enc)
+    codec.validate_encoded_adapters(adapters[0])
+    with pytest.raises(ValueError, match="scale in fc2.B_scale"):
+        codec.validate_encoded_adapters(bad)
+    assert reads.value - before == 2
+
+
 def test_unknown_codec_rejected():
     adapters, _, _ = hetero_cohort(n=1, seed=7)
     with pytest.raises(ValueError, match="unknown codec"):

@@ -297,6 +297,33 @@ def test_crash_recovery_bit_identical(tmp_path):
     assert second.n_received == oracle.n_received
 
 
+def test_durable_submit_validates_an_upload_once(tmp_path):
+    """A live submit validates before it journals, and the base submit
+    reuses that result: one ``submit.validate`` span and one
+    ``validate_finite`` read an upload.  A replayed record is validated
+    once, by the base submit."""
+    from repro.obs import MetricsRegistry
+    s = get_strategy("rbla")
+
+    def validations(agg):
+        reg = agg.obs_registry
+        return (reg.get("obs_span_seconds").labels(
+                    stage="submit.validate").count,
+                reg.get("host_syncs_total").labels(
+                    site="validate_finite").value)
+
+    d = str(tmp_path)
+    live = DurableAggregator(s, make_state(s), dir=d, wal_fsync=False,
+                             registry=MetricsRegistry())
+    live.submit(make_updates(1)[0], update_id="u0")
+    assert live.n_received == 1 and validations(live) == (1, 1)
+    live.close()
+    again = DurableAggregator(s, make_state(s), dir=d, wal_fsync=False,
+                              registry=MetricsRegistry())
+    assert again.n_replayed == 1 and again.n_received == 1
+    assert validations(again)[0] == 1
+
+
 def test_recovery_falls_back_past_corrupt_checkpoint(tmp_path):
     """A checkpoint torn by bit rot is skipped: recovery restores the
     previous snapshot and replays a longer WAL tail -- same final bits

@@ -841,10 +841,10 @@ def _host_syncs():
 def test_host_syncs_count_every_read_the_host_waits_on(case):
     """``host_syncs_total{site}`` against the reads the code makes on a
     tiny cohort: a round reads each client's rank leaves and the
-    previous global's; an upload reads a finiteness flag per
-    float leaf, two flags per int8 scale leaf, and its fold the state's
-    rank leaves (and the upload's, when it names no rank).  Numpy
-    values (the client ranks here) are no reads."""
+    previous global's; an upload reads its validation flags once (every
+    finiteness and scale check in one program), and its fold the
+    state's rank leaves (and the upload's, when it names no rank).
+    Numpy values (the client ranks here) are no reads."""
     from repro.core import codec
     from repro.fl import AsyncAggregator
     n, pairs = 4, len(SPECS)
@@ -869,12 +869,10 @@ def test_host_syncs_count_every_read_the_host_waits_on(case):
         agg.submit(ClientUpdate(
             adapters=upload, base_trainable={}, n_examples=2.0,
             rank=None if case == "fold_unranked" else int(ranks[0])))
-        # f32: A and B are float; int8: only the two scale leaves are
-        want = {"validate_finite": 2 * pairs, "state_spec": pairs}
+        # one read an upload, f32 or int8; submit adds no scale reads
+        want = {"validate_finite": 1, "state_spec": pairs}
         if case == "fold_unranked":
             want["fold_rank"] = pairs
-        if case == "int8_fold":
-            want["validate_scale"] = 2 * 2 * pairs
     after = _host_syncs()
     assert {k: after[k] - before[k] for k in SYNC_SITES} == {
         **dict.fromkeys(SYNC_SITES, 0), **want}
