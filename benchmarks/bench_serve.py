@@ -28,8 +28,8 @@ Reported per case:
 ``--smoke`` runs a reduced case and exits non-zero if parity breaks, the
 speedup at 128 tenants falls under 4x, the serving executable retraces,
 or a publish forces a recompile.  ``--json PATH`` writes the
-machine-readable ``BENCH_serve.json`` (with the same environment header
-as ``BENCH_agg.json``).
+machine-readable ``BENCH_serve.json``, headed by the environment it ran
+in (``repro.obs.bench_payload``).
 """
 from __future__ import annotations
 

@@ -3,11 +3,6 @@
 Prints ``name,us_per_call,derived`` CSV lines (plus markdown tables where
 a bench renders one).  Heavy paper-scale settings are opt-in via each
 bench's CLI; the defaults here finish on a CPU container.
-
-``--json`` additionally writes ``BENCH_agg.json`` (per-strategy /
-per-backend round latency, dispatch counts, plan-cache hit rate from the
-aggregation-throughput bench) so the perf trajectory is tracked across
-PRs.
 """
 from __future__ import annotations
 
@@ -31,7 +26,6 @@ def _run(name, fn):
 
 def main() -> None:
     ok = True
-    write_json = "--json" in sys.argv
 
     def table1():
         from benchmarks import bench_table1
@@ -44,11 +38,6 @@ def main() -> None:
         sys.argv = ["bench_curves", "--rounds", "12", "--n-per-class",
                     "250"]
         bench_curves.main()
-
-    def agg():
-        from benchmarks import bench_agg_throughput
-        bench_agg_throughput.main(
-            ["--json", "BENCH_agg.json"] if write_json else [])
 
     def kernels():
         from benchmarks import bench_kernels
@@ -64,7 +53,6 @@ def main() -> None:
 
     for name, fn in [("table1 (paper Table 1)", table1),
                      ("curves (paper Figs 5-10)", curves),
-                     ("agg_throughput", agg),
                      ("kernels", kernels),
                      ("distributed_agg", dist),
                      ("roofline (dry-run artifacts)", roofline)]:

@@ -2,13 +2,13 @@
 
 This package is the paper's primary contribution (Eq. 6-7, Alg. 1-2) plus
 its distributed (shard_map collective) form, beyond-paper variants, and the
-pluggable :class:`~repro.core.strategy.AggregationStrategy` registry that
-ties every method's reference, distributed, and Pallas paths together.
+pluggable :class:`~repro.core.strategy.AggregationStrategy` registry: each
+method's compiled plan (``repro.core.plan``) on every backend, and its
+float32 reference tree path.
 """
 from .masks import (axis_mask, pad_to_rank, rank_mask, slice_to_rank,
                     stacked_rank_masks)
-from .aggregation import (aggregate, fedavg_leaf, rbla_leaf, zeropad_leaf,
-                          AGGREGATORS)
+from .aggregation import fedavg_leaf, rbla_leaf, zeropad_leaf
 from .variants import (rank_proportional_weights, rbla_norm_leaf,
                        svd_project_pair)
 from .lowrank import (dense_svd, factored_svd, product_factors,
@@ -25,14 +25,11 @@ from .codec import (CODECS, cohort_codecs, decode_adapters, decode_pair,
                     decode_update, encode_adapters, encode_pair,
                     encode_update, stochastic_round, stochastic_round_tree,
                     tree_codec, validate_encoded_adapters)
-from .distributed import (make_distributed_aggregator, rbla_allreduce,
-                          rbla_tree_allreduce)
 
 __all__ = [
     "axis_mask", "pad_to_rank", "rank_mask", "slice_to_rank",
-    "stacked_rank_masks", "aggregate", "fedavg_leaf", "rbla_leaf",
-    "zeropad_leaf", "AGGREGATORS", "make_distributed_aggregator",
-    "rbla_allreduce", "rbla_tree_allreduce", "rank_proportional_weights",
+    "stacked_rank_masks", "fedavg_leaf", "rbla_leaf", "zeropad_leaf",
+    "rank_proportional_weights",
     "rbla_norm_leaf", "svd_project_pair",
     "dense_svd", "factored_svd", "product_factors", "randomized_svd",
     "randomized_svd_product", "svd_project_stacked",

@@ -1,6 +1,6 @@
-"""Server-side aggregation strategies (the paper's core contribution).
+"""Leaf math of the paper's aggregation methods (its core contribution).
 
-Three strategies from the paper:
+Three methods from the paper:
 
 * ``rbla``      -- Rank-Based LoRA Aggregation (Eq. 7 / Alg. 1): per
                    rank-row weighted average over the clients that *own*
@@ -11,20 +11,18 @@ Three strategies from the paper:
 * ``fedavg``    -- plain weighted mean, used for non-LoRA leaves and for
                    the FFT (full fine-tune) baseline.
 
-All functions are pure, jit-able, and operate either on a single stacked
-leaf ``(n_clients, *leaf_shape)`` or on whole pytrees of stacked leaves.
-Masks carry the delta_{i,r} indicator (see ``masks.py``); a mask of ``None``
-means "fully shared leaf" (bias, norm scale, full weight).
+All functions are pure, jit-able, and operate on a single stacked leaf
+``(n_clients, *leaf_shape)``; ``repro.core.strategy`` maps them over
+adapter pytrees.  Masks carry the delta_{i,r} indicator (see
+``masks.py``); a mask of ``None`` means "fully shared leaf" (bias, norm
+scale, full weight).
 """
 from __future__ import annotations
-
-from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 
 Array = jax.Array
-PyTree = Any
 
 _EPS = 1e-12
 
@@ -80,30 +78,3 @@ def rbla_leaf(stacked: Array, mask: Array | None, weights: Array,
                 else prev.astype(jnp.float32))
     return jnp.where(den > 0, num / (den + _EPS),
                      fallback).astype(stacked.dtype)
-
-
-AGGREGATORS: dict[str, Callable[..., Array]] = {
-    "rbla": rbla_leaf,
-    "zeropad": zeropad_leaf,
-}
-
-
-def aggregate(stacked_tree: PyTree, mask_tree: PyTree, weights: Array,
-              method: str = "rbla", prev_tree: PyTree | None = None
-              ) -> PyTree:
-    """Aggregate a pytree of stacked client leaves.
-
-    Deprecated shim: resolves ``method`` through the strategy registry
-    (``repro.core.strategy``) and runs its reference tree path.  New code
-    should call ``get_strategy(method).aggregate_tree(...)`` directly.
-
-    ``stacked_tree`` leaves are ``(n_clients, *shape)``; ``mask_tree`` has
-    the same structure with leaves that broadcast against them (or ``None``
-    for fully-shared leaves -- encode None as a 0-d ones array if the tree
-    library would prune it).  ``prev_tree``: the server's current values,
-    retained (by strategies that keep them, e.g. rbla) for rows no
-    participant owns.
-    """
-    from .strategy import get_strategy
-    return get_strategy(method).aggregate_tree(stacked_tree, mask_tree,
-                                               weights, prev_tree)

@@ -30,9 +30,10 @@ This module turns a round into a **compiled plan**:
    automatically and falls back to the per-leaf reference path only when
    the cohort cannot be described host-side (traced values, bare leaves).
 
-The per-leaf ``aggregate_tree*`` methods remain as the plan's oracles:
-every packed plan must reproduce them allclose (see ``tests/test_plan.py``
-and the parity/property suites, which now exercise plans end to end).
+The per-leaf ``aggregate_tree`` is the float32 reference: every packed
+plan must reproduce it allclose (see ``tests/test_plan.py`` and the
+parity/property suites, which exercise plans end to end), and it runs the
+rounds a plan refuses.
 """
 from __future__ import annotations
 
@@ -572,9 +573,8 @@ class CompiledRound:
     Attributes the benchmarks and tests read:
 
     ``kind``
-        "packed" (fused buckets), "jit" (whole-round jit over the
-        reference math), or "eager" (legacy per-leaf execution --
-        unknown strategies and paths with their own caching).
+        "packed" (fused buckets) or "eager" (the per-leaf reference
+        math -- unknown strategies and paths with their own caching).
     ``n_kernel_launches``
         fused device computations issued per round (packed plans:
         #buckets; others: best-effort 1 / None).
@@ -585,8 +585,7 @@ class CompiledRound:
         compiled Pallas kernels among those launches (0 on the ref and
         distributed backends and wherever a pallas plan runs the XLA
         lowering instead -- interpret mode, unaligned stack copies, mixed
-        codec means; None where the plan cannot tell: eager and jit
-        rounds).
+        codec means; None where the plan cannot tell: eager rounds).
     ``input_shardings``
         ``(shape, sharding)`` of each packed client buffer the last call
         handed to its collective (distributed plans; None elsewhere) --
@@ -1422,9 +1421,9 @@ def _build_svd_round(strategy, spec: CohortSpec) -> CompiledRound:
     """svd's packed lowering: pairs bucket by (shape, dtype) and each
     bucket runs ONE batched factored SVD (``repro.core.lowrank``) inside
     a single jitted round -- same CompiledRound contract as the mean and
-    stack modes.  The per-pair dense O(m*n*min(m,n)) SVDs the jit mode
-    used to issue become O((m+n)*k^2 + k^3) QR/core work, vmapped across
-    the bucket's same-shape pairs, with no dense delta materialized.
+    stack modes.  Per-pair dense O(m*n*min(m,n)) SVDs become
+    O((m+n)*k^2 + k^3) QR/core work, vmapped across the bucket's
+    same-shape pairs, with no dense delta materialized.
 
     Scales (``r_out / rank_i``) enter as runtime data, so -- like the
     mean mode -- one compiled executor serves every rank multiset with
@@ -1511,93 +1510,23 @@ def _build_svd_round(strategy, spec: CohortSpec) -> CompiledRound:
                          n_pallas_launches=0)
 
 
-# ----------------------------------------------------------- legacy plans --
-def _build_jit_round(strategy, spec: CohortSpec) -> CompiledRound:
-    """Whole-round jit over the strategy's reference tree path: ranks and
-    the cohort layout are closed over as constants, so host dispatch is
-    one call per round even where no packed kernel applies (svd's
-    per-pair SVDs, flora's ref backend)."""
-    retains = strategy.retains_prev and spec.has_prev
-    cr = spec.client_ranks_array()
-    rank_consts = [jnp.asarray(m.rank_values().astype(np.int32))
-                   for m in spec.pairs]
-    prev_rank_consts = [
-        None if m.prev_ranks is None
-        else jnp.asarray(m.prev_rank_values().astype(np.int32))
-        for m in spec.pairs]
-    rebuild = [None]
-
-    def round_fn(ab, wt, prev_ab):
-        from repro.lora import pair_masks
-        pairs = [{"A": p["A"], "B": p["B"], "rank": rank_consts[i]}
-                 for i, p in enumerate(ab)]
-        stacked = rebuild[0](pairs)
-        prev = None
-        if retains:
-            prev = rebuild[0](
-                [{"A": p["A"], "B": p["B"], "rank": prev_rank_consts[i]}
-                 for i, p in enumerate(prev_ab)])
-        if spec.kind == "pallas":
-            out = strategy.aggregate_tree_pallas(
-                stacked, wt, cr, prev, r_max=spec.r_max,
-                interpret=spec.interpret)
-        else:
-            masks = _map_pairs_like(pair_masks, stacked)
-            out = strategy.aggregate_tree(stacked, masks, wt, prev,
-                                          r_max=spec.r_max,
-                                          client_ranks=cr)
-        return [{"A": p["A"], "B": p["B"], "rank": p["rank"]}
-                for _, p in _walk_pairs(out)]
-
-    fn = jax.jit(round_fn)
-    fn_donate = jax.jit(round_fn, donate_argnums=(2,))
-
-    def execute(stacked_tree, w, prev_tree, donate):
-        if rebuild[0] is None:
-            rebuild[0] = _make_rebuilder(stacked_tree)
-        ab = _ab_list(stacked_tree)
-        prev_ab = _ab_list(prev_tree) if retains else None
-        run = fn_donate if (donate and retains) else fn
-        outs = run(ab, w, prev_ab)
-        out_tree = rebuild[0](
-            [{"A": o["A"], "B": o["B"], "rank": o["rank"]} for o in outs])
-        return strategy.finalize_tree(out_tree, spec.r_max)
-
-    return CompiledRound(strategy, spec, "jit", execute,
-                         n_kernel_launches=1)
-
-
-def _map_pairs_like(fn, tree):
-    if _is_pair(tree):
-        return fn(tree)
-    if isinstance(tree, Mapping):
-        return {k: _map_pairs_like(fn, v) for k, v in tree.items()}
-    if isinstance(tree, (tuple, list)):
-        return type(tree)(_map_pairs_like(fn, v) for v in tree)
-    return tree
-
-
+# ------------------------------------------------------------ eager plans --
 def _build_eager_round(strategy, spec: CohortSpec) -> CompiledRound:
-    """No-compilation wrapper: exactly the pre-plan execution (unknown
-    strategies whose leaf math we cannot assume, and paths that keep
-    their own caches, e.g. flora's ragged-concat distributed round)."""
+    """No-compilation wrapper over the reference math (unknown strategies
+    whose leaf math we cannot assume, and paths that keep their own
+    caches, e.g. flora's ragged-concat distributed round)."""
     cr = spec.client_ranks_array()
 
     def execute(stacked_tree, w, prev_tree, donate):
-        from repro.lora import pair_masks
+        from repro.lora import adapter_masks
         prev = prev_tree if strategy.retains_prev else None
-        if spec.kind == "pallas":
-            out = strategy.aggregate_tree_pallas(
-                stacked_tree, w, cr, prev, r_max=spec.r_max,
-                interpret=spec.interpret)
-        elif spec.kind == "distributed":
-            masks = _map_pairs_like(pair_masks, stacked_tree)
+        masks = adapter_masks(stacked_tree)
+        if spec.kind == "distributed":
             out = strategy.aggregate_tree_distributed(
                 stacked_tree, masks, w, prev, r_max=spec.r_max,
                 client_ranks=cr, mesh=spec.mesh,
                 client_axis=spec.client_axis)
         else:
-            masks = _map_pairs_like(pair_masks, stacked_tree)
             out = strategy.aggregate_tree(stacked_tree, masks, w, prev,
                                           r_max=spec.r_max,
                                           client_ranks=cr)
@@ -1623,15 +1552,14 @@ def build_plan(strategy, spec: CohortSpec) -> CompiledRound:
       one batched QR-core-SVD per same-shape pair bucket on ref and
       pallas; the gathered-factor collective (its own cache) when
       distributed;
-    * ``"jit"`` -- whole-round jit of the reference math;
-    * ``None`` -- eager legacy execution (registered strategies we know
-      nothing about).
+    * ``None`` -- eager reference execution (registered strategies we
+      know nothing about).
     """
     mode = getattr(strategy, "plan_mode", None)
     if spec.codecs is not None and (mode not in ("mean", "mean_norm")
                                     or spec.kind == "distributed"):
         # per-client cohorts lower through the packed mean family only;
-        # the caller decodes and stacks for stack/svd/jit/eager/distributed
+        # the caller decodes and stacks for stack/svd/eager/distributed
         raise PlanUnavailable(
             "per-client cohorts plan only on the mean family")
     try:
@@ -1649,16 +1577,13 @@ def build_plan(strategy, spec: CohortSpec) -> CompiledRound:
             if spec.kind in ("pallas", "ref"):
                 # both lower to the packed copy/scale round; the ref kind
                 # (and interpret-mode pallas) uses the fused XLA stacking
-                # instead of the kernel -- the whole-round jit of the
-                # per-pair reference math measured *slower* than legacy
+                # instead of the kernel
                 return _build_stack_round(strategy, spec)
             return _build_eager_round(strategy, spec)
         if mode == "svd":
             if spec.kind == "distributed":
                 return _build_eager_round(strategy, spec)
             return _build_svd_round(strategy, spec)
-        if mode == "jit" and spec.kind == "ref":
-            return _build_jit_round(strategy, spec)
     except PlanUnavailable:
         if spec.codecs is not None:
             # the eager round expects a stacked tree -- propagate so the

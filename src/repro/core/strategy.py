@@ -11,14 +11,16 @@ makes each method a single :class:`AggregationStrategy` that owns
 * (c) an optional **distributed** shard_map path
   (:meth:`AggregationStrategy.make_distributed_aggregator` /
   :meth:`AggregationStrategy.allreduce_leaf`),
-* (d) an optional **Pallas kernel** path
-  (:meth:`AggregationStrategy.aggregate_tree_pallas`), and
+* (d) its **compiled plan** (:meth:`AggregationStrategy.plan`, lowered
+  by ``repro.core.plan`` per ``plan_mode``), and
 * (e) a **per-update fold** for the async aggregation service
   (:meth:`AggregationStrategy.fold` + the ``supports_incremental``
   declaration; see ``repro.fl.async_agg`` and ``docs/async.md``),
 
 behind a ``backend="auto" | "ref" | "pallas" | "distributed"`` selector that
-picks the Pallas kernel on TPU/GPU and the jnp reference path on CPU.
+picks the Pallas kernels on TPU/GPU and the XLA lowering on CPU.  The plan
+lowers every backend; the per-leaf :meth:`AggregationStrategy.aggregate_tree`
+is the float32 reference it is tested against.
 
 Registering a new method is one class::
 
@@ -314,9 +316,10 @@ class AggregationStrategy:
     """One server-side aggregation method, every execution path.
 
     Subclasses set the class attributes and implement :meth:`leaf` (or
-    override :meth:`aggregate_tree` for pair-structured methods); the
-    distributed and Pallas paths come for free from ``norm_by`` /
-    ``use_mask`` unless overridden.
+    override :meth:`aggregate_tree` for pair-structured methods).  The
+    compiled plan (``plan_mode``) lowers every backend from ``norm_by`` /
+    ``use_mask``; :meth:`aggregate_tree` is the float32 reference, and
+    runs a round only where the plan refuses the cohort.
     """
     name: str = ""
     aliases: tuple[str, ...] = ()
@@ -329,8 +332,6 @@ class AggregationStrategy:
     retains_prev: bool = False
     supports_pallas: bool = False
     supports_distributed: bool = True
-    #: method name understood by the rbla_agg Pallas kernel
-    pallas_method: str = "rbla"
     #: declared output-rank contract: "fixed" = the aggregate's live rank
     #: is always r_max (the registry's historical assumption); "stacked" =
     #: the live rank varies with the cohort (e.g. flora) and callers must
@@ -353,9 +354,9 @@ class AggregationStrategy:
     #: how :meth:`plan` lowers a round (see ``repro.core.plan``):
     #: "mean" = packed masked-mean buckets, "mean_norm" = + per-row norm
     #: restore, "stack" = flora's copy/scale stacking, "svd" = packed
-    #: batched factored SVD (repro.core.lowrank), "jit" = whole-round
-    #: jit of the reference math, None = eager legacy execution (the safe
-    #: default for strategies whose leaf math the planner cannot assume)
+    #: batched factored SVD (repro.core.lowrank), None = eager reference
+    #: execution (the safe default for strategies whose leaf math the
+    #: planner cannot assume)
     plan_mode: str | None = None
     #: Byzantine-robustness contract: "none" = plain (weighted-mean
     #: family, a single adversarial upload can move the aggregate
@@ -411,8 +412,8 @@ class AggregationStrategy:
         runtime data), their host-side mask matrices should not
         accumulate forever.  ``state`` may carry the server state whose
         adapters the round retains; the spec already encodes its layout,
-        so ``None`` is accepted.  Unsupported backends raise the same
-        ``NotImplementedError`` the per-leaf paths raise.
+        so ``None`` is accepted.  Unsupported backends raise
+        ``NotImplementedError``.
         """
         from .plan import build_plan
         if cohort_spec.kind == "pallas" and not self.supports_pallas:
@@ -447,7 +448,7 @@ class AggregationStrategy:
                     mesh, client_axis, interpret):
         """Best-effort plan for an already-stacked cohort; ``None`` when
         the cohort cannot be described host-side (traced leaves, bare
-        leaves) -- the caller then runs the in-trace legacy path."""
+        leaves) -- the caller then runs the reference path in the trace."""
         from .plan import PlanUnavailable, build_cohort_spec
         try:
             with span("round.spec"):
@@ -605,60 +606,6 @@ class AggregationStrategy:
         cache[(mesh, client_axis)] = fn
         return fn
 
-    # --------------------------------------------------- (d) Pallas path --
-    def aggregate_tree_pallas(self, stacked_tree: PyTree, weights: Array,
-                              client_ranks: Array | None,
-                              prev_tree: PyTree | None = None, *,
-                              r_max: int | None = None,
-                              interpret: bool | None = None) -> PyTree:
-        """Kernel path over an adapter tree of stacked LoRA pairs.
-
-        A leaves (n, r_max, fan_in) hit the kernel directly; B leaves
-        (n, fan_out, r_max) via a rank-axis transpose.  Layer-stacked pairs
-        (leading dims / per-layer rank vectors) fall back to the reference
-        leaf math -- the kernel wants a single rank-row axis.  ``r_max``
-        is ignored by fixed-rank strategies (the caller's finalize resets
-        live ranks); rank-changing ones need it for their cap logic.
-        """
-        if not self.supports_pallas:
-            raise NotImplementedError(
-                f"strategy {self.name!r} has no Pallas kernel path; "
-                "use backend='ref'")
-        from repro.kernels.rbla_agg.ops import rbla_agg
-        from repro.lora import pair_masks
-
-        w = self.transform_weights(jnp.asarray(weights, jnp.float32),
-                                   client_ranks)
-        ranks = (None if client_ranks is None
-                 else jnp.asarray(client_ranks, jnp.int32))
-
-        def agg_pair(pair, prev_pair):
-            A, B = pair["A"], pair["B"]
-            r_storage = A.shape[-2]
-            n = A.shape[0]
-            pranks = ranks
-            if pranks is None and jnp.asarray(pair["rank"]).ndim == 1:
-                pranks = jnp.asarray(pair["rank"], jnp.int32)
-            if not self.use_mask:
-                pranks = jnp.full((n,), r_storage, jnp.int32)
-            if A.ndim != 3 or B.ndim != 3 or pranks is None:
-                masks = pair_masks(pair)       # works on stacked pairs
-                prev_A = prev_pair["A"] if prev_pair is not None else None
-                prev_B = prev_pair["B"] if prev_pair is not None else None
-                return {"A": self.leaf(A, masks["A"], w, prev_A),
-                        "B": self.leaf(B, masks["B"], w, prev_B),
-                        "rank": pair["rank"][0]}
-            outA = rbla_agg(A, pranks, w, method=self.pallas_method,
-                            interpret=interpret)
-            outB = rbla_agg(jnp.swapaxes(B, 1, 2), pranks, w,
-                            method=self.pallas_method, interpret=interpret).T
-            out = {"A": outA, "B": outB, "rank": pair["rank"][0]}
-            if prev_pair is not None and self.retains_prev:
-                out = _retain_prev(out, prev_pair, pranks)
-            return out
-
-        return _map_pairs(agg_pair, stacked_tree, prev_tree, strict=True)
-
     # ----------------------------------------------------- mid-level API --
     def aggregate_adapters(self, client_adapters: Sequence[PyTree],
                            weights: Array, *, r_max: int | None = None,
@@ -667,7 +614,6 @@ class AggregationStrategy:
                            backend: str = "auto", mesh=None,
                            client_axis: str = "clients",
                            interpret: bool | None = None,
-                           use_plan: bool = True,
                            donate: bool = False) -> PyTree:
         """Aggregate per-client adapter trees into the global adapter.
 
@@ -678,14 +624,15 @@ class AggregationStrategy:
         backends plans the per-client trees directly, plain or encoded
         (``repro.core.codec``): one jitted pack writes the client leaves
         straight into the bucket buffers, and the cohort is never
-        stacked.  Every other path -- distributed, the stack/svd/jit/
-        eager plans, cohorts the per-client walk cannot describe --
-        decodes and stacks the uploads first (``cohort_stacks_total``
-        counts those cohorts).  The per-leaf ``aggregate_tree*`` paths
-        remain the plans' oracles and the in-trace fallback
-        (``use_plan=False``, or leaves/ranks hidden by jit tracing).
-        ``donate=True`` donates ``prev_global``'s A/B buffers to the
-        round -- the caller must not touch them after.
+        stacked.  Every other path -- distributed, the stack/svd/eager
+        plans, cohorts the per-client walk cannot describe -- decodes and
+        stacks the uploads first (``cohort_stacks_total`` counts those
+        cohorts).  Where the plan refuses the cohort (leaves or ranks
+        hidden by jit tracing, bare leaves), the round runs the reference
+        :meth:`aggregate_tree` (or, distributed,
+        :meth:`aggregate_tree_distributed`).  ``donate=True`` donates
+        ``prev_global``'s A/B buffers to the round -- the caller must not
+        touch them after.
 
         Output rank bookkeeping follows :meth:`finalize_tree`: fixed-rank
         strategies reset the live rank to ``r_max`` (clients re-slice,
@@ -710,7 +657,7 @@ class AggregationStrategy:
             codecs = cohort_codecs(client_adapters)
             kind = resolve_backend(backend, self)
             prev = prev_global if self.retains_prev else None
-            if (use_plan and kind in ("ref", "pallas")
+            if (kind in ("ref", "pallas")
                     and getattr(self, "plan_mode", None) in ("mean",
                                                              "mean_norm")
                     and (codecs is None or "mixed" not in codecs)):
@@ -750,32 +697,22 @@ class AggregationStrategy:
             if client_ranks is None:
                 client_ranks = _infer_ranks(stacked)
             w = jnp.asarray(weights, jnp.float32)
-            if use_plan:
-                round_ = self._plan_round(
-                    stacked, kind, r_max=r_max, client_ranks=client_ranks,
-                    prev=prev, mesh=mesh, client_axis=client_axis,
-                    interpret=interpret)
-                if round_ is not None:
-                    return round_(stacked, w, prev, donate=donate)
-            if kind == "pallas":
-                out = self.aggregate_tree_pallas(
-                    stacked, w, client_ranks, prev, r_max=r_max,
-                    interpret=interpret)
+            round_ = self._plan_round(
+                stacked, kind, r_max=r_max, client_ranks=client_ranks,
+                prev=prev, mesh=mesh, client_axis=client_axis,
+                interpret=interpret)
+            if round_ is not None:
+                return round_(stacked, w, prev, donate=donate)
+            masks = adapter_masks(stacked)
+            if kind == "distributed":
+                out = self.aggregate_tree_distributed(
+                    stacked, masks, w, prev, r_max=r_max,
+                    client_ranks=client_ranks, mesh=mesh,
+                    client_axis=client_axis)
             else:
-                # the kernel path derives masks from ranks; only the
-                # jnp/psum paths need the materialized delta_{i,r} mask
-                # tree
-                masks = stack_trees([adapter_masks(a)
-                                     for a in client_adapters])
-                if kind == "distributed":
-                    out = self.aggregate_tree_distributed(
-                        stacked, masks, w, prev, r_max=r_max,
-                        client_ranks=client_ranks, mesh=mesh,
-                        client_axis=client_axis)
-                else:
-                    out = self.aggregate_tree(stacked, masks, w, prev,
-                                              r_max=r_max,
-                                              client_ranks=client_ranks)
+                out = self.aggregate_tree(stacked, masks, w, prev,
+                                          r_max=r_max,
+                                          client_ranks=client_ranks)
             return self.finalize_tree(out, r_max)
 
     def finalize_tree(self, out: PyTree, r_max: int | None) -> PyTree:
@@ -950,7 +887,6 @@ class FedAvgStrategy(AggregationStrategy):
     norm_by = "weight"
     use_mask = False
     supports_pallas = True
-    pallas_method = "zeropad"          # full-rank masks => weighted mean
     # the default fold IS the exact streaming form of a weighted mean
     supports_incremental = True
     plan_mode = "mean"
@@ -966,7 +902,6 @@ class ZeropadStrategy(AggregationStrategy):
     name = "zeropad"
     norm_by = "weight"
     supports_pallas = True
-    pallas_method = "zeropad"
     plan_mode = "mean"
     # zeropad = weighted mean of masked uploads, so the default fold's
     # running mix streams it exactly (a single-element aggregate is the
@@ -985,7 +920,6 @@ class RBLAStrategy(AggregationStrategy):
     norm_by = "mask"
     retains_prev = True
     supports_pallas = True
-    pallas_method = "rbla"
     supports_incremental = True
     plan_mode = "mean"
 
@@ -1212,41 +1146,6 @@ class RBLANormStrategy(AggregationStrategy):
             }
         return _map_pairs(agg_pair, stacked_tree, mask_tree, strict=True)
 
-    # --------------------------------------------------- (d) Pallas path --
-    def aggregate_tree_pallas(self, stacked_tree, weights, client_ranks,
-                              prev_tree=None, *, r_max=None,
-                              interpret=None):
-        """Kernel path: the masked mean *and* the per-row norm restore
-        fuse into one ``packed_agg(norm_restore=True)`` launch per side
-        (the compiled plan fuses all pairs into one launch per bucket);
-        the row-norm reduction keeps the whole row in one block."""
-        from repro.kernels.rbla_agg.ops import packed_agg
-        from .masks import stacked_rank_masks
-
-        w = jnp.asarray(weights, jnp.float32)
-        ranks = (None if client_ranks is None
-                 else jnp.asarray(client_ranks, jnp.int32))
-
-        def agg_pair(pair, _prev):
-            A, B = pair["A"], pair["B"]
-            pranks = ranks
-            if pranks is None and jnp.asarray(pair["rank"]).ndim == 1:
-                pranks = jnp.asarray(pair["rank"], jnp.int32)
-            if A.ndim != 3 or B.ndim != 3 or pranks is None:
-                raise NotImplementedError(
-                    "rbla_norm supports scalar-rank pairs (got "
-                    f"A.ndim={A.ndim}); the per-row norm target needs a "
-                    "per-layer loop for layer-stacked pairs")
-            masks = stacked_rank_masks(A.shape[-2], pranks)
-            outA = packed_agg(A, masks, w, norm_by="mask",
-                              norm_restore=True, interpret=interpret)
-            outB = packed_agg(jnp.swapaxes(B, 1, 2), masks, w,
-                              norm_by="mask", norm_restore=True,
-                              interpret=interpret).T
-            return {"A": outA.astype(A.dtype), "B": outB.astype(B.dtype),
-                    "rank": pair["rank"][0]}
-        return _map_pairs(agg_pair, stacked_tree, prev_tree, strict=True)
-
 
 class RobustRBLAStrategy(AggregationStrategy):
     """Byzantine-tolerant RBLA family (pair-structured): the masked
@@ -1298,63 +1197,39 @@ class RobustRBLAStrategy(AggregationStrategy):
         # defend; they keep the plain masked mean
         return rbla_leaf(stacked, mask, weights, prev)
 
-    def _robust_pair(self, agg, pair, prev_pair, w, ranks):
-        A, B = pair["A"], pair["B"]
-        pranks = ranks
-        if pranks is None and jnp.asarray(pair["rank"]).ndim == 1:
-            pranks = jnp.asarray(pair["rank"], jnp.int32)
-        if A.ndim != 3 or B.ndim != 3 or pranks is None:
-            raise NotImplementedError(
-                f"{self.name} supports scalar-rank pairs (got "
-                f"A.ndim={A.ndim}); layer-stacked pairs lower through "
-                "the compiled plan, which packs per-layer rows")
-        from .masks import stacked_rank_masks
-        masks = stacked_rank_masks(A.shape[-2], pranks)
-        pA = pB = None
-        if prev_pair is not None:
-            pA, pB = prev_pair["A"], prev_pair["B"].T
-        outA = agg(A, masks, w, pA)
-        outB = agg(jnp.swapaxes(B, 1, 2), masks, w, pB).T
-        return {"A": outA.astype(A.dtype), "B": outB.astype(B.dtype),
-                "rank": pair["rank"][0]}
-
     def aggregate_tree(self, stacked_tree, mask_tree, weights,
                        prev_tree=None, *, r_max=None, client_ranks=None):
         from repro.kernels.rbla_agg.ref import packed_robust_ref
+        from .masks import stacked_rank_masks
         w = jnp.asarray(weights, jnp.float32)
         ranks = (None if client_ranks is None
                  else jnp.asarray(client_ranks, jnp.int32))
 
-        def agg(x, masks, wt, prev):
-            return packed_robust_ref(x, masks, wt, prev,
+        def agg(x, masks, prev):
+            return packed_robust_ref(x, masks, w, prev,
                                      mode=self.robustness,
                                      clip_norm=self.clip_norm,
                                      trim_frac=self.trim_frac)
-        return _map_pairs(
-            lambda pair, prev_pair: self._robust_pair(agg, pair, prev_pair,
-                                                      w, ranks),
-            stacked_tree, prev_tree, strict=True)
 
-    # --------------------------------------------------- (d) Pallas path --
-    def aggregate_tree_pallas(self, stacked_tree, weights, client_ranks,
-                              prev_tree=None, *, r_max=None,
-                              interpret=None):
-        """Kernel path: one fused ``packed_robust`` launch per side (the
-        compiled plan fuses all pairs into one launch per bucket)."""
-        from repro.kernels.rbla_agg.ops import packed_robust
-        w = jnp.asarray(weights, jnp.float32)
-        ranks = (None if client_ranks is None
-                 else jnp.asarray(client_ranks, jnp.int32))
-
-        def agg(x, masks, wt, prev):
-            return packed_robust(x, masks, wt, prev, mode=self.robustness,
-                                 clip_norm=self.clip_norm,
-                                 trim_frac=self.trim_frac,
-                                 interpret=interpret)
-        return _map_pairs(
-            lambda pair, prev_pair: self._robust_pair(agg, pair, prev_pair,
-                                                      w, ranks),
-            stacked_tree, prev_tree, strict=True)
+        def agg_pair(pair, prev_pair):
+            A, B = pair["A"], pair["B"]
+            pranks = ranks
+            if pranks is None and jnp.asarray(pair["rank"]).ndim == 1:
+                pranks = jnp.asarray(pair["rank"], jnp.int32)
+            if A.ndim != 3 or B.ndim != 3 or pranks is None:
+                raise NotImplementedError(
+                    f"{self.name} supports scalar-rank pairs (got "
+                    f"A.ndim={A.ndim}); layer-stacked pairs lower through "
+                    "the compiled plan, which packs per-layer rows")
+            masks = stacked_rank_masks(A.shape[-2], pranks)
+            pA = pB = None
+            if prev_pair is not None:
+                pA, pB = prev_pair["A"], prev_pair["B"].T
+            outA = agg(A, masks, pA)
+            outB = agg(jnp.swapaxes(B, 1, 2), masks, pB).T
+            return {"A": outA.astype(A.dtype), "B": outB.astype(B.dtype),
+                    "rank": pair["rank"][0]}
+        return _map_pairs(agg_pair, stacked_tree, prev_tree, strict=True)
 
 
 @register_strategy
@@ -1443,17 +1318,6 @@ class SVDStrategy(AggregationStrategy):
                     "B": pad_to_rank(Bo.astype(B.dtype), -1, r_storage),
                     "rank": pair["rank"][0]}
         return _map_pairs(agg_pair, stacked_tree, mask_tree, strict=True)
-
-    # --------------------------------------------------- (d) Pallas path --
-    def aggregate_tree_pallas(self, stacked_tree, weights, client_ranks,
-                              prev_tree=None, *, r_max=None,
-                              interpret=None):
-        """The factored engine is matmul/QR-dominated: XLA's fused
-        matmuls are the accelerator path, so the kernel backend shares
-        the factored tree math (there is no reduction a hand-written
-        Pallas kernel would beat here)."""
-        return self.aggregate_tree(stacked_tree, None, weights, prev_tree,
-                                   r_max=r_max, client_ranks=client_ranks)
 
     # ---------------------------------------------- (c) distributed path --
     def make_distributed_aggregator(self, mesh, client_axis: str = "data"):
@@ -1940,7 +1804,7 @@ class FloraStrategy(AggregationStrategy):
         (gather-then-stack) and computes the stacked pair replicated; the
         concat offsets are static (host-known ranks) so the gathered
         layout compiles to plain slices."""
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
         w = jnp.asarray(weights, jnp.float32)
         ranks = self._concrete_ranks(
@@ -1986,71 +1850,6 @@ class FloraStrategy(AggregationStrategy):
         sh = NamedSharding(mesh, P(client_axis))
         return fn(jax.device_put(stacked_tree, sh),
                   jax.device_put(w, sh), prev_tree)
-
-    # --------------------------------------------------- (d) Pallas path --
-    def aggregate_tree_pallas(self, stacked_tree, weights, client_ranks,
-                              prev_tree=None, *, r_max=None,
-                              interpret=None):
-        """Kernel path: the stack is a pure copy/scale (no reduction), so
-        the ``flora_stack`` kernel places each contributor's live rows at
-        its static offset in one pass.  Layer-stacked (leading-dim) pairs
-        and over-cap cohorts (SVD re-projection) fall back to the
-        reference pair math."""
-        from repro.kernels.rbla_agg.ops import flora_stack
-
-        w = jnp.asarray(weights, jnp.float32)
-
-        def agg_pair(pair, prev_pair):
-            A, B = pair["A"], pair["B"]
-            ranks = self._pair_ranks(pair, client_ranks)
-            prev_rank = self._prev_rank_of(prev_pair)
-            pA = prev_pair["A"] if prev_pair is not None else None
-            pB = prev_pair["B"] if prev_pair is not None else None
-            cap = self.resolve_cap(r_max, r_storage=A.shape[-2])
-            self._validate_cap(cap, ranks, r_max)
-
-            has_prev = pA is not None and bool(prev_rank)
-            seg_ranks = [int(prev_rank)] if has_prev else []
-            live = [i for i in range(len(ranks)) if int(ranks[i]) > 0]
-            seg_ranks += [int(ranks[i]) for i in live]
-            r_total = int(sum(seg_ranks))
-            if A.ndim != 3 or B.ndim != 3 or r_total > cap:
-                # reference fallback: layer-stacked pairs / SVD reproject
-                A_out, B_out, r_out = self._stack_pair(
-                    A, B, ranks, w, pA, pB, prev_rank, r_max)
-                return {"A": A_out, "B": B_out,
-                        "rank": self._out_rank_leaf(pair["rank"], r_out)}
-
-            # uniform-storage contributor stacks (prev first, like ref);
-            # the kernel wants the rank axis leading, so B rides transposed
-            r_st = max(A.shape[-2], pA.shape[-2] if has_prev else 0)
-            keep = jnp.asarray(live, jnp.int32)
-            partsA = [pad_to_rank(A.astype(jnp.float32), -2, r_st)[keep]]
-            partsBt = [pad_to_rank(
-                jnp.swapaxes(B, 1, 2).astype(jnp.float32), -2, r_st)[keep]]
-            masses = [w[i] for i in live]
-            if has_prev:
-                partsA.insert(0, pad_to_rank(
-                    pA.astype(jnp.float32), -2, r_st)[None])
-                partsBt.insert(0, pad_to_rank(
-                    jnp.swapaxes(pB, 0, 1).astype(jnp.float32),
-                    -2, r_st)[None])
-                masses.insert(0, self.prev_weight * jnp.mean(w))
-            xA = jnp.concatenate(partsA, axis=0)
-            xBt = jnp.concatenate(partsBt, axis=0)
-            m = jnp.stack(masses)
-            mhat = m / (jnp.sum(m) + _EPS)
-            r_out = r_total
-            scales = mhat * (jnp.float32(r_out) /
-                             jnp.asarray(seg_ranks, jnp.float32))
-            segs = tuple(seg_ranks)
-            A_out = flora_stack(xA, jnp.ones_like(scales), segs=segs,
-                                out_rows=cap, interpret=interpret)
-            B_out = flora_stack(xBt, scales, segs=segs, out_rows=cap,
-                                interpret=interpret).T
-            return {"A": A_out.astype(A.dtype), "B": B_out.astype(B.dtype),
-                    "rank": self._out_rank_leaf(pair["rank"], r_out)}
-        return _map_pairs(agg_pair, stacked_tree, prev_tree, strict=True)
 
 
 __all__ = [

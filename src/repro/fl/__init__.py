@@ -7,13 +7,11 @@ from .client import (LocalFitResult, make_local_fit, merge_base_params,
 from .comm import BufferedUpdate, DedupWindow, RetryPolicy, UpdateBuffer
 from .durability import DurableAggregator, WriteAheadLog
 from .selection import ClientLatencyModel, select_clients
-from .server import aggregate_adapters, aggregate_base, stack_trees
 from .simulator import (AsyncFLConfig, FLConfig, FLHistory,
                         run_async_simulation, run_simulation)
 
 __all__ = ["LocalFitResult", "make_local_fit", "merge_base_params",
            "softmax_xent", "split_base_params", "select_clients",
-           "aggregate_adapters", "aggregate_base", "stack_trees",
            "FLConfig", "FLHistory", "run_simulation", "ClientUpdate",
            "ServerState", "get_strategy", "AsyncAggregator",
            "STALENESS_SCHEDULES", "make_staleness_fn", "AsyncFLConfig",

@@ -9,11 +9,9 @@ from .lora_matmul.ops import (batched_lora_matmul,
                               lora_matmul, lora_matmul_inline)
 from .lora_matmul.ref import (batched_lora_matmul_ref,
                               batched_lora_matmul_segments, lora_matmul_ref)
-from .rbla_agg.ops import (axpy_fold, flora_stack, packed_agg,
-                           packed_robust, packed_stack, rbla_agg)
-from .rbla_agg.ref import (axpy_fold_ref, flora_stack_ref, packed_agg_ref,
-                           packed_robust_ref, packed_robust_xla,
-                           packed_stack_ref, rbla_agg_ref)
+from .rbla_agg.ops import axpy_fold, packed_agg, packed_robust, packed_stack
+from .rbla_agg.ref import (axpy_fold_ref, packed_agg_ref, packed_robust_ref,
+                           packed_robust_xla, packed_stack_ref)
 from .ssd_scan.ops import ssd_scan
 from .ssd_scan.ref import ssd_scan_ref
 
@@ -21,8 +19,8 @@ __all__ = ["lora_dense_apply", "lora_matmul", "lora_matmul_inline",
            "lora_matmul_ref", "batched_lora_matmul",
            "batched_lora_matmul_inline", "batched_lora_matmul_ref",
            "batched_lora_matmul_segments",
-           "axpy_fold", "axpy_fold_ref", "flora_stack", "flora_stack_ref",
+           "axpy_fold", "axpy_fold_ref",
            "packed_agg", "packed_agg_ref", "packed_robust",
            "packed_robust_ref", "packed_robust_xla",
            "packed_stack", "packed_stack_ref",
-           "rbla_agg", "rbla_agg_ref", "ssd_scan", "ssd_scan_ref"]
+           "ssd_scan", "ssd_scan_ref"]

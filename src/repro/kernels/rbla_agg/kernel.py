@@ -1,21 +1,17 @@
-"""RBLA masked rank-row aggregation Pallas TPU kernel (paper Eq. 7).
+"""RBLA masked rank-row aggregation Pallas TPU kernels (paper Eq. 7).
 
-Given stacked client adapters x (N, R, D), ranks (N,), weights (N,):
+Given packed client rows x (N, R, D), per-row owner masks m (N, R) and
+weights w (N,), ``packed_agg`` computes
 
-    out[r, d] = sum_n w_n * [r < rank_n] * x[n, r, d]
-              / sum_n w_n * [r < rank_n]          (0 where no owner)
+    out[r, d] = sum_n w_n * m[n, r] * x[n, r, d]
+              / sum_n w_n * m[n, r]          (prev[r, d] where no owner)
 
 This is the server's hot loop: bandwidth-bound (reads N*R*D, writes R*D,
-O(1) flops per element).  One pass, fused mask generation from the rank
-vector (delta is never materialized in HBM -- the jnp reference builds an
-(N, R, 1) mask tensor; the kernel derives it from a VMEM iota).
+O(1) flops per element).  Grid (R/br, D/bd); the client axis is an
+in-kernel loop over VMEM blocks (N is the cohort size).
 
-Grid (R/br, D/bd); the client axis is an in-kernel loop over VMEM
-blocks (N is small: the cohort size).  Block (N, br, bd) of x streams
-through VMEM; ranks/weights ride along as (N,) f32 vectors.
-
-The compiled plan's packed kernels below (``packed_agg``,
-``packed_robust``, ``packed_stack``, ``axpy_fold``) keep every block legal
+The compiled plan's kernels (``packed_agg``, ``packed_robust``,
+``packed_stack``, ``axpy_fold``) keep every block legal
 for the TPU at published widths and cohorts up to a few hundred clients:
 per-row operands ride as (R, N) / (R, 1) columns, per-client scalars in
 SMEM, and one VMEM budget (:func:`_client_blocks`) sizes the client
@@ -34,28 +30,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BR = 128
 DEFAULT_BD = 512
-
-
-def _kernel(ranks_ref, weights_ref, x_ref, o_ref, *, n_clients: int,
-            norm_by: str):
-    br = x_ref.shape[1]
-    r0 = pl.program_id(0) * br
-    rows = r0 + jax.lax.broadcasted_iota(jnp.int32, (br, 1), 0)
-
-    num = jnp.zeros(o_ref.shape, jnp.float32)
-    den = jnp.zeros((br, 1), jnp.float32)
-    wtot = jnp.zeros((), jnp.float32)
-    for nix in range(n_clients):                     # static unroll
-        m = (rows < ranks_ref[nix]).astype(jnp.float32)       # (br, 1)
-        w = weights_ref[nix]
-        num = num + (w * m) * x_ref[nix].astype(jnp.float32)
-        den = den + w * m
-        wtot = wtot + w
-    if norm_by == "mask":       # rbla: owner weight-mass denominator
-        out = jnp.where(den > 0, num / jnp.where(den > 0, den, 1.0), 0.0)
-    else:                       # zeropad baseline: total weight mass
-        out = num / wtot
-    o_ref[...] = out.astype(o_ref.dtype)
 
 
 #: bytes of one streamed ``(n, br, bd)`` client block, counted at f32
@@ -99,7 +73,7 @@ def _packed_kernel(weights_ref, masks_ref, x_ref, *rest, n_clients: int,
     more rows), laid out rows-major so a row block's ``(br, N)`` tile is
     legal at any ``br``; ``weights``: (N,) in SMEM; optional ``prev``:
     (R, D) packed previous global, the fallback for rows no participant
-    owns.  One launch aggregates what the per-pair path spread over
+    owns.  One launch aggregates what a per-pair kernel would spread over
     2 x n_pairs launches.
 
     ``has_scales`` adds an (R, N) per-row scale operand (after ``x``,
@@ -184,10 +158,10 @@ def packed_agg_pallas(x, masks, weights, prev=None, *,
                       scales=None, out_dtype=None,
                       br=DEFAULT_BR, bd=DEFAULT_BD, interpret=True):
     """x: (N, R, D); masks: (N, R) f32; weights: (N,) f32; prev: (R, D)
-    or None -> (R, D).  The plan path's fused bucket reduction: like
-    :func:`rbla_agg_pallas` but with an explicit per-row owner-mask
-    matrix (packed rows span many pairs, so a single rank vector cannot
-    describe them) and prev-global retention fused in.  ``scales``:
+    or None -> (R, D).  The plan path's fused bucket reduction: an
+    explicit per-row owner-mask matrix (packed rows span many pairs, so
+    a single rank vector cannot describe them) with prev-global
+    retention fused in.  ``scales``:
     optional (N, R) f32 per-row dequantization scales fused on the load
     (int8 transport); ``out_dtype`` overrides the output dtype when ``x``
     is a wire dtype.
@@ -370,10 +344,10 @@ def packed_stack_pallas(x, scales, prev=None, *, copies_x=(),
                         copies_prev=(), out_rows: int, interpret=True):
     """x: (N, R_in, D); scales: (S,) f32; prev: (R_prev, D) or None ->
     (out_rows, D).  One launch stacks every packable pair of the cohort
-    (the plan path's flora bucket); :func:`flora_stack_pallas` remains
-    the single-pair form.  ``copies_x`` entries are ``(client, src_row,
-    dst_row, rows, scale_idx)``; ``copies_prev`` drop the client index
-    and read ``prev``; a later copy overwrites an earlier one's rows.
+    (the plan path's flora bucket).  ``copies_x`` entries are
+    ``(client, src_row, dst_row, rows, scale_idx)``; ``copies_prev`` drop
+    the client index and read ``prev``; a later copy overwrites an
+    earlier one's rows.
 
     The grid walks the output in row tiles (:func:`stack_row_tile`);
     compiled, every copy must start and end on ``x``'s row tiling."""
@@ -435,59 +409,6 @@ def packed_stack_pallas(x, scales, prev=None, *, copies_x=(),
     )(jnp.asarray(xblk), jnp.asarray(pblk), jnp.asarray(tag), *args)
 
 
-def _stack_kernel(scales_ref, x_ref, o_ref, *, segs, offs):
-    """FLoRA stacking: pure copy/scale, no reduction.
-
-    Each contributor ``i`` owns output rows [offs[i], offs[i]+segs[i]);
-    the segment layout is static (host-known ranks), so every placement
-    is a plain sliced store.  Rows beyond the stacked total stay zero.
-    """
-    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
-    for i, (r_i, off) in enumerate(zip(segs, offs)):
-        o_ref[off:off + r_i, :] = (
-            scales_ref[i] * x_ref[i, :r_i, :].astype(jnp.float32)
-        ).astype(o_ref.dtype)
-
-
-def flora_stack_pallas(x, scales, *, segs: tuple[int, ...], out_rows: int,
-                       bd=DEFAULT_BD, interpret=True):
-    """x: (N, R, D); scales: (N,) f32; segs: static per-contributor live
-    row counts -> (out_rows, D) with contributor i's rows at the running
-    offset, scaled.  ``out_rows >= sum(segs)`` (extra rows are zero).
-
-    Bandwidth-optimal for the stacking server: reads sum(segs)*D, writes
-    out_rows*D, zero flops beyond the scale multiply -- the rbla_agg
-    reduction kernel would burn N*R*D reads on what is a placement.
-    """
-    n, r, d = x.shape
-    if len(segs) != n:
-        raise ValueError(f"{len(segs)} segments for {n} contributors")
-    if any(s < 0 or s > r for s in segs):
-        raise ValueError(f"segment sizes {segs} outside [0, {r}]")
-    offs = []
-    tot = 0
-    for s in segs:
-        offs.append(tot)
-        tot += int(s)
-    if tot > out_rows:
-        raise ValueError(f"stacked rows {tot} exceed out_rows={out_rows}")
-    bd = min(bd, d)
-    grid = (pl.cdiv(d, bd),)
-    return pl.pallas_call(
-        functools.partial(_stack_kernel, segs=tuple(int(s) for s in segs),
-                          offs=tuple(offs)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n,), lambda j: (0,)),
-            pl.BlockSpec((n, r, bd), lambda j: (0, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((out_rows, bd), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((out_rows, d), x.dtype),
-        interpret=interpret,
-        name="flora_stack",
-    )(scales.astype(jnp.float32), x)
-
-
 def _axpy_kernel(alpha_ref, x_ref, y_ref, o_ref):
     """Staleness-weighted fold: o = y + alpha_row * (x - y).
 
@@ -507,7 +428,7 @@ def axpy_fold_pallas(y, x, alpha, *, br=DEFAULT_BR, bd=DEFAULT_BD,
     """y, x: (R, D); alpha: (R,) f32 -> (R, D) = y + alpha[:, None]*(x-y).
 
     The async server's hot loop: one arriving client update folded into
-    the live global in a single pass.  Bandwidth-bound like ``rbla_agg``
+    the live global in a single pass.  Bandwidth-bound like ``packed_agg``
     but reads 2*R*D and writes R*D with no client axis at all -- the
     per-update cost of fully-async aggregation is independent of the
     cohort size.
@@ -532,28 +453,3 @@ def axpy_fold_pallas(y, x, alpha, *, br=DEFAULT_BR, bd=DEFAULT_BD,
         interpret=interpret,
         name="axpy_fold",
     )(alpha.astype(jnp.float32).reshape(r, 1), x, y)
-
-
-def rbla_agg_pallas(x, ranks, weights, *, norm_by: str = "mask",
-                    br=DEFAULT_BR, bd=DEFAULT_BD, interpret=True):
-    """x: (N, R, D); ranks: (N,) int32; weights: (N,) f32 -> (R, D).
-
-    ``norm_by``: "mask" divides by the owners' weight mass (RBLA Eq. 7);
-    "weight" divides by the total mass (zero-padding dilution / FedAvg).
-    """
-    n, r, d = x.shape
-    br, bd = min(br, r), min(bd, d)
-    grid = (pl.cdiv(r, br), pl.cdiv(d, bd))
-    return pl.pallas_call(
-        functools.partial(_kernel, n_clients=n, norm_by=norm_by),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n,), lambda i, j: (0,)),
-            pl.BlockSpec((n,), lambda i, j: (0,)),
-            pl.BlockSpec((n, br, bd), lambda i, j: (0, i, j)),
-        ],
-        out_specs=pl.BlockSpec((br, bd), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((r, d), x.dtype),
-        interpret=interpret,
-        name="rbla_agg",
-    )(ranks, weights.astype(jnp.float32), x)

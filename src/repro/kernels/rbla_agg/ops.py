@@ -2,10 +2,10 @@
 
 Two tiers:
 
-* the public entry points (``rbla_agg``, ``flora_stack``, ``axpy_fold``,
-  ``packed_agg``, ``packed_stack``) are jitted and **count as one tracked
-  dispatch each** (``repro.core.plan.dispatch_counter``) -- they are the
-  per-pair legacy path the aggregation benchmarks compare against;
+* the public entry points (``axpy_fold``, ``packed_agg``,
+  ``packed_robust``, ``packed_stack``) are jitted and **count as one
+  tracked dispatch each** (``repro.core.plan.dispatch_counter``) -- for
+  standalone use and the kernel tests;
 * the ``*_inline`` variants run un-jitted for use *inside* an already
   compiled plan round (``repro.core.plan``), where a whole FL round is a
   single traced function and extra jit layers would only add overhead.
@@ -18,63 +18,15 @@ import jax
 import jax.numpy as jnp
 
 from ..runtime import auto_interpret, count_dispatch, note_trace
-from .kernel import (_sublanes, axpy_fold_pallas, flora_stack_pallas,
-                     packed_agg_pallas, packed_robust_pallas,
-                     packed_stack_pallas, rbla_agg_pallas, stack_row_tile)
-from .ref import (axpy_fold_ref, flora_stack_ref, packed_agg_ref,
-                  packed_robust_ref, packed_stack_ref, rbla_agg_ref)
+from .kernel import (_sublanes, axpy_fold_pallas, packed_agg_pallas,
+                     packed_robust_pallas, packed_stack_pallas,
+                     stack_row_tile)
+from .ref import (axpy_fold_ref, packed_agg_ref, packed_robust_ref,
+                  packed_stack_ref)
 
 
 def _pad_to(v: int, mult: int) -> int:
     return (v + mult - 1) // mult * mult
-
-
-#: legacy method names -> the kernel's two normalization modes.  FedAvg at
-#: kernel level is zeropad with full-rank masks (see FedAvgStrategy).
-_NORM_BY = {"rbla": "mask", "zeropad": "weight"}
-
-
-def rbla_agg_inline(x, ranks, weights, *, method: str = "rbla",
-                    interpret=None):
-    """Un-jitted :func:`rbla_agg` body (for use inside compiled plans)."""
-    interpret = auto_interpret(interpret)
-    try:
-        norm_by = _NORM_BY[method]
-    except KeyError:
-        raise ValueError(f"unknown kernel method {method!r}; options: "
-                         f"{sorted(_NORM_BY)}") from None
-    n, r = x.shape[:2]
-    lead = x.shape[2:]
-    d = 1
-    for v in lead:
-        d *= v
-    x2 = x.reshape(n, r, d)
-    rp, dp = _pad_to(r, 8), _pad_to(d, 128)
-    x2 = jnp.pad(x2, ((0, 0), (0, rp - r), (0, dp - d)))
-    out = rbla_agg_pallas(x2, jnp.asarray(ranks, jnp.int32),
-                          jnp.asarray(weights, jnp.float32),
-                          norm_by=norm_by, interpret=interpret)
-    return out[:r, :d].reshape((r,) + lead)
-
-
-@functools.partial(jax.jit, static_argnames=("method", "interpret"))
-def _rbla_agg_jit(x, ranks, weights, *, method, interpret):
-    note_trace("rbla_agg")
-    return rbla_agg_inline(x, ranks, weights, method=method,
-                           interpret=interpret)
-
-
-def rbla_agg(x, ranks, weights, *, method: str = "rbla", interpret=None):
-    """Aggregate stacked client tensors (N, R, *dims) with rank-row masks.
-
-    Trailing dims are flattened into D; padding rows/cols are masked out of
-    the result.  Matches ``repro.core.rbla_leaf`` semantics.
-    ``interpret=None`` auto-detects: compiled on TPU/GPU, interpreter on
-    CPU.
-    """
-    count_dispatch(kernel="rbla_agg")
-    return _rbla_agg_jit(x, ranks, weights, method=method,
-                         interpret=interpret)
 
 
 def packed_agg_inline(x, masks, weights, prev=None, *,
@@ -265,49 +217,6 @@ def packed_stack(x, scales, prev=None, *, copies_x=(), copies_prev=(),
                              out_rows=out_rows, interpret=interpret)
 
 
-def flora_stack_inline(x, scales, *, segs: tuple[int, ...], out_rows: int,
-                       interpret=None):
-    """Un-jitted :func:`flora_stack` body."""
-    interpret = auto_interpret(interpret)
-    n, r = x.shape[:2]
-    lead = x.shape[2:]
-    d = 1
-    for v in lead:
-        d *= v
-    x2 = x.reshape(n, r, d)
-    rp, dp = _pad_to(max(r, 1), 8), _pad_to(d, 128)
-    op = _pad_to(max(out_rows, 1), 8)
-    x2 = jnp.pad(x2, ((0, 0), (0, rp - r), (0, dp - d)))
-    out = flora_stack_pallas(x2, jnp.asarray(scales, jnp.float32),
-                             segs=segs, out_rows=op, interpret=interpret)
-    return out[:out_rows, :d].reshape((out_rows,) + lead)
-
-
-@functools.partial(jax.jit, static_argnames=("segs", "out_rows",
-                                             "interpret"))
-def _flora_stack_jit(x, scales, *, segs, out_rows, interpret):
-    note_trace("flora_stack")
-    return flora_stack_inline(x, scales, segs=segs, out_rows=out_rows,
-                              interpret=interpret)
-
-
-def flora_stack(x, scales, *, segs: tuple[int, ...], out_rows: int,
-                interpret=None):
-    """Stack contributors' leading rank rows (FLoRA aggregation):
-
-        out[off_i : off_i + segs[i]] = scales[i] * x[i, :segs[i]]
-
-    with ``off_i`` the running sum of ``segs`` -- a pure copy/scale, no
-    reduction.  x: (N, R, *dims); trailing dims are flattened into D and
-    restored; lane/sublane padding is stripped from the result.  ``segs``
-    must be static (the output layout depends on them); recompiles per
-    distinct cohort rank multiset.
-    """
-    count_dispatch(kernel="flora_stack")
-    return _flora_stack_jit(x, scales, segs=segs, out_rows=out_rows,
-                            interpret=interpret)
-
-
 def axpy_fold_inline(y, x, alpha, *, interpret=None, sr_key=None):
     """Un-jitted :func:`axpy_fold` body (for use inside compiled plans --
     the packed per-update fold runs one of these per bucket).
@@ -369,10 +278,8 @@ def axpy_fold(y, x, alpha, *, interpret=None, sr_key=None):
     return _axpy_fold_jit(y, x, alpha, sr_key, interpret=interpret)
 
 
-__all__ = ["rbla_agg", "rbla_agg_ref", "flora_stack", "flora_stack_ref",
-           "axpy_fold", "axpy_fold_ref", "packed_agg", "packed_agg_ref",
+__all__ = ["axpy_fold", "axpy_fold_ref", "packed_agg", "packed_agg_ref",
            "packed_robust", "packed_robust_ref", "packed_stack",
-           "packed_stack_ref", "rbla_agg_inline", "packed_agg_inline",
-           "packed_robust_inline", "packed_stack_inline",
-           "packed_stack_compiles", "flora_stack_inline",
+           "packed_stack_ref", "packed_agg_inline", "packed_robust_inline",
+           "packed_stack_inline", "packed_stack_compiles",
            "axpy_fold_inline"]

@@ -1,12 +1,8 @@
-"""Pure-jnp oracle for the RBLA aggregation kernel (reuses the core
-implementation -- the kernel must agree with the paper's Eq. 7 exactly)."""
+"""Pure-jnp oracles for the RBLA aggregation kernels (the packed forms of
+the paper's Eq. 7 that ``repro.core``'s leaf math defines)."""
 from __future__ import annotations
 
 import jax.numpy as jnp
-
-from repro.core import rbla_leaf, stacked_rank_masks, zeropad_leaf
-
-_REF_FNS = {"rbla": rbla_leaf, "zeropad": zeropad_leaf}
 
 
 def axpy_fold_ref(y, x, alpha):
@@ -17,16 +13,6 @@ def axpy_fold_ref(y, x, alpha):
         a = a.reshape((y.shape[0],) + (1,) * (y.ndim - 1))
     yf = y.astype(jnp.float32)
     return (yf + a * (x.astype(jnp.float32) - yf)).astype(y.dtype)
-
-
-def flora_stack_ref(x, scales, segs, out_rows: int):
-    """Oracle for the FLoRA stacking kernel: x (N, R, D), scales (N,),
-    static segs -> (out_rows, D) ragged concat of scaled leading rows."""
-    parts = [scales[i] * x[i, :int(s)].astype(jnp.float32)
-             for i, s in enumerate(segs)]
-    stacked = jnp.concatenate(parts, axis=0)
-    pad = out_rows - stacked.shape[0]
-    return jnp.pad(stacked, ((0, pad), (0, 0))).astype(x.dtype)
 
 
 def packed_agg_ref(x, masks, weights, prev=None, norm_by: str = "mask",
@@ -213,14 +199,3 @@ def packed_robust_xla(x, masks, weights, prev=None, *, mode: str,
                   for j in range(n)) / cnt
     out = jnp.where(c > 0, out, fb)
     return out.astype(out_dtype or x.dtype)
-
-
-def rbla_agg_ref(x, ranks, weights, method: str = "rbla"):
-    """x: (N, R, D); ranks: (N,); weights: (N,) -> (R, D)."""
-    try:
-        fn = _REF_FNS[method]
-    except KeyError:
-        raise ValueError(f"unknown kernel method {method!r}; options: "
-                         f"{sorted(_REF_FNS)}") from None
-    masks = stacked_rank_masks(x.shape[1], ranks)[:, :, None]
-    return fn(x, masks, weights)

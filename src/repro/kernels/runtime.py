@@ -98,9 +98,9 @@ def auto_interpret(interpret: bool | None) -> bool:
 
 def bench_env() -> dict:
     """The environment header every machine-readable benchmark emits
-    (``BENCH_agg.json``, ``BENCH_serve.json``): enough to tell whether
-    two committed runs are comparable -- jax version, device kind, and
-    whether Pallas kernels ran compiled or in interpreter mode."""
+    (``BENCH_serve.json``): enough to tell whether two committed runs are
+    comparable -- jax version, device kind, and whether Pallas kernels
+    ran compiled or in interpreter mode."""
     dev = jax.devices()[0]
     return {
         "jax_version": jax.__version__,

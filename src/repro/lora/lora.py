@@ -131,7 +131,8 @@ def tree_map_pairs(fn: Callable[[Mapping], Any], tree: PyTree) -> PyTree:
 
 
 def adapter_masks(adapters: PyTree) -> PyTree:
-    """Mask tree (same structure) for ``repro.core.aggregate``."""
+    """Mask tree (same structure) for
+    ``AggregationStrategy.aggregate_tree``."""
     return tree_map_pairs(pair_masks, adapters)
 
 

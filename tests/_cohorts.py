@@ -58,6 +58,25 @@ def mixed_codec_cohort(n=5, seed=0, codecs=None, **kw):
     return encoded, decoded, ranks, weights, codecs
 
 
+def reference_round(strategy, uploads, w, *, r_max, client_ranks,
+                    prev=None):
+    """One round on the float32 reference path: decode the uploads, stack
+    them, build the stacked mask tree (``adapter_masks``) and run the
+    strategy's per-leaf ``aggregate_tree`` + ``finalize_tree`` -- the
+    oracle every compiled plan must reproduce."""
+    from repro.core import codec, get_strategy, stack_trees
+    from repro.lora import adapter_masks
+    s = get_strategy(strategy)
+    if codec.cohort_codecs(uploads) is not None:
+        uploads = [codec.decode_adapters(a) for a in uploads]
+    stacked = stack_trees(uploads)
+    out = s.aggregate_tree(stacked, adapter_masks(stacked),
+                           jnp.asarray(w, jnp.float32),
+                           prev if s.retains_prev else None, r_max=r_max,
+                           client_ranks=client_ranks)
+    return s.finalize_tree(out, r_max)
+
+
 def assert_trees_close(a, b, rtol=1e-4, atol=1e-5, msg=""):
     la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
     assert len(la) == len(lb), msg

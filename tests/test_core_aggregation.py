@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (aggregate, fedavg_leaf, rank_mask, axis_mask,
+from repro.core import (fedavg_leaf, get_strategy, rank_mask, axis_mask,
                         pad_to_rank, rbla_leaf, slice_to_rank,
                         stacked_rank_masks, zeropad_leaf,
                         rank_proportional_weights, rbla_norm_leaf,
@@ -94,11 +94,11 @@ def test_aggregate_pytree_dispatch():
     masks = {"A": stacked_rank_masks(4, jnp.array([2, 4]))[:, :, None],
              "bias": jnp.ones(())}  # 0-d => fully shared
     w = jnp.ones(2)
-    out = aggregate(tree, masks, w, method="rbla")
+    out = get_strategy("rbla").aggregate_tree(tree, masks, w)
     assert out["A"].shape == (4, 3) and out["bias"].shape == (3,)
     np.testing.assert_allclose(np.asarray(out["A"]), 1.0)
     with pytest.raises(ValueError):
-        aggregate(tree, masks, w, method="nope")
+        get_strategy("nope")
 
 
 # ----------------------------------------------------- property tests  ----
@@ -221,7 +221,8 @@ def test_aggregate_threads_prev_tree():
     tree = {"A": jnp.ones((2, 4, 3))}
     masks = {"A": stacked_rank_masks(4, jnp.array([1, 1]))[:, :, None]}
     prev = {"A": jnp.full((4, 3), 5.0)}
-    out = aggregate(jax.tree.map(lambda x, m: x * m, tree, masks), masks,
-                    jnp.ones(2), method="rbla", prev_tree=prev)
+    out = get_strategy("rbla").aggregate_tree(
+        jax.tree.map(lambda x, m: x * m, tree, masks), masks, jnp.ones(2),
+        prev_tree=prev)
     np.testing.assert_allclose(np.asarray(out["A"][0]), 1.0)
     np.testing.assert_allclose(np.asarray(out["A"][1:]), 5.0)

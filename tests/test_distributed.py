@@ -27,8 +27,7 @@ def test_distributed_rbla_matches_host():
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.core import aggregate, stacked_rank_masks
-from repro.core.distributed import make_distributed_aggregator
+from repro.core import get_strategy, stacked_rank_masks
 
 n, r, d = 8, 32, 512
 rng = np.random.default_rng(0)
@@ -38,12 +37,13 @@ x = jnp.asarray(rng.normal(size=(n, r, d)), jnp.float32) * masks
 w = jnp.asarray(rng.uniform(0.5, 2.0, n), jnp.float32)
 mesh = Mesh(np.asarray(jax.devices()).reshape(8), ("clients",))
 for method in ("rbla", "zeropad"):
-    agg = make_distributed_aggregator(mesh, "clients", method)
+    agg = get_strategy(method).make_distributed_aggregator(mesh, "clients")
     sh = NamedSharding(mesh, P("clients"))
     out = agg(jax.device_put(x, sh),
               jax.device_put(jnp.broadcast_to(masks, x.shape), sh),
               jax.device_put(w, sh))
-    want = aggregate({"t": x}, {"t": masks}, w, method=method)["t"]
+    want = get_strategy(method).aggregate_tree({"t": x}, {"t": masks},
+                                               w)["t"]
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
 print("OK")
@@ -110,8 +110,8 @@ def test_fl_round_spmd():
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.core import get_strategy
 from repro.core.compat import shard_map_no_check
-from repro.core.distributed import rbla_tree_allreduce
 from repro.lora import (adapter_masks, attach_ranks, init_adapters,
                         strip_ranks, set_ranks)
 
@@ -128,8 +128,10 @@ def client_round(adapters, rank, x):
     upd["fc1"]["A"] = upd["fc1"]["A"] + 0.1 * jnp.mean(x)
     ad = set_ranks(upd, rank[0])   # re-mask
     masks = adapter_masks(ad)
-    agg = rbla_tree_allreduce(ad, masks, jnp.float32(1.0), "clients")
-    return agg
+    rbla = get_strategy("rbla")
+    return jax.tree.map(
+        lambda x, m: rbla.allreduce_leaf(x, m, jnp.float32(1.0), "clients"),
+        ad, masks)
 
 ranks = jnp.arange(1, 9, dtype=jnp.int32)        # heterogeneous ranks
 xs = jnp.arange(8, dtype=jnp.float32)[:, None] * jnp.ones((8, 4))

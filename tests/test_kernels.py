@@ -6,8 +6,9 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import (lora_matmul, lora_matmul_ref, rbla_agg,
-                           rbla_agg_ref)
+from repro.core import rbla_leaf
+from repro.kernels import (lora_matmul, lora_matmul_ref, packed_agg,
+                           packed_agg_ref)
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -65,7 +66,7 @@ def test_lora_matmul_zero_b_is_base_matmul():
                                rtol=1e-5, atol=1e-5)
 
 
-# -------------------------------------------------------------- rbla_agg ----
+# ------------------------------------------------------------ packed_agg ----
 AGG_SHAPES = [
     # (n_clients, r_rows, d)
     (2, 8, 128),
@@ -75,16 +76,29 @@ AGG_SHAPES = [
 ]
 
 
+#: the strategy methods' normalizations: rbla divides a row by its
+#: owners' weight mass (Eq. 7), zeropad by the total mass
+NORM_BY = {"rbla": "mask", "zeropad": "weight"}
+
+
+def _owner_masks(ranks, r):
+    """(n, r) f32 rank-row owner masks of a rank vector."""
+    return (np.arange(r)[None, :]
+            < np.asarray(ranks)[:, None]).astype(np.float32)
+
+
 @pytest.mark.parametrize("n,r,d", AGG_SHAPES)
 @pytest.mark.parametrize("method", ["rbla", "zeropad"])
 def test_rbla_agg_matches_ref(n, r, d, method):
     rng = np.random.default_rng(n * 100 + r + d)
     ranks = jnp.asarray(rng.integers(1, r + 1, n), jnp.int32)
-    masks = (np.arange(r)[None, :] < np.asarray(ranks)[:, None])
+    masks = _owner_masks(ranks, r)
     x = rng.normal(size=(n, r, d)).astype(np.float32) * masks[:, :, None]
     w = jnp.asarray(rng.uniform(0.5, 2.0, n), jnp.float32)
-    got = rbla_agg(jnp.asarray(x), ranks, w, method=method, interpret=True)
-    want = rbla_agg_ref(jnp.asarray(x), ranks, w, method=method)
+    got = packed_agg(jnp.asarray(x), jnp.asarray(masks), w,
+                     norm_by=NORM_BY[method], interpret=True)
+    want = packed_agg_ref(jnp.asarray(x), jnp.asarray(masks), w,
+                          norm_by=NORM_BY[method])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
 
@@ -93,11 +107,11 @@ def test_rbla_agg_trailing_dims():
     """(N, R, out, r2) adapter-B-like layouts flatten correctly."""
     rng = np.random.default_rng(9)
     n, r = 4, 16
-    ranks = jnp.asarray([4, 8, 16, 2], jnp.int32)
+    masks = jnp.asarray(_owner_masks([4, 8, 16, 2], r))
     x = jnp.asarray(rng.normal(size=(n, r, 8, 32)), jnp.float32)
-    got = rbla_agg(x, ranks, jnp.ones(n), interpret=True)
-    want = rbla_agg_ref(x.reshape(n, r, -1), ranks,
-                        jnp.ones(n)).reshape(r, 8, 32)
+    got = packed_agg(x, masks, jnp.ones(n), interpret=True)
+    want = packed_agg_ref(x.reshape(n, r, -1), masks,
+                          jnp.ones(n)).reshape(r, 8, 32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
 
@@ -108,11 +122,11 @@ def test_rbla_agg_trailing_dims():
 def test_prop_rbla_agg_matches_core(n, r, d, seed):
     rng = np.random.default_rng(seed)
     ranks = jnp.asarray(rng.integers(1, r + 1, n), jnp.int32)
-    masks = (np.arange(r)[None, :] < np.asarray(ranks)[:, None])
+    masks = _owner_masks(ranks, r)
     x = rng.normal(size=(n, r, d)).astype(np.float32) * masks[:, :, None]
     w = jnp.asarray(rng.uniform(0.1, 2.0, n), jnp.float32)
-    got = rbla_agg(jnp.asarray(x), ranks, w, interpret=True)
-    want = rbla_agg_ref(jnp.asarray(x), ranks, w)
+    got = packed_agg(jnp.asarray(x), jnp.asarray(masks), w, interpret=True)
+    want = rbla_leaf(jnp.asarray(x), jnp.asarray(masks)[:, :, None], w)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
 

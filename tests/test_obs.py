@@ -264,10 +264,10 @@ def test_spans_write_nested_annotations_into_the_profiler_trace(tmp_path):
         out = s.aggregate_adapters(adapters, w, r_max=R_MAX,
                                    client_ranks=ranks, prev_global=prev,
                                    backend="pallas", interpret=True)
-        # the per-leaf path still stacks the cohort: ``round.stack``
+        # the distributed plan still stacks the cohort: ``round.stack``
         ref = s.aggregate_adapters(adapters, w, r_max=R_MAX,
                                    client_ranks=ranks, prev_global=prev,
-                                   backend="ref", use_plan=False)
+                                   backend="distributed")
         agg.submit(ClientUpdate(adapters=adapters[0], base_trainable={},
                                 n_examples=2.0, rank=int(ranks[0])))
         jax.block_until_ready((out, ref, agg.state.adapters))
@@ -300,7 +300,7 @@ ROUND_SPANS = ("round", "round.stack", "round.spec", "round.plan",
 
 def test_metrics_toggle_never_retraces_warm_plan_path():
     """Every span of a round -- plain and encoded rounds pack per client,
-    the per-leaf path stacks -- runs on the warm path without a new
+    the distributed round stacks -- runs on the warm path without a new
     executor or trace."""
     from repro.kernels.runtime import trace_counts
     adapters, ranks, w = _warm_cohort()
@@ -308,11 +308,11 @@ def test_metrics_toggle_never_retraces_warm_plan_path():
     s = get_strategy("rbla").with_options()
 
     def run():
-        for cohort, use_plan in ((adapters, True), (enc, True),
-                                 (adapters, False)):
+        for cohort, backend in ((adapters, "ref"), (enc, "ref"),
+                                (adapters, "distributed")):
             jax.block_until_ready(jax.tree.leaves(s.aggregate_adapters(
                 cohort, w, r_max=R_MAX, client_ranks=ranks,
-                backend="ref", use_plan=use_plan)))
+                backend=backend)))
     run()                                                # warm
     execs = len(s.__dict__.get("_plan_exec_cache", {}))
     traces = dict(trace_counts)
@@ -327,11 +327,12 @@ def test_metrics_toggle_never_retraces_warm_plan_path():
         set_enabled(prev)
     assert len(s.__dict__.get("_plan_exec_cache", {})) == execs
     assert dict(trace_counts) == traces
-    # two enabled passes of three rounds each: two planned per client,
-    # one stacked and unplanned
+    # two enabled passes of three rounds each: two packed per client,
+    # one stacked onto the distributed plan (no pack/combine spans)
     assert {st: hist.labels(stage=st).count - seen[st]
             for st in ROUND_SPANS} == {
-        **{st: 4 for st in ROUND_SPANS}, "round": 6, "round.stack": 2}
+        **{st: 4 for st in ROUND_SPANS}, "round": 6, "round.stack": 2,
+        "round.spec": 6, "round.plan": 6}
 
 
 def test_metrics_toggle_never_retraces_warm_fold_path():

@@ -16,9 +16,10 @@ from repro.core.plan import (CohortSpec, PlanUnavailable, build_cohort_spec,
                              dispatch_counter)
 from repro.core.strategy import (ClientUpdate, ServerState, get_strategy,
                                  stack_trees)
-from repro.lora import init_adapters, init_pair, mask_pair, set_ranks
+from repro.lora import init_adapters, init_pair, mask_pair
 
-from _cohorts import R_MAX, SPECS, assert_trees_close, hetero_cohort
+from _cohorts import (R_MAX, SPECS, assert_trees_close, hetero_cohort,
+                      reference_round)
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -109,9 +110,7 @@ def test_plan_api_direct_and_unsupported_backend_raises():
                             client_ranks=ranks)
     round_ = s.plan(None, spec)
     out = round_(stacked, w)
-    want = s.aggregate_adapters(adapters, w, r_max=R_MAX,
-                                client_ranks=ranks, backend="ref",
-                                use_plan=False)
+    want = reference_round(s, adapters, w, r_max=R_MAX, client_ranks=ranks)
     assert_trees_close(out, want)
     # rbla_norm packs on pallas now; its missing path is distributed
     with pytest.raises(NotImplementedError, match="rbla_norm"):
@@ -142,20 +141,24 @@ def test_spec_build_unavailable_under_tracing_and_on_bare_leaves():
         jax.eval_shape(lambda t: (traced(t), t)[1], stacked)
 
 
-def test_aggregate_adapters_inside_jit_falls_back_to_legacy():
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+@pytest.mark.parametrize("method", ["rbla", "zeropad", "fedavg",
+                                    "rbla_norm"])
+def test_aggregate_adapters_inside_jit_falls_back_to_reference(method,
+                                                               backend):
     """Under jit tracing the cohort cannot be described host-side; the
-    round must silently run the in-trace reference path and agree."""
+    round must build no plan, run the reference math inside the trace
+    and agree with it, on either backend."""
     adapters, ranks, w = hetero_cohort(3, seed=8)
-    s = fresh("rbla")
+    s = fresh(method)
 
     @jax.jit
     def round_(ads, wv):
         return s.aggregate_adapters(ads, wv, r_max=R_MAX,
-                                    client_ranks=ranks)
+                                    client_ranks=ranks, backend=backend)
     got = round_(adapters, w)
-    want = s.aggregate_adapters(adapters, w, r_max=R_MAX,
-                                client_ranks=ranks, backend="ref",
-                                use_plan=False)
+    assert not s.__dict__.get("_plan_cache")
+    want = reference_round(s, adapters, w, r_max=R_MAX, client_ranks=ranks)
     assert_trees_close(got, want)
 
 
@@ -227,9 +230,8 @@ def test_same_cohort_reuses_packed_buffers_weight_only():
     assert s.plan_stats["pack_reuses"] >= 1
     assert s.plan_stats["pack_runs"] <= 2
     # the weight-only update is numerically the full round
-    want = s.aggregate_adapters(adapters, w2, r_max=R_MAX,
-                                client_ranks=ranks, backend="ref",
-                                use_plan=False)
+    want = reference_round(s, adapters, w2, r_max=R_MAX,
+                           client_ranks=ranks)
     assert_trees_close(out2, want)
     # different weights must really change the result (no stale cache)
     with pytest.raises(AssertionError):
@@ -333,9 +335,7 @@ def test_svd_plan_is_packed_batched_and_matches_oracle():
     adapters, ranks, w = hetero_cohort(4, seed=32)
     got = s.aggregate_adapters(adapters, w, r_max=R_MAX,
                                client_ranks=ranks, backend="ref")
-    want = s.aggregate_adapters(adapters, w, r_max=R_MAX,
-                                client_ranks=ranks, backend="ref",
-                                use_plan=False)
+    want = reference_round(s, adapters, w, r_max=R_MAX, client_ranks=ranks)
     assert_trees_close(got, want)
     rd = next(iter(s.__dict__["_plan_cache"].values()))
     assert rd.kind == "packed"
@@ -354,8 +354,7 @@ def test_svd_same_shape_pairs_share_one_batched_bucket():
     rd = next(iter(s.__dict__["_plan_cache"].values()))
     assert rd.kind == "packed"
     assert rd.n_kernel_launches == 1       # both pairs: same shapes
-    want = s.aggregate_adapters(cohort, w, r_max=8, client_ranks=ranks,
-                                backend="ref", use_plan=False)
+    want = reference_round(s, cohort, w, r_max=8, client_ranks=ranks)
     assert_trees_close(got, want)
 
 
@@ -401,11 +400,9 @@ def test_rbla_norm_packs_on_pallas_and_matches_ref():
     rd = next(r for r in s.__dict__["_plan_cache"].values()
               if r.spec.kind == "pallas")
     assert rd.kind == "packed" and rd.n_fallback_pairs == 0
-    # the legacy (per-pair kernel) path agrees too
-    legacy = s.aggregate_adapters(adapters, w, r_max=R_MAX,
-                                  client_ranks=ranks, backend="pallas",
-                                  use_plan=False)
-    assert_trees_close(ref, legacy)
+    # and both agree with the float32 reference
+    want = reference_round(s, adapters, w, r_max=R_MAX, client_ranks=ranks)
+    assert_trees_close(want, pal)
 
 
 def test_packed_agg_kernel_norm_restore_matches_oracle():
@@ -431,10 +428,8 @@ def test_donated_prev_buffers_are_consumed():
     out = s.aggregate_adapters(adapters, w, r_max=R_MAX,
                                client_ranks=ranks, prev_global=prev,
                                backend="ref", donate=True)
-    want = s.aggregate_adapters(
-        adapters, w, r_max=R_MAX, client_ranks=ranks,
-        prev_global=jax.tree.map(jnp.asarray, keep), backend="ref",
-        use_plan=False)
+    want = reference_round(s, adapters, w, r_max=R_MAX, client_ranks=ranks,
+                           prev=jax.tree.map(jnp.asarray, keep))
     assert_trees_close(out, want)
     # the no-use-after-donate guard: donated A/B buffers are dead, and
     # touching them afterwards raises instead of reading stale memory
@@ -462,10 +457,7 @@ def test_layer_stacked_pairs_run_fused_on_pallas(method):
     they pack into buckets like everything else."""
     cohort, ranks, w = layer_stacked_cohort()
     s = fresh(method)
-    # oracle: the pre-plan path (whose layer-stacked pairs used the
-    # reference per-pair leaf math)
-    want = s.aggregate_adapters(cohort, w, r_max=8, client_ranks=ranks,
-                                backend="pallas", use_plan=False)
+    want = reference_round(s, cohort, w, r_max=8, client_ranks=ranks)
     got = s.aggregate_adapters(cohort, w, r_max=8, client_ranks=ranks,
                                backend="pallas")
     assert_trees_close(want, got, msg=method)
@@ -477,8 +469,7 @@ def test_layer_stacked_pairs_run_fused_on_pallas(method):
 def test_layer_stacked_flora_packs_into_stack_buckets():
     cohort, ranks, w = layer_stacked_cohort(seed=3)
     s = fresh("flora", stack_r_cap=64)
-    want = s.aggregate_adapters(cohort, w, r_max=8, client_ranks=ranks,
-                                backend="pallas", use_plan=False)
+    want = reference_round(s, cohort, w, r_max=8, client_ranks=ranks)
     got = s.aggregate_adapters(cohort, w, r_max=8, client_ranks=ranks,
                                backend="pallas")
     assert_trees_close(want, got)
@@ -490,9 +481,7 @@ def test_layer_stacked_flora_packs_into_stack_buckets():
 def test_flora_over_cap_pairs_fall_back_inside_the_plan():
     adapters, ranks, w = hetero_cohort(4, seed=13, r_lo=4, r_hi=R_MAX)
     s = fresh("flora", stack_r_cap=R_MAX)    # sum(ranks) certainly > cap
-    want = s.aggregate_adapters(adapters, w, r_max=R_MAX,
-                                client_ranks=ranks, backend="pallas",
-                                use_plan=False)
+    want = reference_round(s, adapters, w, r_max=R_MAX, client_ranks=ranks)
     got = s.aggregate_adapters(adapters, w, r_max=R_MAX,
                                client_ranks=ranks, backend="pallas")
     assert_trees_close(want, got, rtol=1e-3, atol=1e-4)
@@ -511,19 +500,6 @@ def test_plan_round_is_one_tracked_dispatch():
     s.aggregate_adapters(adapters, w, r_max=R_MAX, client_ranks=ranks,
                          backend="pallas")
     assert dispatch_counter.reset() == 1
-
-
-def test_legacy_pallas_path_dispatches_per_pair():
-    s = fresh("rbla")
-    adapters, ranks, w = hetero_cohort(4, seed=14)
-    s.aggregate_adapters(adapters, w, r_max=R_MAX, client_ranks=ranks,
-                         backend="pallas", use_plan=False)   # compile
-    dispatch_counter.reset()
-    s.aggregate_adapters(adapters, w, r_max=R_MAX, client_ranks=ranks,
-                         backend="pallas", use_plan=False)
-    # two kernel launches (A + B) per pair: the dispatch gap the plan
-    # closes (>= 5x for any tree with >= 3 pairs)
-    assert dispatch_counter.reset() == 2 * len(SPECS)
 
 
 # --------------------------------------------------------- packed kernels --
@@ -786,9 +762,8 @@ def test_mean_family_plans_plain_cohorts_per_client(method, with_prev,
         return (init_adapters(jax.random.PRNGKey(45), SPECS, R_MAX, R_MAX)
                 if with_prev else None)
     s = fresh(method)
-    want = s.aggregate_adapters(adapters, w, r_max=R_MAX, client_ranks=ranks,
-                                prev_global=prev(), backend="ref",
-                                use_plan=False)
+    want = reference_round(s, adapters, w, r_max=R_MAX, client_ranks=ranks,
+                           prev=prev())
     spec = build_cohort_spec(stack_trees(adapters), kind="ref", r_max=R_MAX,
                              client_ranks=ranks,
                              prev_tree=prev() if s.retains_prev else None)
@@ -812,11 +787,11 @@ def test_mean_family_plans_plain_cohorts_per_client(method, with_prev,
 def test_stacking_paths_count_their_cohort_stacks():
     """Paths that need the ``(n, ...)`` tree still stack, once a cohort,
     and say so in ``cohort_stacks_total``: the stack plan (flora), the
-    per-leaf path and the distributed plan."""
+    svd plan and the distributed plan."""
     from repro.obs import cohort_stacks
     adapters, ranks, w = hetero_cohort(3, seed=46)
     for method, kw in (("flora", dict(backend="ref")),
-                       ("rbla", dict(backend="ref", use_plan=False)),
+                       ("svd", dict(backend="ref")),
                        ("rbla", dict(backend="distributed"))):
         s = fresh(method)
         stacks = cohort_stacks(s.name)

@@ -2,8 +2,6 @@
 distributed (shard_map psum), and Pallas (kernel) paths must agree
 numerically on heterogeneous-rank fixtures, and unknown names must fail
 with an actionable error."""
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,7 +11,7 @@ from repro.core.strategy import (AggregationStrategy, ClientUpdate,
                                  ServerState, get_strategy, list_strategies,
                                  register_strategy, resolve_backend,
                                  stack_trees)
-from repro.lora import init_adapters, mask_adapters, set_ranks
+from repro.lora import init_adapters, set_ranks
 
 from _cohorts import R_MAX, SPECS, assert_trees_close, hetero_cohort
 
@@ -136,11 +134,16 @@ def test_resolve_backend_auto_is_ref_on_cpu():
 def test_unsupported_paths_raise_actionable_errors():
     from repro.core.strategy import AggregationStrategy
 
+    from repro.core.plan import build_cohort_spec
+
     class NoKernel(AggregationStrategy):        # default: no Pallas path
         name = "no_kernel"
 
+    adapters, ranks, _ = hetero_cohort(2, seed=3)
+    spec = build_cohort_spec(stack_trees(adapters), kind="pallas",
+                             r_max=R_MAX, client_ranks=ranks)
     with pytest.raises(NotImplementedError, match="Pallas"):
-        NoKernel().aggregate_tree_pallas({}, jnp.ones(2), None)
+        NoKernel().plan(None, spec)
     # svd's distributed collective is gathered factors, not the base
     # masked psum: the leafwise aggregator hook refuses with guidance
     with pytest.raises(NotImplementedError, match="distributed"):
@@ -304,34 +307,15 @@ def test_aggregate_without_adapters_is_fedavg_only():
                                rtol=1e-6)
 
 
-# ----------------------------------------- old entry points still dispatch --
-def test_deprecated_server_wrappers_route_through_registry():
-    adapters, ranks, w = hetero_cohort(3, seed=9)
-    from repro.fl.server import aggregate_adapters
-    with pytest.deprecated_call():
-        old = aggregate_adapters(adapters, w, method="rbla", r_max=R_MAX,
-                                 client_ranks=ranks)
-    new = get_strategy("rbla").aggregate_adapters(
-        adapters, w, r_max=R_MAX, client_ranks=ranks, backend="ref")
-    assert_trees_close(old, new)
-
-
-def test_core_aggregate_shim_rejects_unknown_method():
-    from repro.core import aggregate
-    tree = {"t": jnp.ones((2, 4, 3))}
-    masks = {"t": jnp.ones(())}
-    with pytest.raises(ValueError, match="unknown aggregation strategy"):
-        aggregate(tree, masks, jnp.ones(2), method="nope")
-
-
+# ------------------------------------------ ranked weights need the ranks --
 def test_ranked_via_legacy_shims_never_silently_downgrades():
-    """The old string-dispatch aggregate() rejected rbla_ranked; the shim
-    must not quietly run it as plain rbla when ranks are unavailable."""
-    from repro.core import aggregate, rbla_tree_allreduce
+    """rbla_ranked must never quietly run as plain rbla when the rank
+    vector is unavailable: the reference tree path without
+    ``client_ranks`` and the per-shard allreduce both refuse."""
+    s = get_strategy("rbla_ranked")
     tree = {"t": jnp.ones((2, 4, 3))}
     masks = {"t": jnp.ones(())}
     with pytest.raises(ValueError, match="client_ranks"):
-        aggregate(tree, masks, jnp.ones(2), method="rbla_ranked")
+        s.aggregate_tree(tree, masks, jnp.ones(2))
     with pytest.raises(NotImplementedError, match="rank_proportional"):
-        rbla_tree_allreduce(tree, masks, jnp.float32(1.0), "clients",
-                            method="rbla_ranked")
+        s.allreduce_leaf(tree["t"][0], None, jnp.float32(1.0), "clients")

@@ -28,6 +28,8 @@ from hypothesis import strategies as st
 from repro.core.strategy import get_strategy, list_strategies
 from repro.lora import init_adapters, set_ranks
 
+from _cohorts import reference_round
+
 jax.config.update("jax_platform_name", "cpu")
 
 SPECS = {"fc1": (9, 7), "fc2": (6, 9)}
@@ -261,9 +263,7 @@ def test_svd_parity_holds_under_packed_lowering(seed, n):
     adapters, rvec, w = make_cohort(seed, random_ranks(seed + 9, n))
     got = s.aggregate_adapters(adapters, w, r_max=R_MAX,
                                client_ranks=rvec, backend="ref")
-    want = s.aggregate_adapters(adapters, w, r_max=R_MAX,
-                                client_ranks=rvec, backend="ref",
-                                use_plan=False)
+    want = reference_round(s, adapters, w, r_max=R_MAX, client_ranks=rvec)
     for k in SPECS:
         for f in ("A", "B", "rank"):
             np.testing.assert_allclose(
@@ -475,20 +475,24 @@ def test_packed_robust_kernel_matches_ref(seed, n, d, mode):
                                rtol=1e-5, atol=1e-6)
 
 
-# ----------------------------------------------- flora_stack kernel oracle --
+# ---------------------------------------------- packed_stack kernel oracle --
 @settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 10 ** 6), n=st.integers(1, 5),
        d=st.sampled_from([3, 17, 130]))
-def test_flora_stack_kernel_matches_ref(seed, n, d):
-    from repro.kernels import flora_stack, flora_stack_ref
+def test_packed_stack_kernel_matches_ref(seed, n, d):
+    """flora's stacking as packed copies: contributor i's leading
+    ``segs[i]`` rows land, scaled, at the running offset."""
+    from repro.kernels import packed_stack, packed_stack_ref
     rng = np.random.default_rng(seed)
     r_st = R_MAX
     segs = tuple(int(v) for v in rng.integers(1, r_st + 1, n))
     out_rows = sum(segs) + int(rng.integers(0, 4))
     x = jnp.asarray(rng.normal(size=(n, r_st, d)), jnp.float32)
     scales = jnp.asarray(rng.uniform(0.1, 2.0, n), jnp.float32)
-    got = flora_stack(x, scales, segs=segs, out_rows=out_rows,
-                      interpret=True)
-    want = flora_stack_ref(x, scales, segs, out_rows)
+    offs = np.concatenate([[0], np.cumsum(segs)[:-1]])
+    copies = tuple((i, 0, int(offs[i]), segs[i], i) for i in range(n))
+    got = packed_stack(x, scales, copies_x=copies, out_rows=out_rows,
+                       interpret=True)
+    want = packed_stack_ref(x, scales, copies_x=copies, out_rows=out_rows)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
